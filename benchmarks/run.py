@@ -50,6 +50,7 @@ from repro.api import PartitionSpec, solve  # noqa: E402
 from repro.core import (  # noqa: E402
     PAPER_FRAM_MODEL, q_min, single_task_partition, whole_app_partition)
 from repro.core.apps.headcount import THERMAL, VISUAL, build_graph  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 CM = PAPER_FRAM_MODEL
 
@@ -1204,6 +1205,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json-out", default=None,
                     help="partition_sweep: override the JSON dump path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print("name,value,derived")
     sections = [args.section] if args.section else list(SECTIONS)

@@ -316,10 +316,12 @@ class QGridSharding:
 
     Mirrors the legacy ``sweep_jax_sharded`` / ``shard_plan_table``
     parameters: ``devices`` defaults to ``jax.local_devices()`` at solve
-    time; with fewer devices than shards the same chunk decomposition runs
-    sequentially (bit-identical either way). Only ``objective="sum"`` has a
-    Q grid to shard; a spec combining sharding with ``minimax``/``exact_k``
-    is rejected at construction (:class:`SpecError`).
+    time; with fewer local devices than shards the same chunk decomposition
+    then runs sequentially (bit-identical either way). Explicit ``devices``
+    are a placement: too few of them raise ``ValueError`` at solve time.
+    Only ``objective="sum"`` has a Q grid to shard; a spec combining
+    sharding with ``minimax``/``exact_k`` is rejected at construction
+    (:class:`SpecError`).
     """
 
     n_shards: int

@@ -56,7 +56,7 @@ with ``S(j) = Σ_{p ∈ writes(j), l_∞(p) > j} E_w(p)``, and the fused DP:
 
     dp[q, j]  = min_{1 ≤ i ≤ j, E⟨i,j⟩ ≤ Q_max[q]} dp[q, i-1] + E⟨i,j⟩
 
-Numerics run in float64 under :func:`jax.experimental.enable_x64` so results
+Numerics run in float64 under :func:`jax.enable_x64` so results
 match the numpy oracles to ~ulp; infeasibility uses the same relative budget
 tolerance as the numpy path. Tie-breaking (argmin picks the smallest burst
 start) also matches, so reconstructed bounds agree bit-for-bit on generic
@@ -74,7 +74,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from ..obs.metrics import METRICS
 from ._cache import weak_id_cache
@@ -638,7 +637,7 @@ def _sweep_jax(
     arrays = _as_arrays(graph)
     if arrays.n_tasks == 0:
         return _empty_sweep(q_values)
-    with enable_x64():
+    with jax.enable_x64():
         dp, parent, e_total, feasible, starts = _dp_sweep_jit(
             _ga_dict(arrays),
             jnp.asarray(arrays.n_tasks, dtype=jnp.int32),
@@ -739,7 +738,7 @@ def _sweep_jax_batched(
             out[k] = _empty_sweep(q_values)
     if nonempty:
         stacked = stack_graph_arrays([a for _, a in nonempty])
-        with enable_x64():
+        with jax.enable_x64():
             dp, parent, e_total, feasible, starts = _dp_sweep_vmap(
                 _ga_dict(stacked),
                 jnp.asarray(stacked.n_tasks, dtype=jnp.int32),
@@ -887,8 +886,10 @@ def _sweep_jax_sharded(
     solve (per-Q DP independence — the differential tier pins this).
 
     Scan backend: chunks pad to a common width and run under one
-    ``pmap(vmap(...))`` when ``len(devices) >= n_shards``, else sequentially
-    through the same vmapped kernel (one compile either way). Pallas/CSR
+    ``pmap(vmap(...))`` when ``len(devices) >= n_shards``. With ``devices``
+    left to default and too few local devices they run sequentially through
+    the same vmapped kernel (one compile either way); explicit ``devices``
+    that are too few raise ``ValueError``. Pallas/CSR
     backend (or a mixed ``auto`` batch): chunks run as host-side
     ``sweep_jax_batched`` calls — the kernel lanes the Q axis itself, so
     chunked solves are already bit-stable there.
@@ -924,7 +925,14 @@ def _sweep_jax_sharded(
     stacked = stack_graph_arrays([a for _, a in nonempty])
     qs_sh = _pad_q_shards(qs_np, chunks)
     devs = tuple(devices) if devices is not None else tuple(jax.local_devices())
-    with enable_x64():
+    if devices is not None and len(chunks) > 1 and len(devs) < len(chunks):
+        # Devices named by the caller are a placement, not a hint: running
+        # their shards back to back would hide a missing device.
+        raise ValueError(
+            f"{len(chunks)} Q shards need {len(chunks)} devices, "
+            f"got {len(devs)}"
+        )
+    with jax.enable_x64():
         ga = _ga_dict(stacked)
         nt = jnp.asarray(stacked.n_tasks, dtype=jnp.int32)
         cv = _cost_vec(cost)
@@ -1017,7 +1025,7 @@ def _q_min_scan(graph: AnyExport, cost: CostModel) -> float:
     arrays = _as_arrays(graph)
     if arrays.n_tasks == 0:
         return 0.0
-    with enable_x64():
+    with jax.enable_x64():
         out = _qmin_sweep_jit(
             _ga_dict(arrays),
             jnp.asarray(arrays.n_tasks, dtype=jnp.int32),
@@ -1053,7 +1061,7 @@ def _optimal_k_scan(
     if objective not in ("sum", "max"):
         raise ValueError(f"objective must be 'sum' or 'max', got {objective!r}")
     q = np.inf if q_max is None else float(q_max)
-    with enable_x64():
+    with jax.enable_x64():
         vals, bsts = _exactk_sweep_jit(
             _ga_dict(arrays),
             jnp.asarray(n, dtype=jnp.int32),

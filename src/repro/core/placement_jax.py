@@ -22,7 +22,7 @@ slices: inf candidates never beat a finite min, and all-inf rows pick the
 first index in both (``inf == inf``). tests/test_placement.py pins value
 *and* parent arrays bitwise on every smoke config.
 
-Numerics run in float64 under :func:`jax.experimental.enable_x64`, matching
+Numerics run in float64 under :func:`jax.enable_x64`, matching
 :mod:`.partition_jax`.
 """
 
@@ -36,7 +36,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from .cost import CostModel
 from .graph import TaskGraph
@@ -141,7 +140,7 @@ def solve_placement_scan(
     mi_idx = np.tile(np.repeat(np.arange(M), Z), L)
     zi_idx = np.tile(np.arange(Z), L * M)
     kernel = _placement_kernel(n, N, L, M, Z)
-    with enable_x64():
+    with jax.enable_x64():
         S_all, A_all, dp, parent = kernel(
             jnp.asarray(inputs.energy),
             jnp.asarray(inputs.q_thresh),

@@ -770,10 +770,11 @@ def build_plan_table(
     across a device mesh; the gathered per-shard columns assemble into a
     table **byte-identical** to the unsharded build of the same inputs
     (same fingerprint, same :meth:`PlanTable.content_digest` — the
-    differential tier pins this on 1/2/4/8 emulated devices). With fewer
-    devices than shards the same chunk decomposition runs sequentially
-    (bit-identical either way), so a shard count tuned for an 8-device host
-    is safe on a laptop.
+    differential tier pins this on 1/2/4/8 emulated devices). With
+    ``sharding.devices`` left unset and fewer local devices than shards the
+    same chunk decomposition runs sequentially (bit-identical either way),
+    so a shard count tuned for an 8-device host is safe on a laptop;
+    explicit devices that are too few raise instead.
     """
     return _build_table(
         cfg, shape_buckets, q_values, kind=kind, cost=cost, backend=backend,

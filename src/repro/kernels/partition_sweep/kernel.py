@@ -10,8 +10,10 @@ across the sequential grid.
 Read-slot contributions come from the CSR-style compressed slot layout of
 :class:`repro.core.graph.GraphCSRArrays` (flat ``slot_task_ptr`` /
 ``slot_cost`` / ``slot_lt`` / ``slot_writer`` / ``slot_linf`` arrays instead
-of the dense ``(N, R)`` rectangle): each program loops over task j's slot
-range and applies the three piecewise-constant updates in-register:
+of the dense ``(N, R)`` rectangle), held in SMEM so that the compiled kernel
+can read slot ``p0 + s`` as a scalar at a dynamic index: each program loops
+over task j's slot range and applies the three piecewise-constant updates
+in-register:
 
     E⟨i,j⟩ = E⟨i,j-1⟩ + E_task(j) + S(j)
            + Σ_{p ∈ reads(j)}  E_r(p) · [i > l_j(p)]             (new loads)
@@ -42,13 +44,19 @@ Every mode tie-breaks its argmin to the smallest burst start. With
 accumulation order, so the emitted column tables are bit-identical to
 :mod:`.ref` — and hence to the numpy DP oracles — including argmin
 tie-breaks; ``slot_chunk>1`` processes slots in vectorized chunks (one
-masked 2-D reduction per chunk, ~ulp drift, for TPU throughput; on exact
-dyadic-cost graphs the chunked reductions are still exact, which the tie
-audit pins across all three modes).
+masked 2-D reduction per chunk, ~ulp drift; on exact dyadic-cost graphs the
+chunked reductions are still exact, which the tie audit pins across all
+three modes). Chunks are interpret-only: the compiled kernel reads slots
+from SMEM one scalar at a time.
 
-Compiled-mode TPU use is float32 (f64 is interpret-only); the engine's
-differential guarantees are stated for the f64 interpret path, which is
-also the CPU production path (the whole grid lowers to one XLA while-loop).
+Compiled on a TPU the kernel runs float32 (f64 is interpret-only): the
+same slot order, but cut positions may differ from numpy among candidates
+closer than float32 can order. The engine's bit-identity guarantees are
+stated for the f64 interpret path, the CPU path (the whole grid lowers to
+one XLA while-loop). The ``(N, nq_pad)`` outputs and ``dpbuf`` stay
+resident in VMEM; :func:`vmem_bytes` says how much, and
+:func:`repro.kernels.partition_sweep.ops.sweep_columns` refuses sizes over
+the scoped limit before lowering.
 """
 
 from __future__ import annotations
@@ -80,11 +88,11 @@ def _sweep_kernel(
     etask_ref,        # (N,)         f    SMEM  E_task(j)
     store_ref,        # (N,)         f    SMEM  S(j)
     es_ref,           # (1,)         f    SMEM  E_s
-    cost_ref,         # (1, nnz_pad) f    VMEM  E_r per read slot
-    free_ref,         # (1, nnz_pad) f    VMEM  E_w of the read packet
-    lt_ref,           # (1, nnz_pad) i32  VMEM  l_j(p)
-    writer_ref,       # (1, nnz_pad) i32  VMEM  writer(p)
-    linf_ref,         # (1, nnz_pad) i32  VMEM  l_∞(p)
+    cost_ref,         # (nnz_pad,)   f    SMEM  E_r per read slot
+    free_ref,         # (nnz_pad,)   f    SMEM  E_w of the read packet
+    lt_ref,           # (nnz_pad,)   i32  SMEM  l_j(p)
+    writer_ref,       # (nnz_pad,)   i32  SMEM  writer(p)
+    linf_ref,         # (nnz_pad,)   i32  SMEM  l_∞(p)
     budget_ref,       # (1, nq_pad)  f    VMEM  Q·(1+rel)+abs, -inf padding
     mns_ref,          # (N, nq_pad)  f    out   dp[q, j] per column
     best_ref,         # (N, nq_pad)  i32  out   argmin burst start per column
@@ -132,12 +140,12 @@ def _sweep_kernel(
         def slot(s, carry):
             colt, sum_er = carry
             idx = p0 + s
-            sc = cost_ref[0, idx]
-            colt = jnp.where(prev & (i_vec > lt_ref[0, idx]), colt + sc, colt)
-            w = writer_ref[0, idx]
-            freed = (linf_ref[0, idx] == j) & (w >= np.int32(1))
+            sc = cost_ref[idx]
+            colt = jnp.where(prev & (i_vec > lt_ref[idx]), colt + sc, colt)
+            w = writer_ref[idx]
+            freed = (linf_ref[idx] == j) & (w >= np.int32(1))
             colt = jnp.where(
-                prev & freed & (i_vec <= w), colt - free_ref[0, idx], colt
+                prev & freed & (i_vec <= w), colt - free_ref[idx], colt
             )
             return colt, sum_er + sc
 
@@ -146,16 +154,22 @@ def _sweep_kernel(
         )
     else:
         # Chunked: one masked 2-D reduction per C slots (~ulp drift).
+        # Interpret mode only (sweep_columns_call refuses it compiled): a
+        # C-wide load from SMEM is no scalar load, and from VMEM its
+        # dynamic lane offset is not provably 128-aligned.
+        def window(ref, idx0):
+            return ref[pl.ds(idx0, C)][None, :]
+
         def chunk(s, carry):
             colt, sum_er = carry
             idx0 = p0 + s * np.int32(C)
             lanes = idx0 + lax.broadcasted_iota(jnp.int32, (1, C), 1)
             valid = lanes < p1
-            sc = jnp.where(valid, cost_ref[0, pl.ds(idx0, C)], 0.0)
-            sf = jnp.where(valid, free_ref[0, pl.ds(idx0, C)], 0.0)
-            slt = lt_ref[0, pl.ds(idx0, C)]
-            swr = writer_ref[0, pl.ds(idx0, C)]
-            sli = linf_ref[0, pl.ds(idx0, C)]
+            sc = jnp.where(valid, window(cost_ref, idx0), 0.0)
+            sf = jnp.where(valid, window(free_ref, idx0), 0.0)
+            slt = window(lt_ref, idx0)
+            swr = window(writer_ref, idx0)
+            sli = window(linf_ref, idx0)
             loads = jnp.sum(
                 jnp.where(i_vec > slt, sc, 0.0), axis=1, keepdims=True
             )
@@ -227,6 +241,38 @@ def _sweep_kernel(
             dpbuf[pl.ds(j, 1), :] = accmin[0, :][None, :]
 
 
+def _tiling(n: int, tile: int) -> tuple:
+    """(rows per i-tile B, number of tiles T) for an N-task sweep."""
+    b = min(tile, max(8, n))
+    return b, -(-n // b)
+
+
+def vmem_bytes(n: int, nq_pad: int, tile: int = 512) -> int:
+    """Scoped VMEM the compiled kernel allocates for an N × nq_pad sweep.
+
+    Mirrors the specs of :func:`sweep_columns_call` under the TPU's (8, 128)
+    VMEM tiling: the two resident ``(N, nq_pad)`` output tables plus the
+    ``(T·B, nq_pad)`` ``dpbuf``, ``(T·B, 1)`` ``colbuf`` and two ``(1,
+    nq_pad)`` accumulator scratch buffers — 18.92 MiB at N=5458,
+    nq_pad=256, exactly what the v5e compiler reports. The slot arrays live
+    in SMEM and do not count. On top come the kernel body's temporaries,
+    bounded by six ``(B, 1)`` columns and one ``(B, nq_pad)`` tile: fitted
+    so that every size this estimate keeps under 16 MiB compiled for v5e
+    across nq_pad 128–1024 and tile 128–512.
+    """
+    b, t = _tiling(n, tile)
+    f = 4  # float32 and int32: the compiled kernel's dtypes
+    up = lambda x, m: -(-x // m) * m
+    lanes, rows, npad, b8 = up(nq_pad, 128), up(n, 8), up(t * b, 8), up(b, 8)
+    return (
+        rows * lanes * 2 * f          # mns + bests
+        + npad * lanes * f            # dpbuf
+        + npad * 128 * f              # colbuf
+        + lanes * 2 * f               # accmin + accarg
+        + b8 * (6 * 128 + lanes) * f  # body temporaries
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("tile", "slot_chunk", "interpret", "mode", "combine_max"),
@@ -268,16 +314,21 @@ def sweep_columns_call(
     N = e_task.shape[0]
     nq_pad = budget.shape[0]
     dtype = e_task.dtype
-    B = min(tile, max(8, N))
-    T = -(-N // B)
+    B, T = _tiling(N, tile)
     C = slot_chunk
+    if C > 1 and not interpret:
+        raise ValueError(
+            f"slot_chunk={C} runs in interpret mode only: the compiled "
+            "kernel reads the slot arrays from SMEM one scalar at a time; "
+            "use slot_chunk=1"
+        )
     nnz = slot_cost.shape[0]
     # Slot pool padded so every C-wide dynamic load stays in bounds without
     # clamping (clamped loads would misalign the validity mask).
     nnz_pad = (-(-max(nnz, 1) // C) + 1) * C
 
     def pad1(a):
-        return jnp.pad(a, (0, nnz_pad - nnz))[None, :]
+        return jnp.pad(a, (0, nnz_pad - nnz))
 
     vspec = lambda shape: pl.BlockSpec(shape, lambda j, t: (0,) * len(shape))
     sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
@@ -290,8 +341,8 @@ def sweep_columns_call(
         grid=(N, T),
         in_specs=[
             sspec, sspec, sspec, sspec,
-            vspec((1, nnz_pad)), vspec((1, nnz_pad)), vspec((1, nnz_pad)),
-            vspec((1, nnz_pad)), vspec((1, nnz_pad)), vspec((1, nq_pad)),
+            sspec, sspec, sspec, sspec, sspec,
+            vspec((1, nq_pad)),
         ],
         out_specs=[vspec((N, nq_pad)), vspec((N, nq_pad))],
         out_shape=[

@@ -13,7 +13,7 @@ them bit-for-bit against the :mod:`.ref` oracles.
 
 Serving-path notes (ROADMAP "hoist dtype handling"):
 
-* float64 numerics need ``jax.experimental.enable_x64``; the scope is
+* float64 numerics need ``jax.enable_x64``; the scope is
   entered here, around conversion + dispatch only, and it is a cheap
   thread-local flag — the jit cache is keyed per config state, so repeated
   calls reuse one trace (asserted by tests/test_partition_sweep.py).
@@ -30,12 +30,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ...core._cache import weak_id_cache
 from ...core.cost import CostModel
 from ...core.graph import GraphCSRArrays
-from .kernel import sweep_columns_call
+from .kernel import sweep_columns_call, vmem_bytes
 from .ref import (  # noqa: F401  (re-exported oracles)
     _ABS,
     _REL,
@@ -47,6 +46,8 @@ from .ref import (  # noqa: F401  (re-exported oracles)
 )
 
 __all__ = [
+    "SCOPED_VMEM_BYTES",
+    "VmemLimitExceeded",
     "sweep_columns",
     "sweep_columns_ref",
     "sweep_columns_minimax_ref",
@@ -54,6 +55,14 @@ __all__ = [
     "slot_costs",
     "store_add_ref",
 ]
+
+
+# The TPU compiler's default scoped-VMEM limit for one kernel on v5e.
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+class VmemLimitExceeded(ValueError):
+    """The compiled kernel's resident tables would not fit scoped VMEM."""
 
 
 def _needs_interpret() -> bool:
@@ -118,7 +127,9 @@ def sweep_columns(
     Infeasible entries carry ``inf`` in mns; bests are only meaningful
     where finite. ``interpret=None`` auto-selects interpret mode on every
     non-TPU backend (float64, differential-exact); compiled TPU mode runs
-    float32.
+    float32, with ``slot_chunk=1`` only, and raises
+    :class:`VmemLimitExceeded` before lowering when the resident tables
+    would overrun :data:`SCOPED_VMEM_BYTES`.
     """
     if interpret is None:
         interpret = _needs_interpret()
@@ -160,7 +171,18 @@ def sweep_columns(
     else:
         raise ValueError(f"unknown kernel objective {objective!r}")
 
-    with enable_x64(bool(interpret)):
+    if not interpret:
+        need = vmem_bytes(csr.n_pad, nq_pad, tile)
+        if need > SCOPED_VMEM_BYTES:
+            raise VmemLimitExceeded(
+                f"compiled sweep of {csr.n_pad} tasks x {nq_pad} lanes "
+                f"(tile {tile}) needs {need / 2**20:.2f} MiB of VMEM for its "
+                f"resident (N, nq) tables, dpbuf and temporaries; the limit "
+                f"is {SCOPED_VMEM_BYTES / 2**20:.0f} MiB. Split the Q grid "
+                "into narrower solves or use a smaller tile."
+            )
+
+    with jax.enable_x64(bool(interpret)):
         args = _device_slots(csr, cost, dtype)
         mns, bests = sweep_columns_call(
             *args,

@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional
 import jax
 
 from ..configs.base import REGISTRY, SHAPES, get_config, shape_applicable
+from .compile_cache import enable_compile_cache
 from .mesh import make_production_mesh
 from .roofline import analyze_hlo, dominant_term, roofline_terms
 from .steps import build_cell
@@ -153,6 +154,7 @@ def main(argv=None) -> int:
     ap.add_argument("--save-hlo", default=None)
     ap.add_argument("--out", default=None, help="directory for JSON records")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     archs = [args.arch] if args.arch else sorted(REGISTRY)
     shapes = [args.shape] if args.shape else list(SHAPES)

@@ -59,6 +59,7 @@ from ..core.plan_table import (
     probe_plan_table,
     _default_cost,
 )
+from .compile_cache import enable_compile_cache
 from .mesh import shard_devices
 from .planner import _parse_buckets, derive_q_grid, lower_buckets, resolve_config
 
@@ -322,6 +323,7 @@ def main(argv=None) -> int:
     ap.add_argument("--metrics-out", default=None,
                     help="write the metrics-registry snapshot as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.trace_out:
         TRACER.configure(enabled=True)
 

@@ -60,6 +60,7 @@ from ..core.plan_table import (
     _default_cost,
 )
 from ..core.remat_policy import RematPlan, remat_from_bounds
+from .compile_cache import enable_compile_cache
 
 __all__ = [
     "ADMISSION_OUTCOMES",
@@ -381,6 +382,7 @@ def main(argv=None) -> int:
                     help="re-validate this many random cells against the "
                     "live engine after the build")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     buckets = _parse_buckets(args.buckets)
     t0 = time.time()

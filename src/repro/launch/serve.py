@@ -42,6 +42,7 @@ from ..models import api
 from ..obs.metrics import METRICS
 from ..obs.trace import TRACER
 from ..models.sharding import rules_for
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 from .steps import make_constrain
 from .traffic import Continuation, Request
@@ -391,6 +392,7 @@ def main(argv=None) -> int:
                     help="relative drift tolerance for the --calibration "
                          "probe (default 0.05)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.trace_out:
         TRACER.configure(enabled=True)
     if args.calibration:

@@ -36,6 +36,7 @@ from typing import List, Optional, Tuple
 from ..obs.ledger import EnergyLedger, LedgerImbalance
 from ..obs.metrics import METRICS
 from ..obs.trace import PID_SWARM, TRACER, node_tid
+from .compile_cache import enable_compile_cache
 
 __all__ = ["build_swarm_spec", "load_graph", "report_sweep", "main"]
 
@@ -226,6 +227,7 @@ def main(argv=None) -> int:
     ap.add_argument("--metrics-out", default=None,
                     help="write the metrics-registry snapshot as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if not (args.prof or args.dep or args.arch):
         ap.error("pick a mode: --prof/--dep (NS Optimizer) or --arch (zoo)")
     if args.trace_out:

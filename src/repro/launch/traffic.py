@@ -78,6 +78,7 @@ from ..obs.trace import (
     TRACER,
     request_tid,
 )
+from .compile_cache import enable_compile_cache
 
 # Structured progress reporting: silent under pytest / library use (no
 # handler), "[traffic] ..." on stdout under the CLI (enable_cli_output).
@@ -953,6 +954,7 @@ def main(argv=None) -> int:
                          "byte-identical to the original table (holds when "
                          "the measured draw matches the analytical model)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if (args.replan or args.table_out) and not (args.build
                                                 or args.plan_table is None):
         ap.error("--replan/--table-out need the in-process --build path")

@@ -33,6 +33,7 @@ from ..data.synthetic import SyntheticConfig, SyntheticData
 from ..models import api
 from ..models.sharding import rules_for, shardings_for_tree
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh, make_production_mesh
 from .steps import make_constrain
 
@@ -119,6 +120,7 @@ def main(argv=None) -> int:
     ap.add_argument("--plan-bursts", action="store_true",
                     help="print the julienne checkpoint-cadence plan and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.plan_bursts:
         part = plan_burst_schedule(args.steps, step_seconds=1.0,
                                    state_bytes=10**9, max_loss_seconds=60.0)

@@ -135,17 +135,16 @@ def constrain(x, rules: Rules, *logical: Optional[str]):
     No-op outside jit on a single device (smoke tests).
     """
     mesh = _current_mesh()
-    if mesh is None or mesh.empty or mesh.size == 1:
+    if mesh.empty or mesh.size == 1:
         return x
     spec = logical_to_spec(tuple(logical), rules, mesh, shape=tuple(x.shape))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def _current_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src import mesh as mesh_lib
+def _current_mesh() -> Mesh:
+    # The mesh entered with ``with mesh:`` (empty outside one). A private
+    # JAX API: if a JAX upgrade moves it, this fails loudly rather than
+    # turning every sharding constraint off.
+    from jax._src import mesh as mesh_lib
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return m
-    except Exception:
-        return None
+    return mesh_lib.thread_resources.env.physical_mesh

@@ -25,10 +25,13 @@ additionally under hypothesis when it is installed (the test_partition.py
 idiom — the seed container has no hypothesis, CI may).
 """
 
+import collections
 import json
 import math
+import os
 import random
 import statistics
+import tempfile
 
 import numpy as np
 import pytest
@@ -890,10 +893,16 @@ if HAVE_HYPOTHESIS:
             seed=st.integers(0, 2 ** 16),
         )
         @settings(max_examples=40, deadline=None)
-        def test_dump_interleaving_invariance(self, rows, seed, tmp_path):
+        def test_dump_interleaving_invariance(self, rows, seed):
             cm = analytical_cost_model("time")
-            shuffled = list(rows)
-            random.Random(seed).shuffle(shuffled)
+            # Interleave the cells at random; each (rid, cycle) keeps its
+            # own rows in charge order, which sorted_rows() preserves.
+            cells = collections.defaultdict(collections.deque)
+            for r in rows:
+                cells[r[:2]].append(r)
+            keys = [r[:2] for r in rows]
+            random.Random(seed).shuffle(keys)
+            shuffled = [cells[k].popleft() for k in keys]
             a, b = EnergyLedger(), EnergyLedger()
             for ledger, data in ((a, rows), (b, shuffled)):
                 for rid, cycle, cat, e in data:
@@ -901,11 +910,14 @@ if HAVE_HYPOTHESIS:
                         ledger.overhead(rid, cycle, e)
                     else:
                         ledger.charge(rid, cycle, **{cat: e})
-            pa, pb = tmp_path / "a.json", tmp_path / "b.json"
-            a.dump_json(str(pa))
-            b.dump_json(str(pb))
-            fa = MeasuredCostTable.from_ledger_json(str(pa), base=cm)
-            fb = MeasuredCostTable.from_ledger_json(str(pb), base=cm)
+            # A temp dir per example: hypothesis runs the body many times
+            # under one function-scoped fixture, so the dir is made here.
+            with tempfile.TemporaryDirectory() as d:
+                pa, pb = os.path.join(d, "a.json"), os.path.join(d, "b.json")
+                a.dump_json(pa)
+                b.dump_json(pb)
+                fa = MeasuredCostTable.from_ledger_json(pa, base=cm)
+                fb = MeasuredCostTable.from_ledger_json(pb, base=cm)
             assert fa.fingerprint() == fb.fingerprint()
 
         @given(
@@ -925,12 +937,13 @@ if HAVE_HYPOTHESIS:
 
         @given(restore=st.lists(energies, min_size=1, max_size=30))
         @settings(max_examples=40, deadline=None)
-        def test_json_round_trip_property(self, restore, tmp_path):
+        def test_json_round_trip_property(self, restore):
             mt = _stats_table(analytical_cost_model("time"), restore=restore)
-            path = tmp_path / "calib.json"
-            mt.to_json(str(path))
-            assert MeasuredCostTable.from_json(
-                str(path)).fingerprint() == mt.fingerprint()
+            with tempfile.TemporaryDirectory() as d:
+                path = os.path.join(d, "calib.json")
+                mt.to_json(path)
+                loaded = MeasuredCostTable.from_json(path)
+            assert loaded.fingerprint() == mt.fingerprint()
 
 else:
 

@@ -1,0 +1,162 @@
+"""Compile the main path's programs for a described TPU v5e, without a chip.
+
+The TPU compiler is installed with JAX and compiles for a topology that is
+described rather than attached, so these tests catch what interpret mode
+cannot: unaligned dynamic slices in a kernel, scoped-VMEM overruns, programs
+that do not fit the chip. Nothing runs; only the shapes are real.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and the fixture is where a test
+worker that cannot load it skips instead of failing collection.
+"""
+
+import dataclasses
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+ARCH = "qwen1.5-0.5b"
+# The 5458-task THERMAL head-count graph: tasks and read slots of its CSR
+# export (tests/test_partition_sweep.py builds the graph itself).
+THERMAL_N, THERMAL_NNZ = 5458, 10908
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    """ShapeDtypeStructs of ``tree``'s leaves, placed on ``sharding``."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding),
+        tree)
+
+
+def _kernel_args(one_chip, n, nnz, nq_pad):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    f32, i32 = jnp.float32, jnp.int32
+    return (
+        s((n + 1,), i32), s((n,), f32), s((n,), f32), s((1,), f32),
+        s((nnz,), f32), s((nnz,), f32),
+        s((nnz,), i32), s((nnz,), i32), s((nnz,), i32),
+        s((nq_pad,), f32),
+    )
+
+
+# Lane widths as ops.sweep_columns pads them: the 9-point Q grid of
+# examples/headcount_full.py, minimax's single lane, exact-K with K = 18.
+@pytest.mark.parametrize("mode,nq_pad", [
+    ("sum", 16), ("minimax", 8), ("exact_k", 24),
+])
+def test_sweep_kernel_compiles_at_thermal_size(one_chip, mode, nq_pad):
+    from repro.kernels.partition_sweep.kernel import sweep_columns_call
+
+    compiled = sweep_columns_call.lower(
+        *_kernel_args(one_chip, THERMAL_N, THERMAL_NNZ, nq_pad),
+        interpret=False, mode=mode, combine_max=mode == "minimax",
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("nq_pad,tile", [(128, 512), (512, 256)])
+def test_vmem_guard_boundary_compiles(one_chip, nq_pad, tile):
+    """The largest N the VMEM guard accepts compiles: the guard never
+    refuses less than the compiler takes at its boundary."""
+    from repro.kernels.partition_sweep.kernel import sweep_columns_call
+    from repro.kernels.partition_sweep.ops import SCOPED_VMEM_BYTES
+    from repro.kernels.partition_sweep.kernel import vmem_bytes
+
+    n = 8
+    while vmem_bytes(n + 8, nq_pad, tile) <= SCOPED_VMEM_BYTES:
+        n += 8
+    sweep_columns_call.lower(
+        *_kernel_args(one_chip, n, 1000, nq_pad), interpret=False, tile=tile,
+    ).compile()
+
+
+def test_scan_dp_compiles_under_x64(one_chip):
+    from repro.configs import resolve_config
+    from repro.core.cost import cost_scalars
+    from repro.core.graph import GraphArrays
+    from repro.core.layer_profile import default_cost_model, lower_config
+    from repro.core.partition_jax import _dp_sweep_jit
+
+    g = lower_config(resolve_config(ARCH, smoke=False), batch=2, seq=72)
+    arrays = g.to_arrays()
+    with jax.enable_x64():
+        ga = {f.name: jnp.asarray(getattr(arrays, f.name))
+              for f in dataclasses.fields(GraphArrays) if f.name != "n_tasks"}
+        cost = jnp.asarray(cost_scalars(default_cost_model("time")))
+        qs = jnp.zeros((65,), jnp.float64)
+        assert cost.dtype == jnp.float64
+        compiled = _dp_sweep_jit.lower(
+            _on(one_chip, ga),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+            _on(one_chip, cost), _on(one_chip, qs),
+        ).compile()
+    assert compiled is not None
+
+
+@pytest.fixture(scope="module")
+def full_width(one_chip):
+    from repro.configs import resolve_config
+    from repro.models import api
+
+    cfg = resolve_config(ARCH, smoke=False)
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (24, 1024, 151936)
+    params, _ = api.init_params(cfg, None)
+    return cfg, _on(one_chip, params)
+
+
+def test_full_width_prefill_compiles(one_chip, full_width):
+    from repro.launch.serve import _step_fns
+
+    cfg, params = full_width
+    prefill, _ = _step_fns(ARCH, False, 40, donate=False)
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32, sharding=one_chip)
+    compiled = prefill.lower(params, {"tokens": tokens}).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("donate", [False, True])
+def test_full_width_decode_step_compiles(one_chip, full_width, donate):
+    from repro.launch.serve import _step_fns
+    from repro.models import api
+
+    cfg, params = full_width
+    _, decode = _step_fns(ARCH, False, 40, donate=donate)
+    cache, _ = api.cache_shape(cfg, 2, 40)
+    compiled = decode.lower(
+        params, _on(one_chip, cache),
+        jax.ShapeDtypeStruct((2, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

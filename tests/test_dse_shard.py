@@ -139,6 +139,24 @@ def test_sweep_jax_sharded_pallas_chunks_match():
             getattr(got[0], field).tobytes(), field
 
 
+def test_explicit_devices_too_few_for_shards_raise():
+    """Named devices are a placement: a build asked to put 2 shards on one
+    named device fails instead of running the shards back to back."""
+    import jax
+
+    from repro.api import PartitionSpec, QGridSharding, solve
+
+    rng = random.Random(3)
+    g = random_task_graph(rng, max_tasks=6, min_tasks=3)
+    cm = random_cost_model(rng)
+    qs = tuple(random_q_grid(rng, q_min(g, cm),
+                             whole_app_partition(g, cm).e_total))
+    spec = PartitionSpec(graph=g, cost=cm, q_grid=qs, backend="scan",
+                         sharding=QGridSharding(2, jax.devices()[:1]))
+    with pytest.raises(ValueError, match="2 Q shards need 2 devices, got 1"):
+        solve(spec)
+
+
 # -- table level: sharded builds are byte-identical ----------------------------
 
 

@@ -643,3 +643,43 @@ def test_full_headcount_minimax_exactk_pallas_vs_numpy():
                             backend="pallas")).partition()
     assert p.bounds == ref.bounds and p.e_total == ref.e_total
     assert p.n_bursts == 18
+
+
+# -- compiled-path guards (checked on the CPU before anything lowers) --------
+
+
+def test_vmem_estimate_matches_compiler_report():
+    """The resident tables + scratch at N=5458, nq_pad=256 are the 18.92 MiB
+    the v5e compiler reports when it refuses that size (tests/
+    test_chip_compile.py compiles the boundary); the body's temporaries,
+    one (B, nq) tile and six (B, 1) columns, come on top."""
+    from repro.kernels.partition_sweep.kernel import vmem_bytes
+
+    tables = 5464 * 256 * 8 + 5632 * 256 * 4 + 5632 * 128 * 4 + 256 * 8
+    assert round(tables / 2**20, 2) == 18.92
+    assert vmem_bytes(5458, 256) == tables + 512 * (6 * 128 + 256) * 4
+    # (8, 128) tiling: lane widths round up to 128, rows to 8
+    assert vmem_bytes(5458, 16) == vmem_bytes(5458, 128)
+    assert vmem_bytes(13, 8) == vmem_bytes(16, 8)
+
+
+def test_vmem_guard_refuses_before_lowering():
+    from repro.kernels.partition_sweep.ops import (
+        SCOPED_VMEM_BYTES, VmemLimitExceeded,
+    )
+
+    g = build_graph(THERMAL)
+    csr = g.to_csr_arrays()
+    traced = sweep_kernel.TRACE_COUNT["sweep_columns"]
+    # 1024 lanes x 5458 tasks: ~67 MiB resident against 16 MiB
+    with pytest.raises(VmemLimitExceeded, match="limit is 16 MiB"):
+        sweep_columns(csr, CM, list(np.linspace(0.1, 3.0, 1024)),
+                      interpret=False)
+    assert sweep_kernel.TRACE_COUNT["sweep_columns"] == traced
+    assert SCOPED_VMEM_BYTES == 16 * 2**20
+
+
+def test_chunked_slots_refused_on_compiled_path():
+    csr = build_graph(THERMAL.reduced(16)).to_csr_arrays()
+    with pytest.raises(ValueError, match="interpret mode only"):
+        sweep_columns(csr, CM, [None], slot_chunk=8, interpret=False)

@@ -1,37 +1,13 @@
 """Sharding resolver invariants: dedupe, divisibility, greedy axis skipping."""
 
-import jax
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.models.sharding import logical_to_spec, rules_for
 
-try:
-    from jax.sharding import AbstractMesh
-except ImportError:  # pre-0.4.31 jax has no AbstractMesh at all
-    AbstractMesh = None
-
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 1, reason="no devices"),
-    pytest.mark.skipif(AbstractMesh is None, reason="AbstractMesh unavailable"),
-]
-
-
-def fake_mesh(shape, axes):
-    """AbstractMesh stands in for a device mesh (no allocation).
-
-    The constructor signature changed across jax releases: newer versions
-    take ``(axis_sizes, axis_names)``, 0.4.x takes a single tuple of
-    ``(name, size)`` pairs. Try the new form first and fall back.
-    """
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
-SINGLE = fake_mesh((16, 16), ("data", "model")) if AbstractMesh else None
-MULTI = fake_mesh((2, 16, 16), ("pod", "data", "model")) if AbstractMesh else None
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestResolver:
@@ -76,7 +52,7 @@ class TestResolver:
             logical_to_spec(("nope",), rules_for("dense"), SINGLE, shape=(8,))
 
     def test_smoke_mesh_all_replicated(self):
-        tiny = fake_mesh((1, 1), ("data", "model"))
+        tiny = AbstractMesh((1, 1), ("data", "model"))
         r = rules_for("dense")
         spec = logical_to_spec(("batch", "act_seq", None), r, tiny,
                                shape=(2, 32, 64))
