@@ -828,9 +828,9 @@ def telemetry_overhead(smoke=False, json_out=None):
         if TRACER.enabled:
             pass
     guard_ns = (time.perf_counter() - t0) / n_checks * 1e9
-    # sites per request: ~3 arrival/admission events + ~4 per cycle
-    # (cycle span, harvest sample, burst span, commit instant)
-    sites_per_req = 3 + 4 * gen
+    # sites per request: ~3 arrival/admission events + ~5 per cycle
+    # (cycle span, harvest sample, burst, restore and commit spans)
+    sites_per_req = 3 + 5 * gen
     disabled_us_req = guard_ns * sites_per_req / 1e3
 
     # the pace the <1% bound is charged against: the measured real-model

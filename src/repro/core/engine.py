@@ -1043,13 +1043,12 @@ class Engine:
             graphs=len(graphs),
             q_points=len(spec.q_values),
         ):
-            with TRACER.span("engine.dispatch", cat="engine", pid=PID_SOLVER, backend=label):
-                if "+" in label:
-                    # mixed auto batch: the jit dispatcher groups per backend,
-                    # exactly like the legacy batched entry point did
-                    payload = _JitBackend().solve(req)
-                else:
-                    payload = backend_info(label, self._registry).factory().solve(req)
+            if "+" in label:
+                # mixed auto batch: the jit dispatcher groups per backend,
+                # exactly like the legacy batched entry point did
+                payload = _JitBackend().solve(req)
+            else:
+                payload = backend_info(label, self._registry).factory().solve(req)
         return Solution(
             spec=spec,
             backend=label,

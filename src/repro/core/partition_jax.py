@@ -76,6 +76,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs.metrics import METRICS
+from ..obs.trace import PID_SOLVER, TRACER
 from ._cache import weak_id_cache
 from ._deprecation import warn_legacy
 from .cost import CostModel, cost_scalars
@@ -543,8 +544,15 @@ def sweep_from_columns(
     burst achieving it — the convention emitted by the Pallas sweep kernel
     (:mod:`repro.kernels.partition_sweep`) and its numpy CSR oracle. The
     numpy parent-walk here produces bit-identical bounds to the scan
-    backend's in-jit reconstruction.
+    backend's in-jit reconstruction. Traced as ``sweep.assemble``.
     """
+    if not TRACER.enabled:
+        return _sweep_from_columns(n_tasks, q_values, mns, bests)
+    with TRACER.span("sweep.assemble", cat="engine", pid=PID_SOLVER):
+        return _sweep_from_columns(n_tasks, q_values, mns, bests)
+
+
+def _sweep_from_columns(n_tasks, q_values, mns, bests) -> JaxSweep:
     N, nq = mns.shape
     dp = np.concatenate([np.zeros((nq, 1)), mns.T], axis=1)
     parent = np.zeros((nq, N + 1), dtype=np.int32)

@@ -206,10 +206,11 @@ class BurstRuntime:
         self._attempted.add(b)
 
         # DMA in: dependency-optimized load set
-        load_set = self._load_set(i, j)
-        for name in load_set:
-            volatile[name] = self.nvm.read(name)
-            self.stats.bytes_loaded += g.packets[name].nbytes
+        if TRACER.enabled:
+            with TRACER.span("runtime.restore", cat="runtime", pid=PID_RUNTIME, index=b):
+                self._restore(i, j, volatile)
+        else:
+            self._restore(i, j, volatile)
         self._maybe_crash(b, "loaded")
 
         # execute tasks on volatile memory only
@@ -227,19 +228,13 @@ class BurstRuntime:
             self.stats.tasks_run += 1
         self._maybe_crash(b, "executed")
 
-        # DMA out: packets needed by later bursts
-        store_set = self._store_set(i, j)
-        for name in store_set:
-            self.nvm.write(name, volatile[name])
-            self.stats.bytes_stored += g.packets[name].nbytes
-        self._maybe_crash(b, "stored")
-
-        # linearization point
-        self.nvm.commit_index(b + 1)
+        if TRACER.enabled:
+            with TRACER.span("runtime.commit", cat="runtime", pid=PID_RUNTIME, index=b):
+                self._commit(b, i, j, volatile)
+        else:
+            self._commit(b, i, j, volatile)
         self.stats.bursts_run += 1
         COMMIT_STATS["commits"] += 1
-        if TRACER.enabled:
-            TRACER.instant("nvm_commit", cat="runtime", pid=PID_RUNTIME, index=b)
         if self.cost is not None:
             self.stats.energy += detail.total
         if self.on_commit is not None:
@@ -247,6 +242,20 @@ class BurstRuntime:
             # linearization point so a crash inside it cannot lose the burst
             self.on_commit(b)
         # power off: volatile memory is dropped on return
+
+    def _restore(self, i: int, j: int, volatile: Dict[str, Any]) -> None:
+        for name in self._load_set(i, j):
+            volatile[name] = self.nvm.read(name)
+            self.stats.bytes_loaded += self.graph.packets[name].nbytes
+
+    def _commit(self, b: int, i: int, j: int, volatile: Dict[str, Any]) -> None:
+        # DMA out: packets needed by later bursts
+        for name in self._store_set(i, j):
+            self.nvm.write(name, volatile[name])
+            self.stats.bytes_stored += self.graph.packets[name].nbytes
+        self._maybe_crash(b, "stored")
+        # linearization point
+        self.nvm.commit_index(b + 1)
 
     def _load_set(self, i: int, j: int) -> Tuple[str, ...]:
         g = self.graph

@@ -19,7 +19,13 @@ Serving-path notes (ROADMAP "hoist dtype handling"):
   calls reuse one trace (asserted by tests/test_partition_sweep.py).
 * the priced slot arrays are device-cached per ``(export, cost model,
   dtype)``, so a serving loop re-solving one application across request
-  shapes uploads the graph once, not per request.
+  shapes uploads the graph once, not per request. ``UPLOAD_COUNT`` counts
+  the cache's hits and misses.
+
+With ``repro.obs`` tracing on, one launch emits the spans ``sweep.price``
+and ``sweep.upload`` (on a cache miss only), ``sweep.launch`` (the
+dispatch) and ``sweep.readback`` (the host waiting for the kernel, then the
+copy back); off, each site costs one ``TRACER.enabled`` check.
 """
 
 from __future__ import annotations
@@ -34,6 +40,8 @@ import jax.numpy as jnp
 from ...core._cache import weak_id_cache
 from ...core.cost import CostModel
 from ...core.graph import GraphCSRArrays
+from ...obs.metrics import METRICS
+from ...obs.trace import PID_SOLVER, TRACER
 from .kernel import sweep_columns_call, vmem_bytes
 from .ref import (  # noqa: F401  (re-exported oracles)
     _ABS,
@@ -75,25 +83,51 @@ def _needs_interpret() -> bool:
 # core/_cache.py for the id+weakref idiom).
 _DEVICE_CACHE: dict = {}
 
+# Lookups of _DEVICE_CACHE: a miss prices the slots and uploads them.
+UPLOAD_COUNT = METRICS.counter_dict(
+    "kernel.partition_sweep.upload", ("hit", "miss"))
+
+
+def _span(name: str):
+    return TRACER.span(name, cat="kernel", pid=PID_SOLVER)
+
 
 def _device_slots(csr: GraphCSRArrays, cost: CostModel, dtype) -> tuple:
-    def upload():
-        slot_cost, slot_free = slot_costs(csr, cost)
-        store_add = store_add_ref(csr, cost)
-        return (
-            jnp.asarray(csr.read_ptr),
-            jnp.asarray(csr.e_task, dtype=dtype),
-            jnp.asarray(store_add, dtype=dtype),
-            jnp.asarray(np.array([cost.e_startup]), dtype=dtype),
-            jnp.asarray(slot_cost, dtype=dtype),
-            jnp.asarray(slot_free, dtype=dtype),
-            jnp.asarray(csr.read_lt),
-            jnp.asarray(csr.read_writer),
-            jnp.asarray(csr.read_linf),
-        )
+    miss = False
 
-    return weak_id_cache(
+    def upload():
+        nonlocal miss
+        miss = True
+        if not TRACER.enabled:
+            return _upload(csr, cost, dtype, *_price(csr, cost))
+        with _span("sweep.price"):
+            priced = _price(csr, cost)
+        with _span("sweep.upload"):
+            return _upload(csr, cost, dtype, *priced)
+
+    slots = weak_id_cache(
         _DEVICE_CACHE, csr, (cost, np.dtype(dtype).name), upload
+    )
+    UPLOAD_COUNT["miss" if miss else "hit"] += 1
+    return slots
+
+
+def _price(csr: GraphCSRArrays, cost: CostModel) -> tuple:
+    slot_cost, slot_free = slot_costs(csr, cost)
+    return slot_cost, slot_free, store_add_ref(csr, cost)
+
+
+def _upload(csr, cost, dtype, slot_cost, slot_free, store_add) -> tuple:
+    return (
+        jnp.asarray(csr.read_ptr),
+        jnp.asarray(csr.e_task, dtype=dtype),
+        jnp.asarray(store_add, dtype=dtype),
+        jnp.asarray(np.array([cost.e_startup]), dtype=dtype),
+        jnp.asarray(slot_cost, dtype=dtype),
+        jnp.asarray(slot_free, dtype=dtype),
+        jnp.asarray(csr.read_lt),
+        jnp.asarray(csr.read_writer),
+        jnp.asarray(csr.read_linf),
     )
 
 
@@ -183,14 +217,14 @@ def sweep_columns(
             )
 
     with jax.enable_x64(bool(interpret)):
-        args = _device_slots(csr, cost, dtype)
-        mns, bests = sweep_columns_call(
-            *args,
-            jnp.asarray(budget, dtype=dtype),
-            tile=tile,
-            slot_chunk=slot_chunk,
-            interpret=bool(interpret),
-            mode=objective,
-            combine_max=combine_max,
-        )
-        return np.asarray(mns)[:, :nq], np.asarray(bests)[:, :nq]
+        args = (*_device_slots(csr, cost, dtype),
+                jnp.asarray(budget, dtype=dtype))
+        kw = dict(tile=tile, slot_chunk=slot_chunk, interpret=bool(interpret),
+                  mode=objective, combine_max=combine_max)
+        if not TRACER.enabled:
+            mns, bests = sweep_columns_call(*args, **kw)
+            return np.asarray(mns)[:, :nq], np.asarray(bests)[:, :nq]
+        with _span("sweep.launch"):
+            mns, bests = sweep_columns_call(*args, **kw)
+        with _span("sweep.readback"):
+            return np.asarray(mns)[:, :nq], np.asarray(bests)[:, :nq]

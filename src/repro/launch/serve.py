@@ -40,7 +40,7 @@ import numpy as np
 from ..configs import resolve_config
 from ..models import api
 from ..obs.metrics import METRICS
-from ..obs.trace import TRACER
+from ..obs.trace import PID_RUNTIME, PID_TRAFFIC, TRACER
 from ..models.sharding import rules_for
 from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
@@ -129,6 +129,21 @@ def _cache_nbytes(cfg, batch: int, max_seq: int) -> int:
     )
 
 
+def _in_span(name: str, fn, *args):
+    """``fn(*args)``, inside a ``name`` span on the runtime track while
+    tracing is on; off, the site costs one ``TRACER.enabled`` check."""
+    if not TRACER.enabled:
+        return fn(*args)
+    with TRACER.span(name, cat="serve", pid=PID_RUNTIME):
+        return fn(*args)
+
+
+def _append_token(seq: Optional[np.ndarray], tok) -> np.ndarray:
+    # The host reads the step's token back, so it waits for the step.
+    t = np.asarray(tok)
+    return t if seq is None else np.concatenate([seq, t], axis=1)
+
+
 def _request_graph(cfg, params, batch, prompt_len, gen, max_seq,
                    prefill_fn, decode_fn, step_energy):
     """The request as a Ladybirds task graph: task 1 = prefill (emits token
@@ -137,6 +152,10 @@ def _request_graph(cfg, params, batch, prompt_len, gen, max_seq,
     ``sequence`` output. Task bodies are pure functions of their declared
     inputs — the cached jitted steps are deterministic — so replayed cycles
     are idempotent, exactly the contract BurstRuntime's recovery relies on.
+
+    Traced, each body is a ``serve.prefill`` or ``serve.decode`` span whose
+    child ``serve.token_sync`` is the token's readback and the sequence's
+    concatenation.
     """
     from ..core import GraphBuilder
 
@@ -156,8 +175,9 @@ def _request_graph(cfg, params, batch, prompt_len, gen, max_seq,
         def fn(inp):
             logits, cache = prefill_fn(params, _pre_batch(cfg, inp["prompts"]))
             tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-            return emit(0, cache, tok, np.asarray(tok))
-        return fn
+            seq = _in_span("serve.token_sync", _append_token, None, tok)
+            return emit(0, cache, tok, seq)
+        return lambda inp: _in_span("serve.prefill", fn, inp)
 
     def mk_decode(k: int):
         def fn(inp):
@@ -166,9 +186,9 @@ def _request_graph(cfg, params, batch, prompt_len, gen, max_seq,
                 params, st["cache"], st["tok"], jnp.int32(prompt_len + k - 1)
             )
             tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-            seq = np.concatenate([st["seq"], np.asarray(tok)], axis=1)
+            seq = _in_span("serve.token_sync", _append_token, st["seq"], tok)
             return emit(k, cache, tok, seq)
-        return fn
+        return lambda inp: _in_span("serve.decode", fn, inp)
 
     b.task("prefill", reads=("prompts",),
            writes=("sequence",) if gen == 1 else ("state0",),
@@ -233,7 +253,22 @@ class PlannedExecutor:
         ``planner.stats``). External inputs are seeded only on a fresh NVM
         (committed index 0), so reopening against a mid-request NVM resumes
         rather than restarts — the crash-recovery contract.
+
+        Traced as one ``serve.open`` span carrying the ``rid`` this executor
+        gives the request (a traffic harness relabels the continuation with
+        its own request afterwards).
         """
+        kw = dict(seed=seed, cycle_budget=cycle_budget, prompts=prompts,
+                  plan=plan, nvm=nvm, crash_hook=crash_hook)
+        if not TRACER.enabled:
+            return self._open(batch, prompt_len, gen, **kw)
+        with TRACER.span("serve.open", cat="serve", pid=PID_TRAFFIC,
+                         rid=self._next_rid, batch=batch,
+                         prompt_len=prompt_len, gen=gen):
+            return self._open(batch, prompt_len, gen, **kw)
+
+    def _open(self, batch, prompt_len, gen, *, seed, cycle_budget, prompts,
+              plan, nvm, crash_hook) -> Continuation:
         from ..core import BurstRuntime, CostModel, LinearTransfer, Partition
         from ..core.burst import burst_detail
         from .planner import request_cycles

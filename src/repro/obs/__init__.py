@@ -13,9 +13,12 @@ without dragging in jax):
 - :mod:`repro.obs.trace` — a span tracer emitting Chrome ``trace_event``
   JSON loadable in Perfetto (https://ui.perfetto.dev). Spans carry wall-clock
   timestamps (the trace timeline) and, where the caller has one, the
-  harness's virtual-clock time in ``args.vt``. Disabled by default; when
-  disabled every ``span()`` returns a shared no-op context manager and hot
-  paths guard on ``TRACER.enabled`` so tracing costs one attribute check.
+  harness's virtual-clock time in ``args.vt``, an ``id`` and the ``parent``
+  id; while enabled (after jax is imported), each span is also a
+  ``jax.profiler`` annotation on the device trace's clock. Disabled by
+  default; when disabled every ``span()`` returns a shared no-op context
+  manager and hot paths guard on ``TRACER.enabled`` so tracing costs one
+  attribute check.
 - :mod:`repro.obs.ledger` — per-request / per-cycle attribution of tabulated
   energy draw into restore (E_s), compute, and NVM-commit categories, plus a
   replay-overhead category, with a conservation check against the

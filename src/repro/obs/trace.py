@@ -12,6 +12,17 @@ Design points:
   was enabled — that is what Perfetto renders. Callers that live on the
   traffic harness's virtual clock pass ``vt=...`` and the virtual timestamp
   rides along in the event ``args`` so both timelines are recoverable.
+- **The profiler's clock too.** While enabled and while a
+  ``jax.profiler`` session records, every span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the profile holds
+  the span on its host plane, on the same clock as the device's ops. The
+  annotation is bound in ``configure(enabled=True)`` only when jax is
+  already imported: this module never imports jax itself.
+- **Parents.** Every ``X`` event carries an ``id`` and the ``parent`` id of
+  the innermost span open when it began (``None`` at the top), so a
+  reader computes a span's self time (its duration less its children's).
+  A span without a ``rid`` arg takes its parent's, so every span inside a
+  request's ``cycle`` names the request.
 - **Tracks.** ``pid``/``tid`` pairs map to Perfetto tracks; ``set_process``
   / ``set_thread`` emit the ``ph:"M"`` metadata events that name them. The
   traffic harness uses one tid per request plus scheduler and harvest
@@ -23,7 +34,9 @@ Event phases used: ``X`` (complete span, ``ts``+``dur``), ``i`` (instant),
 
 from __future__ import annotations
 
+import itertools
 import json
+import sys
 import time
 from typing import Any, Dict, List, Optional
 
@@ -85,7 +98,8 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """An open ``ph:"X"`` complete event; closing the context records it."""
 
-    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0",
+                 "id", "parent", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, pid: int, tid: int, args: Dict[str, Any]):
         self._tracer = tracer
@@ -100,12 +114,35 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self):
+        tracer = self._tracer
+        self.id = next(tracer._ids)
+        self.parent = None
+        if tracer._open:
+            outer = tracer._open[-1]
+            self.parent = outer.id
+            if "rid" in outer.args and "rid" not in self.args:
+                self.args["rid"] = outer.args["rid"]
+        tracer._open.append(self)
+        # Annotate only while a profiler session records; otherwise the
+        # annotation would cost about a microsecond a span for nothing.
+        note = tracer._annotation
+        self._note = note(self.name) if note is not None and note.is_enabled() else None
+        if self._note is not None:
+            self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
         tracer = self._tracer
+        # Spans close in LIFO order on one thread; a clear() while this one
+        # was open has already dropped it.
+        if tracer._open and tracer._open[-1] is self:
+            tracer._open.pop()
+        elif self in tracer._open:
+            tracer._open.remove(self)
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         ev: Dict[str, Any] = {
@@ -116,6 +153,8 @@ class _Span:
             "dur": (t1 - self._t0) * 1e6,
             "pid": self.pid,
             "tid": self.tid,
+            "id": self.id,
+            "parent": self.parent,
         }
         if self.args:
             ev["args"] = self.args
@@ -131,16 +170,27 @@ class Tracer:
         self._events: List[Dict[str, Any]] = []
         self._t0 = time.perf_counter()
         self._tracks: Dict[Any, str] = {}
+        self._open: List[_Span] = []
+        self._ids = itertools.count(1)
+        self._annotation: Optional[Any] = None  # jax.profiler.TraceAnnotation
 
     # -- lifecycle ---------------------------------------------------------
 
     def configure(self, enabled: bool = True, clear: bool = True) -> None:
         """Turn tracing on/off. ``clear`` drops buffered events and re-zeroes
-        the wall-clock origin so a fresh capture starts at ts=0."""
+        the wall-clock origin so a fresh capture starts at ts=0. Enabling
+        binds the profiler annotation if jax is already imported."""
         if clear:
             self._events = []
             self._tracks = {}
+            self._open = []
+            self._ids = itertools.count(1)
             self._t0 = time.perf_counter()
+        self._annotation = None
+        if enabled and "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self.enabled = enabled
 
     def disable(self) -> None:

@@ -160,3 +160,49 @@ def test_full_width_decode_step_compiles(one_chip, full_width, donate):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+# -- the program names the benchmark's trace readers match ---------------------
+
+def _reader_constant(metric: str, name: str) -> str:
+    import importlib.util
+    import pathlib
+
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "metrics" / f"{metric}.py")
+    spec = importlib.util.spec_from_file_location(f"reader_{metric}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return getattr(mod, name)
+
+
+def test_decode_step_lowers_under_the_name_its_reader_matches():
+    """``decode_step_ms`` finds the decode program by its module name."""
+    from repro.configs import resolve_config
+    from repro.launch.serve import _step_fns
+    from repro.models import api
+
+    cfg = resolve_config(ARCH, smoke=True)
+    params, _ = api.init_params(cfg, None)
+    cache, _ = api.cache_shape(cfg, 1, 40)
+    _, decode = _step_fns(ARCH, True, 40, donate=False)
+    lowered = decode.lower(
+        _on(None, params), _on(None, cache),
+        jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    assert lowered.as_text().startswith("module @jit__decode ")
+    assert _reader_constant("decode_step_ms", "PROGRAM") in "jit__decode"
+
+
+def test_sweep_kernel_compiles_under_the_name_its_reader_matches(one_chip):
+    """``sweep_kernel_roofline`` finds the kernel by its custom call's name
+    on the op line, which the jitted wrapper gives it."""
+    from repro.kernels.partition_sweep.kernel import sweep_columns_call
+
+    compiled = sweep_columns_call.lower(
+        *_kernel_args(one_chip, 64, 100, 8), interpret=False, mode="sum",
+    ).compile()
+    calls = [line.split("=")[0].strip() for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0].startswith("%sweep_columns_call")
+    assert calls[0].startswith(_reader_constant("sweep_kernel_roofline", "KERNEL"))

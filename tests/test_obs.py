@@ -9,6 +9,7 @@ tests/test_traffic.py and in CI's traffic smoke.
 """
 
 import json
+import os
 
 import pytest
 
@@ -156,6 +157,67 @@ def test_span_schema_and_nesting():
     # monotonic nesting: inner is contained in outer on the same track
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
+
+
+def test_span_ids_parents_and_self_time():
+    t = _fresh_tracer()
+    with t.span("outer"):
+        with t.span("first"):
+            with t.span("leaf"):
+                pass
+        with t.span("second"):
+            pass
+    with t.span("next"):
+        pass
+    ev = {e["name"]: e for e in t.events()}
+    assert len({e["id"] for e in ev.values()}) == 5
+    assert ev["outer"]["parent"] is None and ev["next"]["parent"] is None
+    assert ev["first"]["parent"] == ev["second"]["parent"] == ev["outer"]["id"]
+    assert ev["leaf"]["parent"] == ev["first"]["id"]
+    own = ev["outer"]["dur"] - ev["first"]["dur"] - ev["second"]["dur"]
+    assert 0 <= own <= ev["outer"]["dur"]
+    # a clear while a span is open drops it from the new capture's parents
+    with t.span("open"):
+        t.configure(enabled=True)
+        with t.span("after"):
+            pass
+    (after,) = [e for e in t.events() if e["name"] == "after"]
+    assert after["parent"] is None and after["id"] == 1
+
+
+def test_span_inherits_the_request_id_of_its_parent():
+    t = _fresh_tracer()
+    with t.span("cycle", rid=3):
+        with t.span("burst"):
+            with t.span("serve.decode"):
+                pass
+        with t.span("other", rid=4):
+            pass
+    with t.span("alone"):
+        pass
+    rid = {e["name"]: e.get("args", {}).get("rid") for e in t.events()}
+    assert rid == {"cycle": 3, "burst": 3, "serve.decode": 3, "other": 4,
+                   "alone": None}
+
+
+def test_obs_imports_and_traces_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro.obs import TRACER\n"
+        "TRACER.configure(enabled=True)\n"
+        "with TRACER.span('work'):\n"
+        "    pass\n"
+        "assert [e['name'] for e in TRACER.events()] == ['work']\n"
+        "assert TRACER._annotation is None\n"
+        "assert 'jax' not in sys.modules, 'repro.obs imported jax'\n"
+    )
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "src")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env=dict(os.environ, PYTHONPATH=src))
 
 
 def test_span_records_exception_and_reraises():
@@ -619,10 +681,20 @@ def test_engine_solve_emits_spans():
     try:
         solve(PartitionSpec(graph=g, cost=cm, q_max=2.0, backend="numpy"))
         events = TRACER.events()
+        solve(PartitionSpec(graph=g, cost=cm, q_max=2.0, backend="pallas",
+                            interpret=True))
+        kernel = TRACER.events()[len(events):]
     finally:
         TRACER.reset()
     solves = [e for e in events if e["name"] == "engine.solve"]
     assert len(solves) == 1
     assert solves[0]["pid"] == PID_SOLVER
     assert solves[0]["args"]["backend"] == "numpy"
-    assert any(e["name"] == "engine.dispatch" for e in events)
+    # the numpy backend launches no kernel; the pallas solve's kernel steps
+    # are children of its own engine.solve
+    assert not [e for e in events if e["name"].startswith("sweep.")]
+    (pallas,) = [e for e in kernel if e["name"] == "engine.solve"]
+    steps = [e for e in kernel if e["name"].startswith("sweep.")]
+    assert {e["name"] for e in steps} >= {
+        "sweep.launch", "sweep.readback", "sweep.assemble"}
+    assert all(e["parent"] == pallas["id"] for e in steps)
