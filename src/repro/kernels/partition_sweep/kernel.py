@@ -1,11 +1,13 @@
 """Fused CSR column-sweep + multi-Q DP — Pallas TPU kernel (paper §4.2–§4.3).
 
 One kernel juliennes a whole application: it walks tasks j = 1..N carrying
-the live burst column E⟨·,j⟩ and the DP table, with a grid of
-``(N, n_tiles)`` — the minor grid axis is **one program per column tile of
-i-indices**, so each program owns a ``(tile, 1)`` slice of the column and a
-``(tile, nq)`` slice of the DP candidates, all resident in VMEM scratch
-across the sequential grid.
+the live burst column E⟨·,j⟩ and the DP table, with a grid of ``(N,)`` —
+**one program per column**. The column and the DP table are cut into
+i-tiles of ``tile`` rows, resident in VMEM scratch across the sequential
+grid, and program j loops over the ⌈j/tile⌉ tiles that hold a live burst
+⟨i, j⟩ (i ≤ j), each a ``(tile, 1)`` slice of the column and a
+``(tile, nq)`` slice of the DP candidates; the tiles above hold nothing yet
+and are skipped (:func:`tile_bodies` counts the tiles visited).
 
 Read-slot contributions come from the CSR-style compressed slot layout of
 :class:`repro.core.graph.GraphCSRArrays` (flat ``slot_task_ptr`` /
@@ -13,7 +15,7 @@ Read-slot contributions come from the CSR-style compressed slot layout of
 of the dense ``(N, R)`` rectangle), held in SMEM so that the compiled kernel
 can read slot ``p0 + s`` as a scalar at a dynamic index: each program loops
 over task j's slot range and applies the three piecewise-constant updates
-in-register:
+in-register, tile by tile:
 
     E⟨i,j⟩ = E⟨i,j-1⟩ + E_task(j) + S(j)
            + Σ_{p ∈ reads(j)}  E_r(p) · [i > l_j(p)]             (new loads)
@@ -39,7 +41,9 @@ modes now):
   or ``max`` per the static ``combine_max`` flag (the pipeline-bottleneck
   variant).
 
-Every mode tie-breaks its argmin to the smallest burst start. With
+Every mode tie-breaks its argmin to the smallest burst start: the tiles
+are visited in order and a later tile wins only with a strictly smaller
+value. With
 ``slot_chunk=1`` (default) the slot loop replays numpy's exact
 accumulation order, so the emitted column tables are bit-identical to
 :mod:`.ref` — and hence to the numpy DP oracles — including argmin
@@ -98,8 +102,6 @@ def _sweep_kernel(
     best_ref,         # (N, nq_pad)  i32  out   argmin burst start per column
     colbuf,           # (Npad, 1)    f    VMEM scratch: live column E⟨·,j⟩
     dpbuf,            # (Npad, nq)   f    VMEM scratch: dp[q, i-1] table
-    accmin,           # (1, nq_pad)  f    VMEM scratch: cross-tile running min
-    accarg,           # (1, nq_pad)  i32  VMEM scratch: cross-tile argmin
     *,
     n_tiles: int,
     tile: int,
@@ -110,11 +112,9 @@ def _sweep_kernel(
 ):
     B, C = tile, slot_chunk
     j = pl.program_id(0) + np.int32(1)   # task / column index, 1..N
-    t = pl.program_id(1)                 # i-tile index, 0..n_tiles-1
-    base = t * np.int32(B)
 
     # Shared scratch is initialized by the very first program in the grid.
-    @pl.when((j == 1) & (t == 0))
+    @pl.when(j == 1)
     def _():
         dpbuf[...] = jnp.full(dpbuf.shape, jnp.inf, dtype)
         if mode == "exact_k":
@@ -125,120 +125,130 @@ def _sweep_kernel(
             dpbuf[0, :] = jnp.zeros((dpbuf.shape[1],), dtype)  # dp[q, 0] = 0
         colbuf[...] = jnp.zeros(colbuf.shape, dtype)
 
-    i_vec = base + np.int32(1) + lax.broadcasted_iota(jnp.int32, (B, 1), 0)
-    prev = i_vec < j                      # bursts ⟨i, j-1⟩ being extended
     e_j = etask_ref[j - 1]
     s_j = store_ref[j - 1]
-    colt = colbuf[pl.ds(base, B), :]
-    colt = jnp.where(prev, colt + (e_j + s_j), colt)
-
     p0 = ptr_ref[j - 1]
     p1 = ptr_ref[j]
+    rows = lax.broadcasted_iota(jnp.int32, (B, 1), 0)
 
-    if C == 1:
-        # Slot-at-a-time: numpy's exact accumulation order (bit parity).
-        def slot(s, carry):
-            colt, sum_er = carry
-            idx = p0 + s
-            sc = cost_ref[idx]
-            colt = jnp.where(prev & (i_vec > lt_ref[idx]), colt + sc, colt)
-            w = writer_ref[idx]
-            freed = (linf_ref[idx] == j) & (w >= np.int32(1))
-            colt = jnp.where(
-                prev & freed & (i_vec <= w), colt - free_ref[idx], colt
+    def tile_step(t, acc):
+        """Advance i-tile t of column j and fold its candidates into acc."""
+        base = pl.multiple_of(t * np.int32(B), B)
+        i_vec = base + np.int32(1) + rows
+        prev = i_vec < j                  # bursts ⟨i, j-1⟩ being extended
+        colt = colbuf[pl.ds(base, B), :]
+        colt = jnp.where(prev, colt + (e_j + s_j), colt)
+
+        if C == 1:
+            # Slot-at-a-time: numpy's exact accumulation order (bit parity).
+            def slot(s, carry):
+                colt, sum_er = carry
+                idx = p0 + s
+                sc = cost_ref[idx]
+                colt = jnp.where(prev & (i_vec > lt_ref[idx]), colt + sc, colt)
+                w = writer_ref[idx]
+                freed = (linf_ref[idx] == j) & (w >= np.int32(1))
+                colt = jnp.where(
+                    prev & freed & (i_vec <= w), colt - free_ref[idx], colt
+                )
+                return colt, sum_er + sc
+
+            colt, sum_er = lax.fori_loop(
+                0, p1 - p0, slot, (colt, jnp.asarray(0.0, dtype))
             )
-            return colt, sum_er + sc
+        else:
+            # Chunked: one masked 2-D reduction per C slots (~ulp drift).
+            # Interpret mode only (sweep_columns_call refuses it compiled):
+            # a C-wide load from SMEM is no scalar load, and from VMEM its
+            # dynamic lane offset is not provably 128-aligned.
+            def window(ref, idx0):
+                return ref[pl.ds(idx0, C)][None, :]
 
-        colt, sum_er = lax.fori_loop(
-            0, p1 - p0, slot, (colt, jnp.asarray(0.0, dtype))
-        )
-    else:
-        # Chunked: one masked 2-D reduction per C slots (~ulp drift).
-        # Interpret mode only (sweep_columns_call refuses it compiled): a
-        # C-wide load from SMEM is no scalar load, and from VMEM its
-        # dynamic lane offset is not provably 128-aligned.
-        def window(ref, idx0):
-            return ref[pl.ds(idx0, C)][None, :]
+            def chunk(s, carry):
+                colt, sum_er = carry
+                idx0 = p0 + s * np.int32(C)
+                lanes = idx0 + lax.broadcasted_iota(jnp.int32, (1, C), 1)
+                valid = lanes < p1
+                sc = jnp.where(valid, window(cost_ref, idx0), 0.0)
+                sf = jnp.where(valid, window(free_ref, idx0), 0.0)
+                slt = window(lt_ref, idx0)
+                swr = window(writer_ref, idx0)
+                sli = window(linf_ref, idx0)
+                loads = jnp.sum(
+                    jnp.where(i_vec > slt, sc, 0.0), axis=1, keepdims=True
+                )
+                freed = jnp.sum(
+                    jnp.where(
+                        ((sli == j) & (swr >= np.int32(1))) & (i_vec <= swr),
+                        sf,
+                        0.0,
+                    ),
+                    axis=1,
+                    keepdims=True,
+                )
+                colt = jnp.where(prev, colt + loads - freed, colt)
+                return colt, sum_er + jnp.sum(sc)
 
-        def chunk(s, carry):
-            colt, sum_er = carry
-            idx0 = p0 + s * np.int32(C)
-            lanes = idx0 + lax.broadcasted_iota(jnp.int32, (1, C), 1)
-            valid = lanes < p1
-            sc = jnp.where(valid, window(cost_ref, idx0), 0.0)
-            sf = jnp.where(valid, window(free_ref, idx0), 0.0)
-            slt = window(lt_ref, idx0)
-            swr = window(writer_ref, idx0)
-            sli = window(linf_ref, idx0)
-            loads = jnp.sum(
-                jnp.where(i_vec > slt, sc, 0.0), axis=1, keepdims=True
+            nchunks = lax.div(p1 - p0 + np.int32(C - 1), np.int32(C))
+            colt, sum_er = lax.fori_loop(
+                0, nchunks, chunk, (colt, jnp.asarray(0.0, dtype))
             )
-            freed = jnp.sum(
-                jnp.where(
-                    ((sli == j) & (swr >= np.int32(1))) & (i_vec <= swr),
-                    sf,
-                    0.0,
-                ),
-                axis=1,
-                keepdims=True,
+
+        # The new single-task burst ⟨j,j⟩ (left-to-right, ColumnSweep's
+        # order); it lies in the last live tile, the others leave colt be.
+        diag = es_ref[0] + sum_er + e_j + s_j
+        colt = jnp.where(i_vec == j, diag, colt)
+        colbuf[pl.ds(base, B), :] = colt
+
+        # DP relaxation over this tile. dpbuf rows [base, base+B) hold
+        # dp[q, i-1] for the tile's i values; rows ≥ j are still inf, so
+        # beyond-diagonal candidates drop out automatically.
+        dpt = dpbuf[pl.ds(base, B), :]
+        if mode == "exact_k":
+            # Lane b needs dp[b-1, i-1]: shift the burst-count axis one lane
+            # right; lane 0 (zero bursts covering a non-empty prefix)
+            # refills +inf, so the b=0 output row degenerates to an
+            # all-infeasible column (val inf, argmin 1) that callers never
+            # walk.
+            dpt = jnp.concatenate(
+                [jnp.full((B, 1), jnp.inf, dtype), dpt[:, :-1]], axis=1
             )
-            colt = jnp.where(prev, colt + loads - freed, colt)
-            return colt, sum_er + jnp.sum(sc)
-
-        nchunks = lax.div(p1 - p0 + np.int32(C - 1), np.int32(C))
-        colt, sum_er = lax.fori_loop(
-            0, nchunks, chunk, (colt, jnp.asarray(0.0, dtype))
+        masked = jnp.where(colt <= budget_ref[...], colt, jnp.inf)
+        cand = jnp.maximum(dpt, masked) if combine_max else dpt + masked
+        tmin = jnp.min(cand, axis=0, keepdims=True)             # (1, nq_pad)
+        # First i achieving the min (the sentinel never survives: inf == inf
+        # on an all-infeasible column still selects i = 1, like numpy's
+        # argmin — infeasibility is carried by mns, bests are only walked
+        # where finite).
+        targ = jnp.min(
+            jnp.where(cand == tmin, i_vec, np.int32(n_tiles * B + 1)),
+            axis=0,
+            keepdims=True,
         )
 
-    # The new single-task burst ⟨j,j⟩ (left-to-right, ColumnSweep's order).
-    diag = es_ref[0] + sum_er + e_j + s_j
-    colt = jnp.where(i_vec == j, diag, colt)
-    colbuf[pl.ds(base, B), :] = colt
+        # Cross-tile combine: strict < keeps the earliest tile on exact
+        # ties, matching numpy's first-minimum argmin.
+        accmin, accarg = acc
+        better = tmin < accmin
+        return jnp.minimum(accmin, tmin), jnp.where(better, targ, accarg)
 
-    # DP relaxation over this tile. dpbuf rows [base, base+B) hold
-    # dp[q, i-1] for the tile's i values; rows ≥ j are still inf, so
-    # beyond-diagonal candidates drop out automatically.
-    dpt = dpbuf[pl.ds(base, B), :]
-    if mode == "exact_k":
-        # Lane b needs dp[b-1, i-1]: shift the burst-count axis one lane
-        # right; lane 0 (zero bursts covering a non-empty prefix) refills
-        # +inf, so the b=0 output row degenerates to an all-infeasible
-        # column (val inf, argmin 1) that callers never walk.
-        dpt = jnp.concatenate(
-            [jnp.full((B, 1), jnp.inf, dtype), dpt[:, :-1]], axis=1
-        )
-    masked = jnp.where(colt <= budget_ref[...], colt, jnp.inf)
-    cand = jnp.maximum(dpt, masked) if combine_max else dpt + masked
-    tmin = jnp.min(cand, axis=0)                                  # (nq_pad,)
-    # First i achieving the min (the sentinel never survives: inf == inf on
-    # an all-infeasible column still selects i = 1, like numpy's argmin —
-    # infeasibility is carried by mns, bests are only walked where finite).
-    targ = jnp.min(
-        jnp.where(cand == tmin[None, :], i_vec, np.int32(n_tiles * B + 1)),
-        axis=0,
+    # Only the tiles with a row i ≤ j hold live bursts ⟨i, j⟩: the ones above
+    # would leave colbuf as it is and offer only +inf candidates. (+inf, 1) is
+    # what an all-infeasible first tile yields, so folding tile 0 into it
+    # gives tile 0's own (min, argmin).
+    n_live = lax.div(j + np.int32(B - 1), np.int32(B))
+    nq_pad = mns_ref.shape[1]
+    accmin, accarg = lax.fori_loop(
+        0, n_live, tile_step,
+        (jnp.full((1, nq_pad), jnp.inf, dtype),
+         jnp.ones((1, nq_pad), jnp.int32)),
     )
+    mns_ref[pl.ds(j - 1, 1), :] = accmin
+    best_ref[pl.ds(j - 1, 1), :] = accarg
 
-    # Cross-tile combine: strict < keeps the earliest tile on exact ties,
-    # matching numpy's first-minimum argmin.
-    @pl.when(t == 0)
+    @pl.when(j < dpbuf.shape[0])
     def _():
-        accmin[0, :] = tmin
-        accarg[0, :] = targ
-
-    @pl.when(t > 0)
-    def _():
-        better = tmin < accmin[0, :]
-        accarg[0, :] = jnp.where(better, targ, accarg[0, :])
-        accmin[0, :] = jnp.minimum(accmin[0, :], tmin)
-
-    @pl.when(t == n_tiles - 1)
-    def _():
-        mns_ref[pl.ds(j - 1, 1), :] = accmin[0, :][None, :]
-        best_ref[pl.ds(j - 1, 1), :] = accarg[0, :][None, :]
-
-        @pl.when(j < dpbuf.shape[0])
-        def _():
-            dpbuf[pl.ds(j, 1), :] = accmin[0, :][None, :]
+        dpbuf[pl.ds(j, 1), :] = accmin
 
 
 def _tiling(n: int, tile: int) -> tuple:
@@ -247,13 +257,19 @@ def _tiling(n: int, tile: int) -> tuple:
     return b, -(-n // b)
 
 
+def tile_bodies(n: int, tile: int = 512) -> int:
+    """Tile bodies one N-task sweep runs: Σ_j ⌈j/B⌉ over the columns j."""
+    b, t = _tiling(n, tile)
+    return b * t * (t - 1) // 2 + t * (n - (t - 1) * b)
+
+
 def vmem_bytes(n: int, nq_pad: int, tile: int = 512) -> int:
     """Scoped VMEM the compiled kernel allocates for an N × nq_pad sweep.
 
     Mirrors the specs of :func:`sweep_columns_call` under the TPU's (8, 128)
     VMEM tiling: the two resident ``(N, nq_pad)`` output tables plus the
-    ``(T·B, nq_pad)`` ``dpbuf``, ``(T·B, 1)`` ``colbuf`` and two ``(1,
-    nq_pad)`` accumulator scratch buffers — 18.92 MiB at N=5458,
+    ``(T·B, nq_pad)`` ``dpbuf``, ``(T·B, 1)`` ``colbuf`` and the two ``(1,
+    nq_pad)`` accumulators the tile loop carries — 18.92 MiB at N=5458,
     nq_pad=256, exactly what the v5e compiler reports. The slot arrays live
     in SMEM and do not count. On top come the kernel body's temporaries,
     bounded by six ``(B, 1)`` columns and one ``(B, nq_pad)`` tile: fitted
@@ -268,7 +284,7 @@ def vmem_bytes(n: int, nq_pad: int, tile: int = 512) -> int:
         rows * lanes * 2 * f          # mns + bests
         + npad * lanes * f            # dpbuf
         + npad * 128 * f              # colbuf
-        + lanes * 2 * f               # accmin + accarg
+        + lanes * 2 * f               # accmin + accarg, loop-carried
         + b8 * (6 * 128 + lanes) * f  # body temporaries
     )
 
@@ -297,6 +313,9 @@ def sweep_columns_call(
 ):
     """Launch the sweep kernel: → (mns, bests), each ``(N, nq_pad)``.
 
+    One grid program per column j, ``grid=(N,)``; inside it a loop over the
+    ⌈j/B⌉ live i-tiles of B rows (``B = min(tile, max(8, N))``), so a call
+    runs :func:`tile_bodies` tile bodies in all.
     Shapes are static per (N, nnz, nq_pad, tile, slot_chunk); the static
     ``mode`` / ``combine_max`` pair selects the DP combine (see module
     docstring) and keys the jit cache alongside them, so each objective
@@ -330,7 +349,7 @@ def sweep_columns_call(
     def pad1(a):
         return jnp.pad(a, (0, nnz_pad - nnz))
 
-    vspec = lambda shape: pl.BlockSpec(shape, lambda j, t: (0,) * len(shape))
+    vspec = lambda shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))
     sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     kern = functools.partial(
         _sweep_kernel, n_tiles=T, tile=B, slot_chunk=C, dtype=dtype,
@@ -338,7 +357,7 @@ def sweep_columns_call(
     )
     return pl.pallas_call(
         kern,
-        grid=(N, T),
+        grid=(N,),
         in_specs=[
             sspec, sspec, sspec, sspec,
             sspec, sspec, sspec, sspec, sspec,
@@ -352,8 +371,6 @@ def sweep_columns_call(
         scratch_shapes=[
             pltpu.VMEM((T * B, 1), dtype),
             pltpu.VMEM((T * B, nq_pad), dtype),
-            pltpu.VMEM((1, nq_pad), dtype),
-            pltpu.VMEM((1, nq_pad), jnp.int32),
         ],
         interpret=interpret,
     )(

@@ -24,8 +24,10 @@ Serving-path notes (ROADMAP "hoist dtype handling"):
 
 With ``repro.obs`` tracing on, one launch emits the spans ``sweep.price``
 and ``sweep.upload`` (on a cache miss only), ``sweep.launch`` (the
-dispatch) and ``sweep.readback`` (the host waiting for the kernel, then the
-copy back); off, each site costs one ``TRACER.enabled`` check.
+dispatch; its args ``grid_programs`` and ``tile_bodies`` count the kernel's
+grid programs and the i-tile bodies they run) and ``sweep.readback`` (the
+host waiting for the kernel, then the copy back); off, each site costs one
+``TRACER.enabled`` check.
 """
 
 from __future__ import annotations
@@ -42,7 +44,7 @@ from ...core.cost import CostModel
 from ...core.graph import GraphCSRArrays
 from ...obs.metrics import METRICS
 from ...obs.trace import PID_SOLVER, TRACER
-from .kernel import sweep_columns_call, vmem_bytes
+from .kernel import sweep_columns_call, tile_bodies, vmem_bytes
 from .ref import (  # noqa: F401  (re-exported oracles)
     _ABS,
     _REL,
@@ -88,8 +90,8 @@ UPLOAD_COUNT = METRICS.counter_dict(
     "kernel.partition_sweep.upload", ("hit", "miss"))
 
 
-def _span(name: str):
-    return TRACER.span(name, cat="kernel", pid=PID_SOLVER)
+def _span(name: str, **args):
+    return TRACER.span(name, cat="kernel", pid=PID_SOLVER, **args)
 
 
 def _device_slots(csr: GraphCSRArrays, cost: CostModel, dtype) -> tuple:
@@ -224,7 +226,8 @@ def sweep_columns(
         if not TRACER.enabled:
             mns, bests = sweep_columns_call(*args, **kw)
             return np.asarray(mns)[:, :nq], np.asarray(bests)[:, :nq]
-        with _span("sweep.launch"):
+        with _span("sweep.launch", grid_programs=csr.n_pad,
+                   tile_bodies=tile_bodies(csr.n_pad, tile)):
             mns, bests = sweep_columns_call(*args, **kw)
         with _span("sweep.readback"):
             return np.asarray(mns)[:, :nq], np.asarray(bests)[:, :nq]

@@ -38,8 +38,10 @@ from helpers_random import (
 
 from repro.core import (
     PAPER_FRAM_MODEL,
+    CostModel,
     GraphBuilder,
     Infeasible,
+    LinearTransfer,
     dense_export_nbytes,
     lower_config,
     optimal_partition_multi,
@@ -211,6 +213,69 @@ def test_kernel_tile_size_invariance(tile):
     mk, bk = sweep_columns(csr, cm, qs, tile=tile, interpret=True)
     _assert_bitequal(mr, mk, tile)
     assert (br == bk).all(), tile
+
+
+def _tied_graph(n, combine_max):
+    """n identical tasks whose every split ties exactly: for an additive
+    combine each task costs 0.5 and a burst nothing more (dp[j] = j/2 by
+    any split); for a max combine tasks are free and every burst costs its
+    start-up 0.5 (a max over bursts is 0.5 by any split)."""
+    b = GraphBuilder()
+    for t in range(n):
+        b.task(f"t{t}", cost=0.0 if combine_max else 0.5)
+    zero = LinearTransfer(0.0, 0.0)
+    es = 0.5 if combine_max else 0.0
+    return b.build(), CostModel(e_startup=es, read=zero, write=zero,
+                                name="tied")
+
+
+def _mode_tables(csr, cm, mode, qs, slot_chunk, tile):
+    """(ref, kernel) (mns, bests) of one kernel mode on one export."""
+    kw = dict(slot_chunk=slot_chunk, tile=tile, interpret=True)
+    if mode == "sum":
+        return (sweep_columns_ref(csr, cm, qs),
+                sweep_columns(csr, cm, qs, **kw))
+    if mode == "minimax":
+        return (sweep_columns_minimax_ref(csr, cm),
+                sweep_columns(csr, cm, (), objective="minimax", **kw))
+    kobj = mode.rsplit("_", 1)[1]
+    K = max(1, csr.n_pad // 2)
+    return (sweep_columns_exactk_ref(csr, cm, qs[2], K, kobj),
+            sweep_columns(csr, cm, (qs[2],), objective="exact_k",
+                          n_bursts=K, k_objective=kobj, **kw))
+
+
+@pytest.mark.parametrize("slot_chunk", [1, 4])
+@pytest.mark.parametrize("mode", ["sum", "minimax", "exact_k_sum",
+                                  "exact_k_max"])
+@pytest.mark.parametrize("n", [5, 8, 9, 24, 29])
+def test_kernel_live_tile_loop_bitexact(n, mode, slot_chunk):
+    """Each column visits only its ⌈j/B⌉ live i-tiles: at every size around
+    the tile B = 8 (N < B, N = B, B + 1, 3B, 3B + 5), in every mode and
+    both slot-loop modes, mns AND argmin bests stay bit-identical to the
+    oracles — on a dyadic graph (exact whatever the summation order) and
+    on one where every split ties, so ties fall across tile boundaries and
+    the earliest tile must keep them."""
+    B = 8
+    rng = random.Random(9500 + n)
+    g = adversarial_tie_graph(rng, max_tasks=n, min_tasks=n)
+    cm = tie_cost_model(rng)
+    qs = tie_q_grid(rng, q_min(g, cm), whole_app_partition(g, cm).e_total)
+    gt, cmt = _tied_graph(n, combine_max=mode in ("minimax", "exact_k_max"))
+    # a budget of four tied tasks' worth of energy, on the exact lattice
+    qst = [None, 2.0, 2.0]
+    for graph, cost, grid in ((g, cm, qs), (gt, cmt, qst)):
+        csr = graph.to_csr_arrays()
+        assert csr.n_pad == n
+        (mr, br), (mk, bk) = _mode_tables(csr, cost, mode, grid, slot_chunk,
+                                          tile=B)
+        ctx = (n, mode, slot_chunk, cost.name)
+        _assert_bitequal(mr, mk, ctx)
+        assert (br == bk).all(), ctx
+    if mode in ("sum", "minimax"):
+        # unbounded lane of the tied graph: the last column's optimum is
+        # reached from every start i, and i = 1 of the first tile wins
+        assert bk[-1, 0] == 1, ctx
 
 
 def test_kernel_chunked_slots_close_to_ref():

@@ -107,6 +107,30 @@ def test_design_query_spans_and_upload_reuse(tracer):
                     "sweep.launch": [mm, sm], "sweep.readback": [mm, sm],
                     "sweep.assemble": [sm]}
     assert not by_name(ev, "engine.dispatch")
+    # two tasks, one i-tile: a grid program and a tile body per column
+    for e in by_name(ev, "sweep.launch"):
+        assert e["args"]["grid_programs"] == 2
+        assert e["args"]["tile_bodies"] == 2
+
+
+@pytest.mark.parametrize("n,tile", [(20, 8), (24, 8), (7, 8)])
+def test_sweep_launch_counts_live_tile_bodies(tracer, n, tile):
+    """``sweep.launch`` carries the kernel's grid programs (one a column)
+    and the i-tile bodies they run: column j visits its ⌈j/B⌉ live tiles."""
+    from repro.core import GraphBuilder
+    from repro.kernels.partition_sweep.ops import sweep_columns
+
+    _, cm = _tiny_graph()
+    b = GraphBuilder()
+    for t in range(n):
+        b.task(f"t{t}", cost=1.0)
+    sweep_columns(b.build().to_csr_arrays(), cm, [None], tile=tile,
+                  interpret=True)
+    (launch,) = by_name(tracer.events(), "sweep.launch")
+    B = min(tile, max(8, n))
+    assert launch["args"]["grid_programs"] == n
+    assert launch["args"]["tile_bodies"] == sum(-(-j // B)
+                                                for j in range(1, n + 1))
 
 
 def test_planned_request_spans(tracer):
