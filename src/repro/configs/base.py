@@ -38,6 +38,9 @@ class ModelConfig:
     qkv_bias: bool = False          # qwen1.5
     qk_norm: bool = False           # qwen3
     rope_theta: float = 10000.0
+    attn_in: int = 0                # attention input width (0 = d_model)
+    attn_scale: Optional[float] = None  # score scale (None = hd ** -0.5)
+    mlp_act: str = "silu"           # gated MLP activation: silu | gelu (erf)
     tie_embeddings: bool = False
     # MoE
     moe: Optional[MoEConfig] = None
@@ -45,8 +48,15 @@ class ModelConfig:
     ssm_state: int = 0              # mamba2 d_state (zamba2) — 0 = no ssm
     ssm_expand: int = 2
     ssm_headdim: int = 64
+    ssm_ngroups: int = 1            # mamba2 B/C groups (heads split evenly)
+    ssm_chunk: int = 256            # mamba2 SSD chunk length (prefill)
     slstm_every: int = 0            # xlstm: every k-th block is sLSTM (0 = none)
-    attn_every: int = 0             # zamba2: shared attention every k-th block
+    # zamba2: a shared transformer block runs before each of these Mamba2
+    # layers; hybrid layer k uses block k % n_shared_blocks and its own
+    # low-rank MLP adapter and linear
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_shared_blocks: int = 0
+    adapter_rank: int = 0
     # enc-dec (whisper)
     n_encoder_layers: int = 0
     n_audio_frames: int = 1500      # encoder input length (frontend stub)
@@ -110,10 +120,19 @@ class ModelConfig:
             total += self.n_layers * per
         elif self.family == "hybrid":  # zamba2
             d_in = self.ssm_expand * d
-            per_mamba = d * (2 * d_in) + d_in * d + d_in  # in/out proj + dt
+            H = d_in // self.ssm_headdim
+            conv = d_in + 2 * self.ssm_ngroups * self.ssm_state
+            per_mamba = (d * (d_in + conv + H) + d_in * d  # in/out proj
+                         + 5 * conv                        # conv weight, bias
+                         + 3 * H + d_in + d)               # A, dt, D, norms
             total += self.n_layers * per_mamba
-            if self.attn_every:
-                total += attn_params() + mlp_params(self.d_ff) + 2 * d  # shared block
+            a_in, ff = self.attn_in or d, self.d_ff
+            shared = (a_in * n_q + 2 * a_in * n_kv + n_q * d  # q/k/v/o
+                      + 3 * d * ff + a_in + d)                # MLP, norms
+            n_hyb = len(self.hybrid_layer_ids)
+            adapter = self.adapter_rank * (d + 2 * ff)
+            total += self.n_shared_blocks * shared + n_hyb * (adapter + d * d)
+            total -= d  # the final norm alone: no position stub
         return int(total)
 
     def active_param_count(self) -> int:

@@ -8,8 +8,8 @@ packets that make dependency-aware partitioning interesting —
   the last decoder layer, the exact analogue of the paper's image packet
   read by ~7300 CNN window tasks);
 * llama-vision: the vision embeddings, read by every 5th layer;
-* zamba2: the token embeddings, concat-read by all 13 shared-attention
-  applications.
+* zamba2: the token embeddings, concat-read by the shared transformer
+  block before each of the 13 hybrid Mamba2 layers.
 
 Two cost interpretations of the same graph (DESIGN.md §2):
 
@@ -126,23 +126,23 @@ def profile_model(cfg: ModelConfig, B: int, S: int) -> Tuple[
     elif cfg.family == "hybrid":  # zamba2
         d_in = cfg.ssm_expand * d
         H = d_in // cfg.ssm_headdim
-        m_w = (d * (2 * d_in + 2 * cfg.ssm_state + H) + d_in * d) * 2
-        m_fl = 2 * B * S * d * (2 * d_in + 2 * cfg.ssm_state + H) \
-            + 2 * B * S * d_in * d + 6 * B * S * d_in * cfg.ssm_state
+        conv = d_in + 2 * cfg.ssm_ngroups * cfg.ssm_state
+        m_w = (d * (d_in + conv + H) + d_in * d) * 2
+        m_fl = 2 * B * S * d * (d_in + conv + H) + 2 * B * S * d_in * d \
+            + 2 * B * S * 4 * conv + 6 * B * S * d_in * cfg.ssm_state
         long_lived["embed0"] = act
-        shared_w = (2 * d * (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.hd
-                    + cfg.n_heads * cfg.hd * d + 3 * d * cfg.d_ff) * 2
-        shared_fl = (2 * B * S * 2 * d * (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.hd
-                     + 2 * B * S * S * cfg.n_heads * cfg.hd
+        a_in, nq = cfg.attn_in or d, cfg.n_heads * cfg.hd
+        proj = a_in * (nq + 2 * cfg.n_kv_heads * cfg.hd) + nq * d
+        side = cfg.adapter_rank * (d + 2 * cfg.d_ff) + d * d  # adapter, linear
+        shared_w = (proj + 3 * d * cfg.d_ff + side) * 2
+        shared_fl = (2 * B * S * (proj + side) + 2 * B * S * S * nq
                      + _mlp_flops(cfg, B, S))
-        n_groups = cfg.n_layers // cfg.attn_every if cfg.attn_every else 0
-        g = 0
+        hybrid = {h: k for k, h in enumerate(cfg.hybrid_layer_ids)}
         for i in range(cfg.n_layers):
+            if i in hybrid:
+                out.append(LayerProfile(f"shared{hybrid[i]}", shared_fl,
+                                        shared_w, act, 4 * act, ("embed0",)))
             out.append(LayerProfile(f"mamba{i}", m_fl, m_w, act, 4 * act))
-            if cfg.attn_every and (i + 1) % cfg.attn_every == 0 and g < n_groups:
-                g += 1
-                out.append(LayerProfile(f"shared_attn{g}", shared_fl, shared_w,
-                                        act, 4 * act, ("embed0",)))
     else:
         raise ValueError(cfg.family)
     return out, long_lived

@@ -23,14 +23,17 @@ def _is_cpu() -> bool:
 
 def flash_attention(q, k, v, *, causal: bool = True, q_positions=None,
                     kv_positions=None, block_k: int = 128,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None, scale=None):
     """q: [B, Sq, H, hd]; k, v: [B, Sk, KV, hd] → [B, Sq, H, hd].
 
     Drop-in for ``blockwise_attention`` (positions args accepted for
     signature compatibility; the kernel assumes contiguous positions from 0,
-    which is what train/prefill use).
+    which is what train/prefill use). The kernel scales scores by
+    hd ** -0.5; another ``scale`` is folded into q.
     """
     B, Sq, H, hd = q.shape
+    if scale is not None:
+        q = q * (scale * hd ** 0.5)
     _, Sk, KV, _ = k.shape
     G = H // KV
     if interpret is None:

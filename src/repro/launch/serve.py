@@ -55,6 +55,12 @@ from .traffic import Continuation, Request
 TRACE_COUNT = METRICS.counter_dict("serve.trace_count", ("prefill", "decode"))
 
 
+# Bytes of decode state in the packet each opened request passes from step to
+# step: ``recurrent`` (fixed-size SSM / conv state) and ``kv`` (attention
+# caches at the request's bucket length).
+STATE_BYTES = METRICS.counter_dict("serve.state_bytes", ("recurrent", "kv"))
+
+
 def reset_trace_counts() -> None:
     """Zero the process-global retrace counters (test isolation). The jit
     caches themselves are untouched — this resets observability, not
@@ -119,14 +125,9 @@ def _pre_batch(cfg, prompts) -> Dict[str, Any]:
     return out
 
 
-def _cache_nbytes(cfg, batch: int, max_seq: int) -> int:
-    cache, _ = api.cache_shape(cfg, batch, max_seq)
-    return int(
-        sum(
-            int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree.leaves(cache)
-        )
-    )
+@functools.lru_cache(maxsize=None)
+def _cache_nbytes(cfg, batch: int, max_seq: int) -> Dict[str, int]:
+    return api.cache_bytes(cfg, batch, max_seq)
 
 
 def _in_span(name: str, fn, *args):
@@ -161,7 +162,7 @@ def _request_graph(cfg, params, batch, prompt_len, gen, max_seq,
 
     b = GraphBuilder()
     b.packet("prompts", batch * prompt_len * 4, external=True)
-    state_bytes = _cache_nbytes(cfg, batch, max_seq) + batch * 4
+    state_bytes = sum(_cache_nbytes(cfg, batch, max_seq).values()) + batch * 4
     for k in range(gen - 1):
         b.packet(f"state{k}", state_bytes)
     b.packet("sequence", batch * gen * 4, keep=True)
@@ -285,6 +286,8 @@ class PlannedExecutor:
             graph = _request_graph(self.cfg, params, batch, prompt_len, gen,
                                    max_seq, prefill_fn, decode_fn,
                                    step_energy=plan.e_total)
+        for kind, n in _cache_nbytes(self.cfg, batch, max_seq).items():
+            STATE_BYTES[kind] += n
         cycles = request_cycles(gen, plan.e_total, cycle_budget,
                                 e_startup=self.planner.e_startup)
         cost = CostModel(e_startup=self.planner.e_startup,

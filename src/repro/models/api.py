@@ -8,6 +8,7 @@ All functions are pure and jit-friendly; ``key=None`` gives abstract
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -18,7 +19,7 @@ from . import encdec, recurrent, transformer
 from .common import COMPUTE_DTYPE
 
 __all__ = ["init_params", "loss", "prefill", "decode_step", "cache_shape",
-           "input_specs", "extra_inputs"]
+           "cache_bytes", "input_specs", "extra_inputs"]
 
 
 def init_params(cfg: ModelConfig, key=None, max_seq: int = 4096):
@@ -92,6 +93,23 @@ def cache_shape(cfg: ModelConfig, batch: int, max_seq: int):
     if cfg.family == "hybrid":
         return recurrent.zamba_cache_shape(cfg, batch, max_seq)
     raise ValueError(cfg.family)
+
+
+# Cache leaves that hold attention keys and values; every other leaf is
+# fixed-size recurrent state.
+KV_LEAVES = ("k", "v", "cross_k", "cross_v")
+
+
+def cache_bytes(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, int]:
+    """Bytes of one decode cache by kind: ``kv`` (attention keys and values)
+    and ``recurrent`` (SSM, conv and xLSTM state)."""
+    cache, _ = cache_shape(cfg, batch, max_seq)
+    out = {"recurrent": 0, "kv": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        key = getattr(path[-1], "key", None)
+        kind = "kv" if key in KV_LEAVES else "recurrent"
+        out[kind] += math.prod(leaf.shape) * jnp.dtype(leaf.dtype).itemsize
+    return out
 
 
 # ---------------------------------------------------------------------------

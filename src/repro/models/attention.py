@@ -35,12 +35,15 @@ NEG_INF = -1e30
 
 
 def init_attention(cfg, kg, cross: bool = False):
+    """q/k/v read ``cfg.attn_in`` features (d_model unless set); o writes
+    d_model."""
     d, hd = cfg.d_model, cfg.hd
+    d_in = cfg.attn_in or d
     nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
     p: Dict[str, Any] = {
-        "wq": dense_init(kg(), (d, nq)),
-        "wk": dense_init(kg(), (d, nkv)),
-        "wv": dense_init(kg(), (d, nkv)),
+        "wq": dense_init(kg(), (d_in, nq)),
+        "wk": dense_init(kg(), (d_in, nkv)),
+        "wv": dense_init(kg(), (d_in, nkv)),
         "wo": dense_init(kg(), (nq, d)),
     }
     logical: Dict[str, Any] = {
@@ -88,17 +91,18 @@ def _project_qkv(cfg, p, x, kv_x=None, positions=None, kv_positions=None,
 
 
 def blockwise_attention(q, k, v, *, causal: bool, q_positions=None,
-                        kv_positions=None, block_k: int = 1024):
+                        kv_positions=None, block_k: int = 1024, scale=None):
     """Online-softmax attention scanned over KV blocks (the flash pattern).
 
     q: [B, Sq, H, hd];  k, v: [B, Sk, KV, hd];  H % KV == 0 (GQA).
     Positions are absolute token indices used for causal masking; when None,
-    iota is used (pure self-attention over a contiguous block).
+    iota is used (pure self-attention over a contiguous block). Scores are
+    scaled by ``scale``, hd ** -0.5 unless given.
     """
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     G = H // KV
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     blk = min(block_k, Sk)
     if Sk % blk:
         # cross-attention KV lengths (1601 vision tokens, 1500 audio frames)
@@ -179,7 +183,7 @@ def attention(cfg, p, x, *, positions, causal: bool = True, kv_x=None,
         v = constrain(v)
     impl = attn_impl or blockwise_attention
     o = impl(q, k, v, causal=causal, q_positions=positions,
-             kv_positions=kv_positions, block_k=block_k)
+             kv_positions=kv_positions, block_k=block_k, scale=cfg.attn_scale)
     o = o.reshape(*o.shape[:-2], cfg.n_heads * cfg.hd)
     return o @ p["wo"].astype(COMPUTE_DTYPE), (k, v)
 
@@ -220,8 +224,9 @@ def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, cross: bool = False):
     H = cfg.n_heads
     G = H // KV
     qg = q.reshape(B, 1, KV, G, hd)
+    scale = hd ** -0.5 if cfg.attn_scale is None else cfg.attn_scale
     s = jnp.einsum("bqkgh,bskh->bkgqs", qg, k.astype(COMPUTE_DTYPE),
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+                   preferred_element_type=jnp.float32) * scale
     if mask is not None:
         s = jnp.where(mask[:, :, None, :, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)  # reduction over sharded S → psum via SPMD

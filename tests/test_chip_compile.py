@@ -194,6 +194,20 @@ def test_decode_step_lowers_under_the_name_its_reader_matches():
     assert _reader_constant("decode_step_ms", "PROGRAM") in "jit__decode"
 
 
+def test_prefill_lowers_under_the_name_its_reader_matches():
+    """``prefill_step_ms`` finds the prefill program by its module name."""
+    from repro.configs import resolve_config
+    from repro.launch.serve import _step_fns
+    from repro.models import api
+
+    params, _ = api.init_params(resolve_config(ARCH, smoke=True), None)
+    prefill, _ = _step_fns(ARCH, True, 40, donate=False)
+    lowered = prefill.lower(_on(None, params),
+                            {"tokens": jax.ShapeDtypeStruct((1, 8), jnp.int32)})
+    assert lowered.as_text().startswith("module @jit__prefill ")
+    assert _reader_constant("prefill_step_ms", "PROGRAM") in "jit__prefill"
+
+
 def test_sweep_kernel_compiles_under_the_name_its_reader_matches(one_chip):
     """``sweep_kernel_roofline`` finds the kernel by its custom call's name
     on the op line, which the jitted wrapper gives it."""
@@ -206,3 +220,54 @@ def test_sweep_kernel_compiles_under_the_name_its_reader_matches(one_chip):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and calls[0].startswith("%sweep_columns_call")
     assert calls[0].startswith(_reader_constant("sweep_kernel_roofline", "KERNEL"))
+
+
+# -- the benchmark's Zamba2 stage: the published widths, the file's depth -----
+
+@pytest.fixture(scope="module")
+def zamba_stage(one_chip):
+    import json
+    import pathlib
+
+    from repro.configs import resolve_config
+    from repro.models import api
+
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / "zamba2-7b.json")
+    c = json.loads(path.read_text())
+    cfg = dataclasses.replace(resolve_config("zamba2-7b"),
+                              n_layers=c["num_hidden_layers"],
+                              hybrid_layer_ids=tuple(c["hybrid_layer_ids"]))
+    params, _ = api.init_params(cfg, None)
+    return cfg, _on(one_chip, params)
+
+
+def test_zamba_stage_prefill_compiles_at_the_longest_prompt(one_chip, zamba_stage):
+    """4064 = 15 chunks of 256 and 224 more, into a 4096-position cache."""
+    from repro.launch.serve import _step_fns
+
+    cfg, params = zamba_stage
+    prefill, _ = _step_fns(cfg, False, 4096, donate=False)
+    tokens = jax.ShapeDtypeStruct((1, 4064), jnp.int32, sharding=one_chip)
+    mem = prefill.lower(params, {"tokens": tokens}).compile().memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_zamba_stage_decode_compiles_with_its_scopes(one_chip, zamba_stage):
+    """The decode program fits the chip, and its ops carry the ``mamba2`` and
+    ``zamba.shared`` scopes in their metadata."""
+    from repro.launch.serve import _step_fns
+    from repro.models import api
+
+    cfg, params = zamba_stage
+    _, decode = _step_fns(cfg, False, 4096, donate=False)
+    cache, _ = api.cache_shape(cfg, 1, 4096)
+    compiled = decode.lower(
+        params, _on(one_chip, cache),
+        jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    text = compiled.as_text()
+    assert "/mamba2/" in text and "/zamba.shared/" in text

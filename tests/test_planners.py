@@ -160,11 +160,18 @@ class TestDependencyAwareness:
         assert d.loads.count("enc_out") == 1
 
     def test_zamba_boundaries_after_mamba(self):
-        """Pipeline cuts should not strand the shared-attn's embed0 input
-        needlessly — every stage after the first reads it exactly once."""
+        """The profile has a shared-block task before each of the 13 hybrid
+        Mamba2 layers, each reading embed0; pipeline cuts should not strand
+        that input needlessly — every stage reads it at most once."""
         cfg = REGISTRY["zamba2-7b"]
         pp = plan_pipeline(cfg, 16, 4096, 4)
         profiles, ll = profile_model(cfg, 16, 4096)
+        names = [p.name for p in profiles]
+        assert len(names) == 81 + 13
+        for k, h in enumerate(cfg.hybrid_layer_ids):
+            at = names.index(f"mamba{h}")
+            assert names[at - 1] == f"shared{k}"
+            assert profiles[at - 1].extra_reads == ("embed0",)
         g = build_activation_graph(profiles, ll, kind="time")
         from repro.core import burst_detail, tpu_pipeline_model
         for (i, j) in pp.bounds:

@@ -150,3 +150,32 @@ def test_reset_trace_counts_zeroes_counters():
     serve_mod.TRACE_COUNT["prefill"] += 1  # simulate leaked state
     serve_mod.reset_trace_counts()
     assert serve_mod.TRACE_COUNT == {"prefill": 0, "decode": 0}
+
+
+@pytest.mark.parametrize("arch", ["zamba2-7b", "qwen1.5-0.5b"])
+def test_open_counts_decode_state_bytes_by_kind(arch):
+    """``serve.state_bytes`` grows at each open by one decode-state packet's
+    recurrent state and KV caches, counted here by hand."""
+    from repro.configs import SMOKE_CONFIGS
+    from repro.launch.planner import build_table_for_arch
+    from repro.launch.serve import STATE_BYTES, PlannedExecutor
+
+    cfg = SMOKE_CONFIGS[arch]
+    batch, prompt, gen = 1, 8, 4
+    seq = prompt + gen
+    ex = PlannedExecutor(arch, build_table_for_arch(arch, [(batch, seq)], n_q=4))
+    before = dict(STATE_BYTES)
+    for _ in range(2):
+        ex.open(batch, prompt, gen)
+    kv_pos = 2 * cfg.n_kv_heads * cfg.hd * 2  # k and v, bfloat16
+    if cfg.family == "hybrid":
+        heads = 2 * cfg.d_model // cfg.ssm_headdim
+        conv = 2 * cfg.d_model + 2 * cfg.ssm_ngroups * cfg.ssm_state
+        recurrent = cfg.n_layers * 4 * (heads * cfg.ssm_headdim * cfg.ssm_state
+                                        + 3 * conv)
+        kv = len(cfg.hybrid_layer_ids) * seq * kv_pos
+        assert (recurrent, kv) == (6 * 4 * (8 * 16 * 16 + 3 * 192), 2 * 12 * 512)
+    else:
+        recurrent, kv = 0, cfg.n_layers * seq * kv_pos
+    assert STATE_BYTES["recurrent"] - before["recurrent"] == 2 * recurrent
+    assert STATE_BYTES["kv"] - before["kv"] == 2 * kv
