@@ -31,6 +31,7 @@ import argparse
 import functools
 import sys
 import time
+from collections.abc import MutableMapping
 from typing import Any, Dict, Optional
 
 import jax
@@ -39,6 +40,7 @@ import numpy as np
 
 from ..configs import resolve_config
 from ..models import api
+from ..models.common import compute_copy
 from ..obs.metrics import METRICS
 from ..obs.trace import PID_RUNTIME, PID_TRAFFIC, TRACER
 from ..models.sharding import rules_for
@@ -59,6 +61,10 @@ TRACE_COUNT = METRICS.counter_dict("serve.trace_count", ("prefill", "decode"))
 # step: ``recurrent`` (fixed-size SSM / conv state) and ``kv`` (attention
 # caches at the request's bucket length).
 STATE_BYTES = METRICS.counter_dict("serve.state_bytes", ("recurrent", "kv"))
+
+# Serving copies of the weights (``compute_copy``) looked up at each open:
+# ``miss`` made one, ``hit`` reused the copy of a tree already seen.
+PARAM_CAST = METRICS.counter_dict("serve.param_cast", ("miss", "hit"))
 
 
 def reset_trace_counts() -> None:
@@ -201,12 +207,59 @@ def _request_graph(cfg, params, batch, prompt_len, gen, max_seq,
     return b.build()
 
 
+class WeightTrees(MutableMapping):
+    """``(seed, max_seq)`` → float32 weight tree, as a dict, with each tree's
+    serving copy (:func:`~repro.models.common.compute_copy`) made at its
+    first :meth:`serving` lookup. Keys that hold the same tree share one
+    copy, found by the tree's identity with no walk of the tree. A copy
+    lives only while some key holds its tree: replacing or deleting a key's
+    tree drops copies no key needs. Trees are values: one is replaced, never
+    updated in place."""
+
+    def __init__(self) -> None:
+        self._trees: Dict[Any, Any] = {}
+        self._copies: Dict[int, Any] = {}   # id(tree) → its serving copy
+
+    def __getitem__(self, key):
+        return self._trees[key]
+
+    def __setitem__(self, key, tree) -> None:
+        self._trees[key] = tree
+        self._drop_unheld()
+
+    def __delitem__(self, key) -> None:
+        del self._trees[key]
+        self._drop_unheld()
+
+    def __iter__(self):
+        return iter(self._trees)
+
+    def __len__(self) -> int:
+        return len(self._trees)
+
+    def _drop_unheld(self) -> None:
+        held = {id(t) for t in self._trees.values()}
+        for i in [i for i in self._copies if i not in held]:
+            del self._copies[i]
+
+    def serving(self, key):
+        tree = self._trees[key]
+        copy = self._copies.get(id(tree))
+        if copy is None:
+            PARAM_CAST["miss"] += 1
+            copy = self._copies[id(tree)] = compute_copy(tree)
+        else:
+            PARAM_CAST["hit"] += 1
+        return copy
+
+
 class PlannedExecutor:
     """Reusable per-request executor for the planned path.
 
     Owns the pieces that amortize across a request stream — the resolved
     config, the :class:`~repro.launch.planner.ServePlanner` (O(1) lookups),
-    a params cache keyed on ``(seed, max_seq)``, and the process-wide jitted
+    a params cache keyed on ``(seed, max_seq)`` (:class:`WeightTrees`: the
+    steps run on each tree's serving copy), and the process-wide jitted
     step cache — and :meth:`open`\\ s each request as a
     :class:`~repro.launch.traffic.Continuation` whose energy cycles commit
     one :meth:`~repro.launch.traffic.Continuation.step` at a time. The
@@ -228,17 +281,19 @@ class PlannedExecutor:
                 f"plan table was built for {self.planner.table.arch!r} but "
                 f"this request is for {self.cfg.name!r}"
             )
-        self._params: Dict[Any, Any] = {}
+        self._params = WeightTrees()
         self._next_rid = 0
 
     def _params_for(self, seed: int, max_seq: int):
+        """The serving copy of the weights for ``(seed, max_seq)``, made
+        from the seed on first use unless a tree was put there."""
         key = (seed, max_seq)
         if key not in self._params:
             with _host_mesh():
                 params, _ = api.init_params(
                     self.cfg, jax.random.PRNGKey(seed), max_seq=max_seq)
             self._params[key] = params
-        return self._params[key]
+        return self._params.serving(key)
 
     def make_prompts(self, batch: int, prompt_len: int, seed: int = 0):
         return jax.random.randint(jax.random.PRNGKey(seed + 1),
@@ -375,6 +430,7 @@ def serve(arch: str, batch: int, prompt_len: int, gen: int, smoke: bool = True,
 
     with mesh:
         params, _ = api.init_params(cfg, jax.random.PRNGKey(seed), max_seq=max_seq)
+        params = compute_copy(params)
         prompts = jax.random.randint(jax.random.PRNGKey(seed + 1),
                                      (batch, prompt_len), 0, cfg.vocab)
         pre_batch = _pre_batch(cfg, prompts)

@@ -3,6 +3,13 @@
 Numerics policy (mixed precision, MaxText-style): parameters and optimizer
 state in fp32; activations and matmuls in bf16; softmax statistics, norms and
 the loss in fp32.
+
+Every read of a matmul weight is ``w.astype(COMPUTE_DTYPE)``. Serving reads
+the weights without updating them, so it runs on :func:`compute_copy`: the
+leaves named in ``COMPUTE_COPY_NAMES`` cast to bf16 once, where a step would
+cast them again on every call. The steps see the same bf16 operands either
+way. Every other leaf (norm gains, SSM decay and skip terms, gates) is read
+in fp32 and stays the fp32 original.
 """
 
 from __future__ import annotations
@@ -19,6 +26,8 @@ PARAM_DTYPE = jnp.float32
 __all__ = [
     "COMPUTE_DTYPE",
     "PARAM_DTYPE",
+    "COMPUTE_COPY_NAMES",
+    "compute_copy",
     "KeyGen",
     "dense_init",
     "rmsnorm",
@@ -27,6 +36,35 @@ __all__ = [
     "softmax_cross_entropy",
     "Abstract",
 ]
+
+
+# Leaf names that every model family reads only as ``.astype(COMPUTE_DTYPE)``
+# (matmul weights, the biases added to their bf16 outputs, conv taps,
+# embedding and position tables); tests/test_serve_compute_copy.py holds each
+# family to it.
+COMPUTE_COPY_NAMES = frozenset({
+    "wq", "wk", "wv", "wo", "bq", "bk", "bv",            # attention
+    "w1", "w2", "w3", "b1", "b2", "gate_up", "down", "up",  # MLPs, adapters
+    "router",                                             # MoE
+    "in_proj", "out_proj", "conv_w", "linear",            # Mamba2, Zamba2
+    "wi", "wf", "wo_gate", "w_in", "ff_w1", "ff_w2", "ff_w3",  # xLSTM
+    "embed", "head", "pos_enc", "pos_dec",
+})
+
+
+def compute_copy(params):
+    """``params`` with each leaf named in ``COMPUTE_COPY_NAMES`` (its key in
+    the innermost dict) cast to ``COMPUTE_DTYPE``; every other leaf is the
+    same object as in ``params``. Steps run on the copy give the same
+    numbers as on ``params``."""
+    def cast(path, leaf):
+        key = path[-1] if path else None
+        if (isinstance(key, jax.tree_util.DictKey)
+                and key.key in COMPUTE_COPY_NAMES):
+            return leaf.astype(COMPUTE_DTYPE)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 class KeyGen:

@@ -12,6 +12,7 @@ worker that cannot load it skips instead of failing collection.
 
 import dataclasses
 import os
+import re
 
 import numpy as np
 import pytest
@@ -123,6 +124,38 @@ def test_scan_dp_compiles_under_x64(one_chip):
     assert compiled is not None
 
 
+def _served(sharding, params):
+    """The weights as the serving steps receive them: ``compute_copy``."""
+    from repro.models.common import compute_copy
+
+    return _on(sharding, jax.eval_shape(compute_copy, params))
+
+
+def _weight_converts(text: str, params) -> list:
+    """Top-level convert ops of a compiled module (not those inside a
+    fusion's body, which write nothing to memory) whose result has the shape
+    of a weight, or of one layer of a stacked weight: weights cast inside
+    the program, on every call."""
+    shapes = set()
+    for leaf in jax.tree.leaves(params):
+        if leaf.ndim >= 2:
+            shapes |= {tuple(leaf.shape), tuple(leaf.shape[1:])}
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    found, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* convert\(",
+                      line)
+        if op and comp not in fused:
+            shape = tuple(int(d) for d in op.group(2).split(",") if d)
+            if shape in shapes:
+                found.append((op.group(1), shape))
+    return found
+
+
 @pytest.fixture(scope="module")
 def full_width(one_chip):
     from repro.configs import resolve_config
@@ -131,7 +164,7 @@ def full_width(one_chip):
     cfg = resolve_config(ARCH, smoke=False)
     assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (24, 1024, 151936)
     params, _ = api.init_params(cfg, None)
-    return cfg, _on(one_chip, params)
+    return cfg, _served(one_chip, params)
 
 
 def test_full_width_prefill_compiles(one_chip, full_width):
@@ -143,6 +176,7 @@ def test_full_width_prefill_compiles(one_chip, full_width):
     compiled = prefill.lower(params, {"tokens": tokens}).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert _weight_converts(compiled.as_text(), params) == []
 
 
 @pytest.mark.parametrize("donate", [False, True])
@@ -160,6 +194,28 @@ def test_full_width_decode_step_compiles(one_chip, full_width, donate):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert _weight_converts(compiled.as_text(), params) == []
+
+
+def test_float32_weights_are_cast_inside_the_step(one_chip):
+    """The check above can fail: on the float32 tree the decode step casts
+    each matmul weight (the stacked MLP weights, the embedding) itself."""
+    from repro.configs import resolve_config
+    from repro.launch.serve import _step_fns
+    from repro.models import api
+
+    cfg = resolve_config(ARCH, smoke=False)
+    params, _ = api.init_params(cfg, None)
+    params = _on(one_chip, params)
+    _, decode = _step_fns(ARCH, False, 40, donate=False)
+    cache, _ = api.cache_shape(cfg, 2, 40)
+    compiled = decode.lower(
+        params, _on(one_chip, cache),
+        jax.ShapeDtypeStruct((2, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    shapes = {shape for _, shape in _weight_converts(compiled.as_text(), params)}
+    assert {(24, 1024, 2816), (24, 2816, 1024), (151936, 1024)} <= shapes
 
 
 # -- the program names the benchmark's trace readers match ---------------------
@@ -239,23 +295,26 @@ def zamba_stage(one_chip):
                               n_layers=c["num_hidden_layers"],
                               hybrid_layer_ids=tuple(c["hybrid_layer_ids"]))
     params, _ = api.init_params(cfg, None)
-    return cfg, _on(one_chip, params)
+    return cfg, _served(one_chip, params)
 
 
 def test_zamba_stage_prefill_compiles_at_the_longest_prompt(one_chip, zamba_stage):
-    """4064 = 15 chunks of 256 and 224 more, into a 4096-position cache."""
+    """4064 = 15 chunks of 256 and 224 more, into a 4096-position cache, with
+    no weight cast inside the program."""
     from repro.launch.serve import _step_fns
 
     cfg, params = zamba_stage
     prefill, _ = _step_fns(cfg, False, 4096, donate=False)
     tokens = jax.ShapeDtypeStruct((1, 4064), jnp.int32, sharding=one_chip)
-    mem = prefill.lower(params, {"tokens": tokens}).compile().memory_analysis()
+    compiled = prefill.lower(params, {"tokens": tokens}).compile()
+    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert _weight_converts(compiled.as_text(), params) == []
 
 
 def test_zamba_stage_decode_compiles_with_its_scopes(one_chip, zamba_stage):
-    """The decode program fits the chip, and its ops carry the ``mamba2`` and
-    ``zamba.shared`` scopes in their metadata."""
+    """The decode program fits the chip, casts no weight, and its ops carry
+    the ``mamba2`` and ``zamba.shared`` scopes in their metadata."""
     from repro.launch.serve import _step_fns
     from repro.models import api
 
@@ -270,4 +329,5 @@ def test_zamba_stage_decode_compiles_with_its_scopes(one_chip, zamba_stage):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     text = compiled.as_text()
+    assert _weight_converts(text, params) == []
     assert "/mamba2/" in text and "/zamba.shared/" in text
