@@ -56,8 +56,7 @@ CM = PAPER_FRAM_MODEL
 
 
 def _np_partition(g, cm, q_max):
-    """One numpy-backend partition through the façade (the old
-    ``optimal_partition`` call shape)."""
+    """One numpy-backend partition through the façade."""
     return solve(PartitionSpec(graph=g, cost=cm, q_max=q_max,
                                backend="numpy")).partition()
 
@@ -516,9 +515,9 @@ def api_facade(smoke=False, json_out=None):
     The façade validates the spec, resolves the backend through the
     registry's capability flags, and wraps the result — all host-side
     bookkeeping. The acceptance row pins that this costs <1% on the smoke
-    config (the old direct ``sweep_jax_batched`` call shape), so routing
-    every consumer through the one API is free at solve granularity. Rows
-    merge into BENCH_partition_sweep.json.
+    config (against a direct ``partition_jax._sweep_jax_batched`` call on
+    the resolved backend), so routing every consumer through the one API is
+    free at solve granularity. Rows merge into BENCH_partition_sweep.json.
     """
     from repro.core import lower_config, q_min as qmin_np
     from repro.core.partition_jax import _sweep_jax_batched

@@ -3,8 +3,9 @@
 Lowers every registered architecture to a Ladybirds task graph
 (`lower_zoo`), pads them to a common shape, and solves the optimal burst
 partition for all of them across a shared 256-point Q_max grid in a single
-vmapped, jit-compiled dispatch (`sweep_jax_batched`) — the NS-Optimizer-style
-"sweep every device config" workflow at hardware speed.
+vmapped, jit-compiled dispatch (`solve(PartitionSpec(graphs=...)).sweeps`) —
+the NS-Optimizer-style "sweep every device config" workflow at hardware
+speed.
 
 Two cost readings of the same graphs (DESIGN: time vs memory):
 

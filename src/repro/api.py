@@ -28,11 +28,11 @@ Q-grid device sharding, numpy/scan/Pallas backends — goes through one call::
     solve(PartitionSpec(graph=g, cost=cm, objective="exact_k",
                         n_bursts=4, k_objective="max")).partition()
 
-Results reproduce the legacy entry points (``optimal_partition``,
-``sweep_jax_batched``, …) **bit-identically** — the façade routes to the same
-private implementations; see tests/test_api.py for the per-function
-differential pins and the README "Public API" section for the migration
-table. The legacy functions still work but emit :class:`DeprecationWarning`.
+This is the one way into the solver: the numpy reference DP
+(``backend="numpy"``, the oracle the jit backends are tested against), the
+``lax.scan`` engine and the Pallas sweep kernel are all reached through
+:func:`solve`, and ``backend="auto"`` picks scan or Pallas per graph in one
+place (:func:`repro.core.engine.resolve_jit_backend`).
 
 Backends self-register with capability flags; third-party code can add one::
 
@@ -47,7 +47,6 @@ and address it with ``PartitionSpec(backend="mine")``.
 
 from __future__ import annotations
 
-from .core._deprecation import JulienningDeprecationWarning
 from .core.calibration import (
     CalibrationError,
     MeasuredCostTable,
@@ -91,7 +90,6 @@ __all__ = [
     "EngineError",
     "ExportMismatch",
     "Infeasible",
-    "JulienningDeprecationWarning",
     "LinkModel",
     "MeasuredCostTable",
     "NodeSpec",

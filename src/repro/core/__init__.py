@@ -59,13 +59,9 @@ from .partition import (
     Partition,
     brute_force_partition,
     dijkstra_partition,
-    optimal_partition,
-    optimal_partition_k,
-    optimal_partition_multi,
     q_min,
     q_min_bruteforce,
     single_task_partition,
-    sweep,
     whole_app_partition,
     within_budget,
 )
@@ -93,7 +89,6 @@ from .plan_table import (
     config_fingerprint,
     extend_plan_table,
     probe_plan_table,
-    shard_plan_table,
 )
 from .runtime import (
     COMMIT_STATS,
@@ -112,11 +107,7 @@ __all__ = [k for k in dir() if not k.startswith("_")]
 # pure-numpy analysis (`import repro.core`) stays jax-free.
 _JAX_EXPORTS = (
     "JaxSweep",
-    "sweep_jax",
-    "sweep_jax_batched",
-    "sweep_jax_sharded",
     "shard_q_grid",
-    "optimal_partition_jax",
     "sweep_from_columns",
 )
 __all__ += list(_JAX_EXPORTS)

@@ -3,9 +3,7 @@
 The paper's contribution is a *specification model*: an application is
 declared once (atomic kernels + explicit data dependencies) and a single
 optimization flow produces energy-bounded cycles. This module is that model
-for the solver layer. Instead of ~10 entry points with divergent signatures
-(``optimal_partition``, ``sweep_jax_batched``, ``sweep_jax_sharded``, …),
-callers build one immutable :class:`PartitionSpec` —
+for the solver layer: callers build one immutable :class:`PartitionSpec` —
 
 * **what** to partition: a :class:`~repro.core.graph.TaskGraph` (or a
   dense/CSR export of one), a batch of graphs, or a model-zoo config plus
@@ -19,18 +17,19 @@ callers build one immutable :class:`PartitionSpec` —
 — and :meth:`Engine.solve` resolves it through a backend *registry*. Backends
 self-register via :func:`register_backend` with capability flags
 (``supports_sharding``, ``supports_csr``, ``supports_dense``, the supported
-objective set), which replace the old hand-rolled ``_select_backend``
-if-chain: ``backend="auto"`` picks the highest-priority registered backend
-whose capabilities match the export kind (and dense-export size) of each
-graph, and mismatches raise *typed* errors — :class:`ExportMismatch` for a
-layout the backend cannot consume, :class:`UnsupportedObjective` for an
-objective it does not implement — identically from every backend.
+objective set): ``backend="auto"`` picks the highest-priority registered
+backend whose capabilities match the export kind (and dense-export size) of
+each graph, and mismatches raise *typed* errors — :class:`ExportMismatch`
+for a layout the backend cannot consume, :class:`UnsupportedObjective` for
+an objective it does not implement — identically from every backend. The
+backend is resolved here, once per graph (:func:`resolve_jit_backend`);
+the implementations in :mod:`repro.core.partition_jax` receive only the
+concrete name.
 
-Results come back as a :class:`Solution` whose accessors reproduce each
-legacy entry point **bit-identically** (pinned per legacy function by
-tests/test_api.py): the same private implementations run underneath, the
-façade only routes. The legacy entry points themselves survive as thin
-:class:`DeprecationWarning` shims.
+Results come back as a :class:`Solution` whose accessors give the sweep
+tables, per-Q :class:`~repro.core.partition.Partition` objects or Q_min
+values; tests/test_api.py pins the jit backends against the numpy oracle
+through these accessors.
 
 Most callers go through :mod:`repro.api`, which re-exports everything here
 plus the module-level :func:`solve` convenience.
@@ -76,6 +75,12 @@ __all__ = [
 AnyExport = Union[TaskGraph, GraphArrays, GraphCSRArrays]
 
 OBJECTIVES = ("sum", "minimax", "exact_k")
+
+# backend="auto": route a TaskGraph to the compressed-layout (CSR/Pallas)
+# backend once its dense export would cross this size (the full head-count
+# graph is ~1 GB dense, ~400 kB CSR). Read at call time, so tests can
+# monkeypatch it.
+_AUTO_DENSE_BYTES = 32 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -245,16 +250,15 @@ def resolve_jit_backend(
     objective: str = "sum",
     registry: Optional[Dict[str, BackendInfo]] = None,
 ) -> str:
-    """Resolve ``backend="auto"`` for one graph via the registry flags.
+    """Resolve ``backend="auto"`` for one graph via the registry flags —
+    the one place the scan/Pallas rule lives.
 
-    This replaces the hand-rolled if-chain that used to live in
-    ``partition_jax._select_backend`` (which now delegates here): among the
-    ``auto_eligible`` backends implementing ``objective``, a CSR export picks
-    a ``supports_csr`` backend, a dense export a ``supports_dense`` one, and
-    a raw :class:`TaskGraph` routes by dense-export size — above
-    ``partition_jax._AUTO_DENSE_BYTES`` (read at call time so tests can
-    monkeypatch it) the compressed-layout backend wins. Explicit names pass
-    through after a registry existence check.
+    Among the ``auto_eligible`` backends implementing ``objective``, a CSR
+    export picks a ``supports_csr`` backend, a dense export a
+    ``supports_dense`` one, and a raw :class:`TaskGraph` routes by
+    dense-export size — above ``_AUTO_DENSE_BYTES`` the compressed-layout
+    backend wins. Explicit names pass through after a registry existence
+    check.
     """
     reg = _REGISTRY if registry is None else registry
     jit = [b for b in reg.values() if b.auto_eligible]
@@ -286,12 +290,10 @@ def resolve_jit_backend(
     elif kind == "dense":
         pool = dense_c
     else:
-        from . import partition_jax as pj  # lazy: jax-heavy
-
         n = graph.n_tasks
         r = max((len(t.reads) for t in graph.tasks), default=0)
         w = max((len(t.writes) for t in graph.tasks), default=0)
-        big = dense_export_nbytes(n, r, w) > pj._AUTO_DENSE_BYTES
+        big = dense_export_nbytes(n, r, w) > _AUTO_DENSE_BYTES
         pool = (csr_c or dense_c) if big else (dense_c or csr_c)
     if not pool:
         # some backend implements the objective, just not for this layout —
@@ -314,10 +316,9 @@ def resolve_jit_backend(
 class QGridSharding:
     """Shard the Q_max grid across ``n_shards`` device chunks.
 
-    Mirrors the legacy ``sweep_jax_sharded`` / ``shard_plan_table``
-    parameters: ``devices`` defaults to ``jax.local_devices()`` at solve
-    time; with fewer local devices than shards the same chunk decomposition
-    then runs sequentially (bit-identical either way). Explicit ``devices``
+    ``devices`` defaults to ``jax.local_devices()`` at solve time; with
+    fewer local devices than shards the same chunk decomposition then runs
+    sequentially (bit-identical either way). Explicit ``devices``
     are a placement: too few of them raise ``ValueError`` at solve time.
     Only ``objective="sum"`` has a Q grid to shard; a spec combining
     sharding with ``minimax``/``exact_k`` is rejected at construction
@@ -544,7 +545,7 @@ class PartitionSpec:
 @dataclasses.dataclass(frozen=True, eq=False)
 class Solution:
     """What :meth:`Engine.solve` returns: one payload per objective, with
-    accessors reproducing the legacy entry points bit-for-bit.
+    accessors for each shape of result.
 
     ``backend`` is the *resolved* backend name (``"scan+pallas"`` for a
     mixed ``auto`` batch); ``graphs`` / ``cost`` / ``q_values`` are the
@@ -577,7 +578,7 @@ class Solution:
     @property
     def sweep(self):
         """The single :class:`~repro.core.partition_jax.JaxSweep` (one-graph
-        specs on a jit backend) — the ``sweep_jax`` return value."""
+        specs on a jit backend)."""
         sweeps = self._one(self.sweeps, "JaxSweep results")
         if len(sweeps) != 1:
             raise EngineError(
@@ -588,7 +589,7 @@ class Solution:
 
     def partitions(self, graph_index: int = 0) -> List[Optional[Partition]]:
         """Per-Q :class:`Partition` objects for one graph (None where
-        infeasible) — the ``optimal_partition_multi`` / ``sweep`` shape."""
+        infeasible)."""
         if self.spec.objective == "minimax":
             raise EngineError(
                 "objective='minimax' yields Q_min values; use .q_min()"
@@ -607,8 +608,7 @@ class Solution:
         )
 
     def partition(self, graph_index: int = 0, q_index: int = 0) -> Partition:
-        """One feasible :class:`Partition` — the ``optimal_partition`` /
-        ``optimal_partition_jax`` / ``optimal_partition_k`` shape. Raises
+        """One feasible :class:`Partition`. Raises
         :class:`~repro.core.partition.Infeasible` identically across
         backends when that (graph, Q) cell has no partition."""
         p = self.partitions(graph_index)[q_index]
@@ -681,7 +681,7 @@ class _SolveRequest:
     sharding: Optional[QGridSharding]
     interpret: Optional[bool]
     batched: bool
-    backend: str                 # concrete name, or "auto" for a mixed batch
+    backends: Tuple[str, ...]    # resolved concrete name, one per graph
     placement: Optional[PlacementSpec] = None
 
 
@@ -699,8 +699,7 @@ class NumpyBackend:
 
     Consumes :class:`TaskGraph` objects only (the incremental column sweep
     needs the graph structure); explicit array exports raise
-    :class:`ExportMismatch`. Every result is exactly what the legacy
-    ``optimal_partition*`` / ``sweep`` / ``q_min`` functions returned.
+    :class:`ExportMismatch`. The jit backends are tested against it.
     """
 
     name = "numpy"
@@ -721,9 +720,7 @@ class NumpyBackend:
             return {
                 "parts": tuple(
                     tuple(
-                        _optimal_multi(
-                            g, req.cost, list(req.q_values), raise_single=False
-                        )
+                        _optimal_multi(g, req.cost, list(req.q_values))
                     )
                     for g in req.graphs
                 )
@@ -749,9 +746,12 @@ class NumpyBackend:
 
 
 class _JitBackend:
-    """Shared dispatch for the jit engines (scan / pallas / mixed-auto):
-    the concrete backend string is threaded into the partition_jax
-    implementations, which own upload caching and compilation."""
+    """Shared dispatch for the jit engines (scan / pallas / mixed-auto).
+
+    The engine hands over one resolved name per graph (``req.backends``);
+    a mixed ``auto`` batch is split here into one call per backend — the
+    only place a batch is grouped. The partition_jax implementations own
+    upload caching and compilation."""
 
     name = "jit"
 
@@ -767,58 +767,59 @@ class _JitBackend:
                     for g in req.graphs
                 )
             }
-        if req.objective == "sum":
-            qs = list(req.q_values)
+        pairs = tuple(zip(req.graphs, req.backends))
+        if req.objective == "minimax":
+            return {
+                "qmins": tuple(
+                    pj._q_min_pallas(g, req.cost, interpret=req.interpret)
+                    if be == "pallas" else pj._q_min_scan(g, req.cost)
+                    for g, be in pairs
+                )
+            }
+        if req.objective == "exact_k":
+            k_args = (req.n_bursts, req.q_values[0], req.k_objective)
+            return {
+                "parts": tuple(
+                    (
+                        pj._optimal_k_pallas(
+                            g, req.cost, *k_args, interpret=req.interpret
+                        )
+                        if be == "pallas"
+                        else pj._optimal_k_scan(g, req.cost, *k_args),
+                    )
+                    for g, be in pairs
+                )
+            }
+        qs = list(req.q_values)
+        sweeps: List[Any] = [None] * len(pairs)
+        for be in dict.fromkeys(req.backends):
+            idx = [k for k, b in enumerate(req.backends) if b == be]
+            group = [req.graphs[k] for k in idx]
             if req.sharding is not None:
                 devices = req.sharding.devices
-                sweeps = pj._sweep_jax_sharded(
-                    list(req.graphs),
+                res = pj._sweep_jax_sharded(
+                    group,
                     req.cost,
                     qs,
                     n_shards=req.sharding.n_shards,
                     devices=None if devices is None else list(devices),
-                    backend=req.backend,
+                    backend=be,
                     interpret=req.interpret,
                 )
             elif req.batched:
-                sweeps = pj._sweep_jax_batched(
-                    list(req.graphs), req.cost, qs,
-                    backend=req.backend, interpret=req.interpret,
+                res = pj._sweep_jax_batched(
+                    group, req.cost, qs, backend=be, interpret=req.interpret
                 )
             else:
-                sweeps = [
+                res = [
                     pj._sweep_jax(
-                        req.graphs[0], req.cost, qs,
-                        backend=req.backend, interpret=req.interpret,
+                        group[0], req.cost, qs,
+                        backend=be, interpret=req.interpret,
                     )
                 ]
-            return {"sweeps": tuple(sweeps)}
-        if req.objective == "minimax":
-            return {
-                "qmins": tuple(
-                    pj._q_min_jit(
-                        g, req.cost,
-                        backend=req.backend, interpret=req.interpret,
-                    )
-                    for g in req.graphs
-                )
-            }
-        return {
-            "parts": tuple(
-                (
-                    pj._optimal_k_jit(
-                        g,
-                        req.cost,
-                        req.n_bursts,
-                        req.q_values[0],
-                        objective=req.k_objective,
-                        backend=req.backend,
-                        interpret=req.interpret,
-                    ),
-                )
-                for g in req.graphs
-            )
-        }
+            for k, r in zip(idx, res):
+                sweeps[k] = r
+        return {"sweeps": tuple(sweeps)}
 
 
 @register_backend(
@@ -932,9 +933,9 @@ class Engine:
     ) -> Tuple[str, List[str]]:
         """(label, per-graph concrete names). ``label`` is the Solution's
         resolved-backend string — a concrete name, or ``"a+b"`` for a mixed
-        ``auto`` batch (dispatched group-wise like the legacy batched
-        entry point). Any explicitly named *registered* backend — including
-        ones registered by downstream code — passes through directly."""
+        ``auto`` batch (dispatched group-wise by the jit backend). Any
+        explicitly named *registered* backend — including ones registered
+        by downstream code — passes through directly."""
         if spec.backend != "auto":
             info = backend_info(spec.backend, self._registry)
             return info.name, [info.name] * len(graphs)
@@ -1031,7 +1032,7 @@ class Engine:
             sharding=spec.sharding,
             interpret=spec.interpret,
             batched=spec.batched,
-            backend="auto" if "+" in label else per_graph[0],
+            backends=tuple(per_graph),
             placement=spec.placement,
         )
         with TRACER.span(
@@ -1044,8 +1045,7 @@ class Engine:
             q_points=len(spec.q_values),
         ):
             if "+" in label:
-                # mixed auto batch: the jit dispatcher groups per backend,
-                # exactly like the legacy batched entry point did
+                # mixed auto batch: the jit dispatcher groups per backend
                 payload = _JitBackend().solve(req)
             else:
                 payload = backend_info(label, self._registry).factory().solve(req)

@@ -268,7 +268,7 @@ def stack_csr_arrays(arrays: Sequence[GraphCSRArrays]) -> GraphCSRArrays:
     All arrays re-pad to the largest (N, nnz_r, nnz_w) in the batch;
     ``n_tasks`` becomes a ``(B,)`` int array. Mirrors
     :func:`stack_graph_arrays` for the compressed layout — this is what
-    :func:`repro.core.partition_jax.sweep_jax_batched` feeds the Pallas
+    :func:`repro.core.partition_jax._sweep_jax_batched` feeds the Pallas
     backend (one compiled kernel serves every graph in the batch).
     """
     if not arrays:
@@ -293,7 +293,7 @@ def stack_graph_arrays(arrays: Sequence[GraphArrays]) -> GraphArrays:
 
     All arrays are re-padded to the largest (N, R, W) in the batch;
     ``n_tasks`` becomes an ``(B,)`` int array. The result is what
-    :func:`repro.core.partition_jax.sweep_jax_batched` vmaps over.
+    :func:`repro.core.partition_jax._sweep_jax_batched` vmaps over.
     """
     if not arrays:
         raise ValueError("empty batch")

@@ -250,7 +250,7 @@ def lower_zoo(
 
     This is what opens the full model zoo as partitioning workloads: the
     resulting graphs batch together through
-    :func:`repro.core.partition_jax.sweep_jax_batched`.
+    :func:`repro.core.partition_jax._sweep_jax_batched`.
     """
     if configs is None:
         from ..configs import REGISTRY

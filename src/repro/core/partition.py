@@ -13,8 +13,11 @@ Also implemented:
 
 * :func:`q_min` — storage minimization (§4.4): the minimax/bottleneck path,
   i.e. minimize (over partitions) the maximum single-burst cost.
-* :func:`sweep` — design-space exploration over a Q_max range (paper §6.3),
-  vectorized so the O(n²) column sweep is paid once for all Q values.
+* :func:`_optimal_multi` — design-space exploration over a Q_max range
+  (paper §6.3), vectorized so the O(n²) column sweep is paid once for all
+  Q values. It and :func:`_optimal_k` are the ``backend="numpy"`` reference
+  DP behind :func:`repro.api.solve`, and the oracle the jit backends are
+  tested against.
 * :func:`single_task_partition` / :func:`whole_app_partition` — the paper's
   two baselines (§6.3), including the un-optimized state retention of the
   *Single Task* scheme (every burst saves and restores all application data).
@@ -28,7 +31,6 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ._deprecation import warn_legacy
 from .burst import BurstDetail, ColumnSweep, burst_cost, burst_detail
 from .cost import CostModel
 from .graph import TaskGraph
@@ -39,14 +41,10 @@ __all__ = [
     "within_budget",
     "Partition",
     "Infeasible",
-    "optimal_partition",
-    "optimal_partition_k",
-    "optimal_partition_multi",
     "dijkstra_partition",
     "brute_force_partition",
     "q_min",
     "q_min_bruteforce",
-    "sweep",
     "single_task_partition",
     "whole_app_partition",
 ]
@@ -172,53 +170,15 @@ def _reconstruct(parent: np.ndarray, n: int) -> List[Tuple[int, int]]:
 # ---------------------------------------------------------------------------
 
 
-def optimal_partition(
-    graph: TaskGraph, cost: CostModel, q_max: Optional[float] = None
-) -> Partition:
-    """Minimize E_total subject to every burst ≤ Q_max (None = unbounded).
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graph=g, cost=cm,
-       q_max=q, backend="numpy")).partition()`` — bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition.optimal_partition",
-        "solve(PartitionSpec(graph=g, cost=cm, q_max=q, "
-        "backend='numpy')).partition()",
-    )
-    return _optimal_multi(graph, cost, [q_max])[0]
-
-
-def optimal_partition_multi(
-    graph: TaskGraph, cost: CostModel, q_values: Sequence[Optional[float]]
-) -> List[Optional[Partition]]:
-    """One column sweep, many Q_max values (design-space exploration).
-
-    Returns ``None`` for infeasible Q values instead of raising when more than
-    one Q is requested; raises :class:`Infeasible` for a single infeasible Q.
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graph=g, cost=cm,
-       q_grid=qs, backend="numpy")).partitions()`` — bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition.optimal_partition_multi",
-        "solve(PartitionSpec(graph=g, cost=cm, q_grid=qs, "
-        "backend='numpy')).partitions()",
-    )
-    return _optimal_multi(graph, cost, q_values)
-
-
 def _optimal_multi(
     graph: TaskGraph,
     cost: CostModel,
     q_values: Sequence[Optional[float]],
-    *,
-    raise_single: bool = True,
 ) -> List[Optional[Partition]]:
-    """Implementation behind ``optimal_partition*`` / ``sweep`` and the
-    façade's numpy backend. ``raise_single`` keeps the historical contract
-    (a lone infeasible Q raises) for the legacy shims; the façade passes
-    False so infeasibility always comes back as ``None`` and surfaces as
-    :class:`Infeasible` uniformly at ``Solution.partition()`` time.
+    """Minimize E_total subject to every burst ≤ Q_max, for every Q_max in
+    ``q_values`` (None = unbounded) in one column sweep — the façade's
+    numpy backend for ``objective="sum"``. Infeasible Q values come back as
+    ``None``; ``Solution.partition()`` raises :class:`Infeasible` for them.
     """
     n = graph.n_tasks
     nq = len(q_values)
@@ -245,8 +205,6 @@ def _optimal_multi(
     out: List[Optional[Partition]] = []
     for qi, q in enumerate(q_values):
         if not np.isfinite(dp[qi, n]):
-            if nq == 1 and raise_single:
-                raise Infeasible(f"Q_max={q} < Q_min={q_min(graph, cost):.6g}")
             out.append(None)
             continue
         bounds = _reconstruct(parent[qi], n)
@@ -256,34 +214,19 @@ def _optimal_multi(
     return out
 
 
-def optimal_partition_k(
+def _optimal_k(
     graph: TaskGraph, cost: CostModel, n_bursts: int,
     q_max: Optional[float] = None, objective: str = "sum",
 ) -> Partition:
-    """Optimal partition with *exactly* ``n_bursts`` bursts (beyond-paper
-    extension used for pipeline-stage assignment: K stages = K bursts).
+    """Optimal partition with *exactly* ``n_bursts`` bursts — the façade's
+    numpy backend for ``objective="exact_k"`` (pipeline-stage assignment:
+    K stages = K bursts).
 
     ``objective="sum"`` minimizes E_total (the paper's objective);
     ``objective="max"`` minimizes the largest burst (pipeline bottleneck —
     the §4.4 minimax criterion with a fixed stage count).
     DP over (bursts used, last task): O(K·n²).
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graph=g, cost=cm,
-       objective="exact_k", n_bursts=k, k_objective=..., q_max=q,
-       backend="numpy")).partition()`` — bit-identical.
     """
-    warn_legacy(
-        "repro.core.partition.optimal_partition_k",
-        "solve(PartitionSpec(graph=g, cost=cm, objective='exact_k', "
-        "n_bursts=k, backend='numpy')).partition()",
-    )
-    return _optimal_k(graph, cost, n_bursts, q_max, objective)
-
-
-def _optimal_k(
-    graph: TaskGraph, cost: CostModel, n_bursts: int,
-    q_max: Optional[float] = None, objective: str = "sum",
-) -> Partition:
     n = graph.n_tasks
     if not 1 <= n_bursts <= max(n, 1):
         raise ValueError(f"n_bursts={n_bursts} out of range for {n} tasks")
@@ -433,24 +376,8 @@ def q_min_bruteforce(graph: TaskGraph, cost: CostModel) -> float:
 
 
 # ---------------------------------------------------------------------------
-# Design-space exploration + baselines (§6.3)
+# Baselines (§6.3)
 # ---------------------------------------------------------------------------
-
-
-def sweep(
-    graph: TaskGraph, cost: CostModel, q_values: Sequence[float]
-) -> List[Optional[Partition]]:
-    """Optimal partitions across a Q_max range; None where infeasible.
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graph=g, cost=cm,
-       q_grid=qs, backend="numpy")).partitions()`` — bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition.sweep",
-        "solve(PartitionSpec(graph=g, cost=cm, q_grid=qs, "
-        "backend='numpy')).partitions()",
-    )
-    return _optimal_multi(graph, cost, list(q_values))
 
 
 def single_task_partition(

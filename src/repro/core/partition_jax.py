@@ -1,10 +1,9 @@
 """JAX-native, jit-compiled burst partitioning engine (paper §4.3–§4.4).
 
-Reached through the :mod:`repro.api` façade: the ``scan`` and ``pallas``
-registry backends (:mod:`repro.core.engine`) dispatch into the private
-implementations here, and the historical public entry points (``sweep_jax``,
-``sweep_jax_batched``, ``sweep_jax_sharded``, ``optimal_partition_jax``)
-survive as thin :class:`DeprecationWarning` shims over the same code.
+Reached through the :mod:`repro.api` façade only: its ``scan`` and
+``pallas`` registry backends (:mod:`repro.core.engine`) resolve the backend
+once per graph and call the private implementations here with that
+concrete name.
 
 This is the batched re-expression of the two numpy reference paths:
 
@@ -13,16 +12,17 @@ This is the batched re-expression of the two numpy reference paths:
   applying each task's three piecewise-constant updates as masked adds over
   the dense arrays exported by :meth:`TaskGraph.to_arrays`;
 
-* the forward DAG-DP (:func:`repro.core.partition.optimal_partition_multi`)
+* the forward DAG-DP (:func:`repro.core.partition._optimal_multi`)
   rides in the same scan, broadcast across an arbitrary Q_max grid — one
   compiled kernel juliennes the whole design space in one shot.
 
-A second ``vmap`` layer batches across *graphs*: :func:`sweep_jax_batched`
+A second ``vmap`` layer batches across *graphs*: :func:`_sweep_jax_batched`
 takes padded exports of different applications (the whole model zoo, lowered
 via :func:`repro.core.layer_profile.lower_config`) and solves them together.
 
 Two interchangeable backends drive the same host API (``backend=`` on
-:func:`sweep_jax` / :func:`sweep_jax_batched` / :func:`optimal_partition_jax`):
+:func:`_sweep_jax` / :func:`_sweep_jax_batched` / :func:`_sweep_jax_sharded`,
+and the ``_q_min_*`` / ``_optimal_k_*`` pairs):
 
 * ``"scan"`` — the ``lax.scan`` engine below over the dense
   :meth:`TaskGraph.to_arrays` export. Best for Q-grid-heavy DSE on graphs
@@ -33,8 +33,12 @@ Two interchangeable backends drive the same host API (``backend=`` on
   the full 5458-task head-count application has R ≈ 5452 (its sort task reads
   every score packet), which would dense-export ~1 GB; the CSR slot layout is
   ~400 kB and the kernel applies slot contributions in-register.
-* ``"auto"`` (default) — picks "pallas" when the dense export would exceed
-  ``_AUTO_DENSE_BYTES`` (or when handed a ``GraphCSRArrays``), else "scan".
+
+Which one a graph gets is decided in one place,
+:func:`repro.core.engine.resolve_jit_backend` (``backend="auto"`` picks
+"pallas" when the dense export would exceed ``engine._AUTO_DENSE_BYTES`` or
+when handed a ``GraphCSRArrays``, else "scan"). The functions here accept
+only the two concrete names.
 
 Serving-path behavior (ROADMAP "hoist dtype handling"): graph uploads are
 device-cached per export object, cost scalars per cost model, and both
@@ -78,9 +82,8 @@ from jax import lax
 from ..obs.metrics import METRICS
 from ..obs.trace import PID_SOLVER, TRACER
 from ._cache import weak_id_cache
-from ._deprecation import warn_legacy
 from .cost import CostModel, cost_scalars
-from .engine import ExportMismatch, resolve_jit_backend
+from .engine import ExportMismatch
 from .graph import (
     GraphArrays,
     GraphCSRArrays,
@@ -97,11 +100,7 @@ from .partition import (
 
 __all__ = [
     "JaxSweep",
-    "sweep_jax",
-    "sweep_jax_batched",
-    "sweep_jax_sharded",
     "shard_q_grid",
-    "optimal_partition_jax",
     "sweep_from_columns",
     "cost_scalars",
 ]
@@ -115,10 +114,6 @@ _ABS = BUDGET_ABS
 # the order-preserving unrolled loop to one masked 2-D reduction.
 _UNROLL_MAX = 8
 
-# backend="auto": route to the CSR/Pallas backend once the dense export would
-# cross this size (the full head-count graph is ~1 GB dense, ~400 kB CSR).
-_AUTO_DENSE_BYTES = 32 << 20
-
 # Trace-count regression hooks (incremented at trace time only; see the
 # no-retrace test in tests/test_partition_sweep.py). Registry-backed
 # (repro.obs.metrics) but still plain dicts to consumers.
@@ -129,7 +124,8 @@ TRACE_COUNT = METRICS.counter_dict(
 # Host-side solve counters (incremented per engine entry, cached or not):
 # the plan-table serving tests pin "zero partitioner solves on the request
 # path" against these, and the DSE tests pin "extending an untouched table
-# never re-solves existing cells".
+# never re-solves existing cells". The three sum keys count calls of
+# _sweep_jax, _sweep_jax_batched and _sweep_jax_sharded.
 SOLVE_COUNT = METRICS.counter_dict(
     "partition_jax.solve_count",
     (
@@ -466,15 +462,15 @@ def _as_csr(graph: AnyExport) -> GraphCSRArrays:
     return graph.to_csr_arrays() if isinstance(graph, TaskGraph) else graph
 
 
-def _select_backend(
-    graph: AnyExport, backend: str, objective: str = "sum"
-) -> str:
-    """Resolve ``backend="auto"`` per graph — delegates to the façade's
-    backend registry (:func:`repro.core.engine.resolve_jit_backend`), which
-    replaced the hand-rolled if-chain that used to live here. The size
-    threshold stays in this module as ``_AUTO_DENSE_BYTES`` (read at call
-    time, so tests can monkeypatch it)."""
-    return resolve_jit_backend(graph, backend, objective)
+def _check_backend(backend: str) -> str:
+    """The backend resolved by the engine; ``"auto"`` is resolved there
+    (:func:`repro.core.engine.resolve_jit_backend`), never here."""
+    if backend not in ("scan", "pallas"):
+        raise ValueError(
+            f"partition_jax solves on a resolved backend, 'scan' or "
+            f"'pallas'; got {backend!r}"
+        )
+    return backend
 
 
 # Serving-path upload caches (see core/_cache.py for the id+weakref idiom):
@@ -593,51 +589,28 @@ def _sweep_pallas(
     return sweep_from_columns(csr.n_tasks, q_values, mns, bests)
 
 
-def sweep_jax(
-    graph: AnyExport,
-    cost: CostModel,
-    q_values: Sequence[Optional[float]],
-    *,
-    backend: str = "auto",
-    interpret: Optional[bool] = None,
-) -> JaxSweep:
-    """One jitted pass: optimal E_total + bounds for every Q_max in the grid.
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graph=g, cost=cm,
-       q_grid=qs, backend=...)).sweep`` — bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition_jax.sweep_jax",
-        "solve(PartitionSpec(graph=g, cost=cm, q_grid=qs)).sweep",
-    )
-    return _sweep_jax(graph, cost, q_values, backend=backend,
-                      interpret=interpret)
-
-
 def _sweep_jax(
     graph: AnyExport,
     cost: CostModel,
     q_values: Sequence[Optional[float]],
     *,
-    backend: str = "auto",
+    backend: str,
     interpret: Optional[bool] = None,
 ) -> JaxSweep:
-    """Implementation behind ``sweep_jax`` and the façade's single-graph sum
-    dispatch: optimal E_total + bounds for every Q_max in the grid.
+    """The façade's single-graph sum dispatch: optimal E_total + bounds for
+    every Q_max in the grid.
 
-    Drop-in analogue of :func:`repro.core.partition.sweep` /
-    ``optimal_partition_multi`` — infeasible Q values come back with
-    ``feasible == False`` instead of None. An empty graph is trivially
-    feasible everywhere (matching the numpy path).
+    Analogue of the numpy :func:`repro.core.partition._optimal_multi` —
+    infeasible Q values come back with ``feasible == False`` instead of
+    None. An empty graph is trivially feasible everywhere (matching the
+    numpy path).
 
-    ``backend`` selects the dense ``lax.scan`` engine, the CSR/Pallas sweep
-    kernel, or lets ``"auto"`` route by dense-export size (module
-    docstring); ``interpret`` is forwarded to the Pallas backend (``None``
-    auto-selects interpret mode on CPU).
+    ``backend`` (``"scan"`` or ``"pallas"``) selects the dense ``lax.scan``
+    engine or the CSR/Pallas sweep kernel; ``interpret`` is forwarded to the
+    Pallas backend (``None`` auto-selects interpret mode on CPU).
     """
     SOLVE_COUNT["sweep_jax"] += 1
-    backend = _select_backend(graph, backend)
-    if backend == "pallas":
+    if _check_backend(backend) == "pallas":
         csr = _as_csr(graph)
         if csr.n_tasks == 0:
             return _empty_sweep(q_values)
@@ -663,37 +636,16 @@ def _sweep_jax(
         )
 
 
-def sweep_jax_batched(
-    graphs: Sequence[AnyExport],
-    cost: CostModel,
-    q_values: Sequence[Optional[float]],
-    *,
-    backend: str = "auto",
-    interpret: Optional[bool] = None,
-) -> List[JaxSweep]:
-    """Solve many applications × many Q_max values with one compiled kernel.
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graphs=gs, cost=cm,
-       q_grid=qs, backend=...)).sweeps`` — bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition_jax.sweep_jax_batched",
-        "solve(PartitionSpec(graphs=gs, cost=cm, q_grid=qs)).sweeps",
-    )
-    return _sweep_jax_batched(graphs, cost, q_values, backend=backend,
-                              interpret=interpret)
-
-
 def _sweep_jax_batched(
     graphs: Sequence[AnyExport],
     cost: CostModel,
     q_values: Sequence[Optional[float]],
     *,
-    backend: str = "auto",
+    backend: str,
     interpret: Optional[bool] = None,
 ) -> List[JaxSweep]:
-    """Implementation behind ``sweep_jax_batched`` and the façade's batched
-    sum dispatch.
+    """The façade's batched sum dispatch: many applications × many Q_max
+    values with one compiled kernel per backend.
 
     Scan backend: graphs pad to a common (N, R, W) via
     :func:`stack_graph_arrays` and solve in one ``vmap``. Pallas backend:
@@ -701,27 +653,11 @@ def _sweep_jax_batched(
     per export, and :func:`stack_csr_arrays` builds the same layout with a
     leading batch axis for vmap consumers — and the sweep kernel runs per
     graph: one compiled kernel (the padded shape is shared) applied
-    sequentially, since the DP grid is already sequential per graph.
-    ``backend="auto"`` resolves per member and solves each group with its
-    own backend (a mixed batch of dense and CSR exports is legal), keeping
-    one compilation per group.
+    sequentially, since the DP grid is already sequential per graph. A
+    mixed batch is split by backend in the engine, one call per group.
     """
     SOLVE_COUNT["sweep_jax_batched"] += 1
-    if backend == "auto":
-        resolved = [_select_backend(g, "auto") for g in graphs]
-        if "scan" in resolved and "pallas" in resolved:
-            out: List[Optional[JaxSweep]] = [None] * len(graphs)
-            for be in ("scan", "pallas"):
-                idx = [k for k, r in enumerate(resolved) if r == be]
-                group = _sweep_jax_batched(
-                    [graphs[k] for k in idx], cost, q_values,
-                    backend=be, interpret=interpret,
-                )
-                for k, res in zip(idx, group):
-                    out[k] = res
-            return out  # type: ignore[return-value]
-        backend = resolved[0] if resolved else "scan"
-    if backend == "pallas":
+    if _check_backend(backend) == "pallas":
         csrs = [_as_csr(g) for g in graphs]
         out = [None] * len(csrs)
         nonempty = [(k, a) for k, a in enumerate(csrs) if a.n_tasks > 0]
@@ -849,33 +785,6 @@ def _merge_sweeps(
     return out
 
 
-def sweep_jax_sharded(
-    graphs: Sequence[AnyExport],
-    cost: CostModel,
-    q_values: Sequence[Optional[float]],
-    *,
-    n_shards: int,
-    devices: Optional[Sequence] = None,
-    backend: str = "auto",
-    interpret: Optional[bool] = None,
-) -> List[JaxSweep]:
-    """Q-grid-sharded batched sweep: same results, many devices.
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graphs=gs, cost=cm,
-       q_grid=qs, sharding=QGridSharding(n_shards, devices))).sweeps`` —
-       bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition_jax.sweep_jax_sharded",
-        "solve(PartitionSpec(graphs=gs, cost=cm, q_grid=qs, "
-        "sharding=QGridSharding(n_shards, devices))).sweeps",
-    )
-    return _sweep_jax_sharded(
-        graphs, cost, q_values, n_shards=n_shards, devices=devices,
-        backend=backend, interpret=interpret,
-    )
-
-
 def _sweep_jax_sharded(
     graphs: Sequence[AnyExport],
     cost: CostModel,
@@ -883,7 +792,7 @@ def _sweep_jax_sharded(
     *,
     n_shards: int,
     devices: Optional[Sequence] = None,
-    backend: str = "auto",
+    backend: str,
     interpret: Optional[bool] = None,
 ) -> List[JaxSweep]:
     """Q-grid-sharded :func:`_sweep_jax_batched`: same results, many devices.
@@ -898,30 +807,30 @@ def _sweep_jax_sharded(
     left to default and too few local devices they run sequentially through
     the same vmapped kernel (one compile either way); explicit ``devices``
     that are too few raise ``ValueError``. Pallas/CSR
-    backend (or a mixed ``auto`` batch): chunks run as host-side
-    ``sweep_jax_batched`` calls — the kernel lanes the Q axis itself, so
-    chunked solves are already bit-stable there.
+    backend: chunks run as host-side :func:`_sweep_jax_batched` calls — the
+    kernel lanes the Q axis itself, so chunked solves are already bit-stable
+    there.
     """
     SOLVE_COUNT["sweep_jax_sharded"] += 1
+    _check_backend(backend)
     qs_np = _qs_array(q_values)
     chunks = shard_q_grid(qs_np.shape[0], n_shards)
     if not graphs:
         return []
 
-    resolved = {_select_backend(g, backend) for g in graphs}
-    arrays = [_as_arrays(g) for g in graphs] if resolved == {"scan"} else None
-    if arrays is None:
-        # CSR/Pallas (or mixed) batch: host-sharded chunk loop.
+    if backend == "pallas":
+        # CSR/Pallas batch: host-sharded chunk loop.
         qs_list = list(q_values)
         chunk_sweeps = [
             _sweep_jax_batched(
-                graphs, cost, qs_list[lo:hi], backend=backend,
+                graphs, cost, qs_list[lo:hi], backend="pallas",
                 interpret=interpret,
             )
             for (lo, hi) in chunks
         ]
         return _merge_sweeps(q_values, chunk_sweeps)
 
+    arrays = [_as_arrays(g) for g in graphs]
     out: List[Optional[JaxSweep]] = [None] * len(arrays)
     nonempty = [(k, a) for k, a in enumerate(arrays) if a.n_tasks > 0]
     for k, a in enumerate(arrays):
@@ -982,44 +891,9 @@ def _sweep_jax_sharded(
     return out  # type: ignore[return-value]
 
 
-def optimal_partition_jax(
-    graph: TaskGraph,
-    cost: CostModel,
-    q_max: Optional[float] = None,
-    *,
-    backend: str = "auto",
-) -> Partition:
-    """Single-Q convenience mirroring the legacy ``optimal_partition``
-    (raises :class:`Infeasible` when Q_max < Q_min).
-
-    .. deprecated:: use ``repro.api.solve(PartitionSpec(graph=g, cost=cm,
-       q_max=q, backend=...)).partition()`` — bit-identical.
-    """
-    warn_legacy(
-        "repro.core.partition_jax.optimal_partition_jax",
-        "solve(PartitionSpec(graph=g, cost=cm, q_max=q)).partition()",
-    )
-    return _optimal_partition_jax(graph, cost, q_max, backend=backend)
-
-
-def _optimal_partition_jax(
-    graph: TaskGraph,
-    cost: CostModel,
-    q_max: Optional[float] = None,
-    *,
-    backend: str = "auto",
-) -> Partition:
-    res = _sweep_jax(graph, cost, [q_max], backend=backend)
-    parts = res.to_partitions(graph, cost)
-    if parts[0] is None:
-        raise Infeasible(f"Q_max={q_max} admits no partition")
-    return parts[0]
-
-
 # ---------------------------------------------------------------------------
 # Jit-backend minimax / exact-K — the façade's objective= axis (scan re-
-# expressions + the Pallas kernel modes, routed per backend by the
-# _q_min_jit / _optimal_k_jit dispatchers below)
+# expressions + the Pallas kernel modes; the engine picks one per graph)
 # ---------------------------------------------------------------------------
 
 
@@ -1166,36 +1040,3 @@ def _optimal_k_pallas(
     part = _partition_from_bounds(graph, cost, bounds, q_max)
     part.validate(graph)
     return part
-
-
-def _q_min_jit(
-    graph: AnyExport,
-    cost: CostModel,
-    *,
-    backend: str = "auto",
-    interpret: Optional[bool] = None,
-) -> float:
-    """Route the façade's ``objective="minimax"`` to the resolved jit
-    backend (scan re-expression or Pallas kernel mode)."""
-    if _select_backend(graph, backend, objective="minimax") == "pallas":
-        return _q_min_pallas(graph, cost, interpret=interpret)
-    return _q_min_scan(graph, cost)
-
-
-def _optimal_k_jit(
-    graph: AnyExport,
-    cost: CostModel,
-    n_bursts: int,
-    q_max: Optional[float] = None,
-    objective: str = "sum",
-    *,
-    backend: str = "auto",
-    interpret: Optional[bool] = None,
-) -> Partition:
-    """Route the façade's ``objective="exact_k"`` to the resolved jit
-    backend (scan re-expression or Pallas kernel mode)."""
-    if _select_backend(graph, backend, objective="exact_k") == "pallas":
-        return _optimal_k_pallas(
-            graph, cost, n_bursts, q_max, objective, interpret=interpret
-        )
-    return _optimal_k_scan(graph, cost, n_bursts, q_max, objective)

@@ -31,8 +31,7 @@ Design-space exploration at scale (the sharded DSE subsystem):
   grid across a device mesh (pmap over emulated or real devices) and gathers
   per-shard columns into one table whose content is **byte-identical** to
   the unsharded :func:`build_plan_table` result (compare with
-  :meth:`PlanTable.content_digest`; :func:`shard_plan_table` survives as a
-  deprecation shim);
+  :meth:`PlanTable.content_digest`);
 * :func:`extend_plan_table` appends new buckets / Q points to an existing
   table *without re-solving any existing cell* — copied cells are byte-moved,
   only the genuinely new (bucket, Q) cells hit the engine, and the header's
@@ -48,10 +47,11 @@ extensions — produces the same payload bytes (the differential/property tier
 in tests/test_dse_shard.py pins this).
 
 Bit-exactness contract (tested in tests/test_plan_table.py): a table lookup
-returns bounds bit-identical to a direct :func:`optimal_partition_jax` solve
-of the same (graph, cost, Q) — the batched build pads graphs to a common
-shape, but padded slots contribute exact zeros and the per-Q DP rows are
-independent, so tabulated and direct plans agree bound-for-bound.
+returns bounds bit-identical to a direct single-graph
+``solve(PartitionSpec(...)).partition()`` of the same (graph, cost, Q) —
+the batched build pads graphs to a common shape, but padded slots
+contribute exact zeros and the per-Q DP rows are independent, so tabulated
+and direct plans agree bound-for-bound.
 """
 
 from __future__ import annotations
@@ -68,7 +68,6 @@ import numpy as np
 from ..configs.base import ModelConfig
 from ..obs.metrics import METRICS
 from ..obs.trace import PID_SOLVER, TRACER
-from ._deprecation import warn_legacy
 from .burst import burst_cost
 from .cost import CostModel, cost_scalars
 from .graph import TaskGraph
@@ -83,7 +82,6 @@ __all__ = [
     "SegmentPlan",
     "PlanTable",
     "build_plan_table",
-    "shard_plan_table",
     "extend_plan_table",
     "probe_plan_table",
     "config_fingerprint",
@@ -239,8 +237,8 @@ def config_fingerprint(
 class PlanTable:
     """Immutable (bucket × Q) grid of precomputed segment plans.
 
-    Construct via :func:`build_plan_table` / :func:`shard_plan_table` /
-    :func:`extend_plan_table` or :meth:`load`; query via :meth:`lookup`.
+    Construct via :func:`build_plan_table` / :func:`extend_plan_table` or
+    :meth:`load`; query via :meth:`lookup`.
     Storage is flat-ragged: entry ``(b, k)`` owns segment rows
     ``seg_ptr[b*nq+k] : seg_ptr[b*nq+k+1]`` of ``seg_start``/``seg_end``/
     ``cycle_energy`` (the CSR idiom the engine already uses for graphs).
@@ -779,41 +777,6 @@ def build_plan_table(
     return _build_table(
         cfg, shape_buckets, q_values, kind=kind, cost=cost, backend=backend,
         cache_dir=cache_dir, graphs=graphs, sharding=sharding,
-    )
-
-
-def shard_plan_table(
-    cfg: Union[ModelConfig, str],
-    shape_buckets: Sequence[Tuple[int, int]],
-    q_values: Sequence[Optional[float]],
-    *,
-    n_shards: int,
-    devices: Optional[Sequence] = None,
-    kind: str = "time",
-    cost: Optional[CostModel] = None,
-    backend: str = "auto",
-    cache_dir: Optional[str] = None,
-    graphs: Optional[Sequence[TaskGraph]] = None,
-) -> PlanTable:
-    """Sharded offline build.
-
-    .. deprecated:: use ``build_plan_table(...,
-       sharding=QGridSharding(n_shards, devices))`` — byte-identical output
-       (the two historical builders collapsed into one spec-shaped entry
-       point).
-    """
-    warn_legacy(
-        "repro.core.plan_table.shard_plan_table",
-        "build_plan_table(..., sharding=QGridSharding(n_shards, devices))",
-    )
-    from ..api import QGridSharding  # lazy: avoids an import cycle
-
-    return _build_table(
-        cfg, shape_buckets, q_values, kind=kind, cost=cost, backend=backend,
-        cache_dir=cache_dir, graphs=graphs,
-        sharding=QGridSharding(
-            int(n_shards), None if devices is None else tuple(devices)
-        ),
     )
 
 

@@ -1,9 +1,9 @@
 """Numpy oracles for the CSR column-sweep/DP kernel — all three objectives.
 
 Replays :class:`repro.core.burst.ColumnSweep` and the fused DPs of
-:func:`repro.core.partition.optimal_partition_multi` (sum),
+:func:`repro.core.partition._optimal_multi` (sum),
 :func:`repro.core.partition.q_min` (minimax), and
-:func:`repro.core.partition.optimal_partition_k` (exact-K) directly from a
+:func:`repro.core.partition._optimal_k` (exact-K) directly from a
 :class:`repro.core.graph.GraphCSRArrays` export — same slot order, same
 left-to-right accumulation, same first-minimum argmin and budget tolerance —
 so the (mns, bests) column tables are **bit-identical** to the numpy DP

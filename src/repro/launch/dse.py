@@ -4,7 +4,7 @@ The paper's Julienning flow is an offline DSE — solve the energy-bounded
 partition for every (application, E_burst) point of interest. This module is
 that flow at bucket-fleet scale: the (shape-bucket × Q-grid) work partitions
 across a device mesh (:func:`repro.launch.mesh.make_shard_mesh`; pmap over
-the Q-shard axis inside :func:`repro.core.partition_jax.sweep_jax_sharded`)
+the Q-shard axis inside :func:`repro.core.partition_jax._sweep_jax_sharded`)
 and the gathered per-shard columns assemble into one versioned table whose
 content is byte-identical to a single-host :func:`build_plan_table` run.
 

@@ -1,4 +1,4 @@
-"""Test bootstrap: import paths, marker registration, shared fixtures.
+"""Test bootstrap: import paths and shared fixtures.
 
 Makes ``repro`` importable without an install (the repo is src-layout and has
 no setup.py) and the sibling test helpers importable regardless of how pytest
@@ -21,22 +21,6 @@ _SRC = os.path.join(os.path.dirname(_TESTS), "src")
 for _p in (_SRC, _TESTS):
     if _p not in sys.path:
         sys.path.insert(0, _p)
-
-
-def pytest_configure(config):
-    config.addinivalue_line(
-        "markers",
-        "slow: long-running model-zoo smoke / kernel sweeps "
-        "(deselect with -m 'not slow' for the fast tier-1 job)",
-    )
-    config.addinivalue_line(
-        "markers",
-        "legacy: intentionally exercises the deprecated solver entry points "
-        "(the pre-façade differential/byte-identity pins). The CI "
-        "deprecation-gate step runs the fast tier with "
-        "-W error::DeprecationWarning and -m 'not legacy', proving no "
-        "internal module still routes through a legacy entry point.",
-    )
 
 
 @pytest.fixture(autouse=True)
@@ -92,17 +76,16 @@ def plan_grid():
 @pytest.fixture(scope="session")
 def smoke_plan_table(plan_grid):
     """Factory: smoke arch (or ModelConfig) → (cfg, cm, qs, table) built on
-    PLAN_BUCKETS. ``builder`` swaps in shard_plan_table etc.; extra kwargs
-    (n_shards, cache_dir, ...) forward to the builder."""
-    def _build(arch, kind="time", *, builder=None, buckets=None, **kwargs):
+    PLAN_BUCKETS. Extra kwargs (sharding, cache_dir, ...) forward to
+    build_plan_table."""
+    def _build(arch, kind="time", *, buckets=None, **kwargs):
         from repro.configs import SMOKE_CONFIGS
         from repro.core import build_plan_table
 
         cfg = SMOKE_CONFIGS[arch] if isinstance(arch, str) else arch
         cm, qs = plan_grid(cfg, kind)
-        build = builder if builder is not None else build_plan_table
-        table = build(cfg, buckets or PLAN_BUCKETS, qs, kind=kind, cost=cm,
-                      **kwargs)
+        table = build_plan_table(cfg, buckets or PLAN_BUCKETS, qs, kind=kind,
+                                 cost=cm, **kwargs)
         return cfg, cm, qs, table
 
     return _build

@@ -1,36 +1,25 @@
 """Façade tier: PartitionSpec → Engine.solve pins.
 
-Three layers, matching the acceptance criteria of the API redesign:
+Three layers:
 
-* **Differential per legacy entry point** (marked ``legacy`` — they call the
-  deprecated shims on purpose): one ``Engine.solve(PartitionSpec)`` call
-  reproduces, bit-identically, each of ``optimal_partition``,
-  ``optimal_partition_multi``, ``sweep``, ``optimal_partition_k``,
-  ``q_min``, ``sweep_jax``, ``sweep_jax_batched``, ``sweep_jax_sharded``,
-  ``optimal_partition_jax``, and ``shard_plan_table`` on every smoke config.
+* **Differentials on every smoke config**: the jit backends (scan and the
+  Pallas kernel in interpret mode) reproduce the numpy reference DP
+  (``backend="numpy"``) bit-for-bit through the same spec — single Q,
+  Q grids, exact-K, minimax, batched and Q-sharded sweeps — and batching,
+  sharding and mixed ``auto`` batches reproduce the plain single solve.
 * **Error paths**: ``Infeasible`` and ``UnsupportedObjective`` surface with
   the same type *and message* from every backend (numpy / scan / pallas /
   sharded) for the same spec; export mismatches raise the typed
   :class:`ExportMismatch` everywhere.
 * **Registry**: backends self-register with capability flags, custom
-  registries dispatch, and every legacy entry point emits exactly one
-  :class:`DeprecationWarning`.
-
-The static no-legacy-imports check at the bottom is the other half of the
-deprecation story: no non-test module under ``src/`` imports a legacy entry
-point directly (the CI gate enforces the dynamic version with
-``-W error::DeprecationWarning``).
+  registries dispatch, and the backend is resolved once, in the engine.
 """
 
-import ast
-import os
 import random
-import warnings
 
 import numpy as np
 import pytest
 
-from conftest import PLAN_BUCKETS
 from helpers_random import random_cost_model, random_task_graph
 
 from repro.api import (
@@ -96,56 +85,59 @@ def _assert_sweeps_equal(a, b, ctx=""):
 
 
 # ---------------------------------------------------------------------------
-# Differential: one façade call per legacy entry point, every smoke config
+# Differential: the jit backends against the numpy oracle, every smoke config
 # ---------------------------------------------------------------------------
 
+JIT_BACKENDS = ("scan", "pallas")
 
-@pytest.mark.legacy
+
+def _numpy_oracle(**spec):
+    return solve(PartitionSpec(backend="numpy", **spec))
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_matches_optimal_partition(arch, arch_case):
-    from repro.core.partition import optimal_partition
-
+    """One Q_max: scan and Pallas give the numpy oracle's partition."""
     g, cm, qs = arch_case(arch)
-    sol = solve(PartitionSpec(graph=g, cost=cm, q_max=qs[2], backend="numpy"))
-    _assert_parts_equal([sol.partition()], [optimal_partition(g, cm, qs[2])])
+    ref = _numpy_oracle(graph=g, cost=cm, q_max=qs[2]).partition()
+    for backend in JIT_BACKENDS:
+        sol = solve(PartitionSpec(graph=g, cost=cm, q_max=qs[2],
+                                  backend=backend))
+        _assert_parts_equal([sol.partition()], [ref], ctx=backend)
 
 
-@pytest.mark.legacy
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_matches_optimal_partition_multi_and_sweep(arch, arch_case):
-    from repro.core.partition import optimal_partition_multi, sweep
-
+    """A Q grid from infeasible to unbounded: per-Q partitions (None where
+    infeasible) match the numpy oracle on both jit backends."""
     g, cm, qs = arch_case(arch)
-    sol = solve(PartitionSpec(graph=g, cost=cm, q_grid=tuple(qs),
-                              backend="numpy"))
-    _assert_parts_equal(sol.partitions(), optimal_partition_multi(g, cm, qs))
-    _assert_parts_equal(sol.partitions(), sweep(g, cm, qs))
+    ref = _numpy_oracle(graph=g, cost=cm, q_grid=tuple(qs)).partitions()
+    assert ref[0] is None and ref[-1] is not None
+    for backend in JIT_BACKENDS:
+        sol = solve(PartitionSpec(graph=g, cost=cm, q_grid=tuple(qs),
+                                  backend=backend))
+        _assert_parts_equal(sol.partitions(), ref, ctx=backend)
 
 
-@pytest.mark.legacy
 @pytest.mark.parametrize("arch", ARCHS)
 @pytest.mark.parametrize("k_objective", ["sum", "max"])
 def test_facade_matches_optimal_partition_k(arch, k_objective, arch_case):
-    from repro.core.partition import optimal_partition_k
-
     g, cm, qs = arch_case(arch)
     k = min(3, g.n_tasks)
-    for backend in ("numpy", "scan", "pallas"):
+    ref = _numpy_oracle(graph=g, cost=cm, objective="exact_k", n_bursts=k,
+                        k_objective=k_objective).partition()
+    assert ref.n_bursts == k
+    for backend in JIT_BACKENDS:
         sol = solve(PartitionSpec(graph=g, cost=cm, objective="exact_k",
                                   n_bursts=k, k_objective=k_objective,
                                   backend=backend))
-        _assert_parts_equal(
-            [sol.partition()],
-            [optimal_partition_k(g, cm, k, objective=k_objective)],
-            ctx=backend,
-        )
+        _assert_parts_equal([sol.partition()], [ref], ctx=backend)
 
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_minimax_matches_q_min(arch, arch_case):
-    """objective='minimax' == the (non-deprecated) numpy q_min on every
-    backend — numpy, scan, and the Pallas kernel's minimax mode —
-    bit-for-bit."""
+    """objective='minimax' == the numpy q_min on every backend — numpy,
+    scan, and the Pallas kernel's minimax mode — bit-for-bit."""
     g, cm, qs = arch_case(arch)
     ref = q_min(g, cm)
     for backend in ("numpy", "scan", "pallas"):
@@ -154,143 +146,146 @@ def test_facade_minimax_matches_q_min(arch, arch_case):
         assert sol.q_min() == ref, backend
 
 
-@pytest.mark.legacy
+def _assert_sweep_matches_oracle(sweep, graph, cm, ref_parts, ctx=""):
+    """A JaxSweep against the numpy oracle's per-Q partitions: the same
+    feasibility and the same bursts."""
+    assert sweep.feasible.tolist() == [p is not None for p in ref_parts], ctx
+    _assert_parts_equal(sweep.to_partitions(graph, cm), ref_parts, ctx=ctx)
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_matches_sweep_jax(arch, arch_case):
-    from repro.core.partition_jax import sweep_jax
-
+    """The single-graph sweep table, under auto (scan here) and on Pallas,
+    reconstructs the numpy oracle's partitions."""
     g, cm, qs = arch_case(arch)
-    sol = solve(PartitionSpec(graph=g, cost=cm, q_grid=tuple(qs)))
-    _assert_sweeps_equal(sol.sweep, sweep_jax(g, cm, qs))
+    ref = _numpy_oracle(graph=g, cost=cm, q_grid=tuple(qs)).partitions()
+    for backend in ("auto", "pallas"):
+        sol = solve(PartitionSpec(graph=g, cost=cm, q_grid=tuple(qs),
+                                  backend=backend))
+        assert sol.backend == ("scan" if backend == "auto" else backend)
+        _assert_sweep_matches_oracle(sol.sweep, g, cm, ref, ctx=backend)
 
 
-@pytest.mark.legacy
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_matches_sweep_jax_batched(arch, arch_case):
-    from repro.core.partition_jax import sweep_jax_batched
-
+    """Two buckets in one batched solve: each member matches the numpy
+    oracle, and the single-graph solve of that member."""
     g, cm, qs = arch_case(arch)
     g2 = lower_config(SMOKE_CONFIGS[arch], batch=2, seq=24, kind="time")
-    sol = solve(PartitionSpec(graphs=(g, g2), cost=cm, q_grid=tuple(qs)))
-    for a, b in zip(sol.sweeps, sweep_jax_batched([g, g2], cm, qs)):
-        _assert_sweeps_equal(a, b, ctx=arch)
+    ref = _numpy_oracle(graphs=(g, g2), cost=cm, q_grid=tuple(qs))
+    for backend in JIT_BACKENDS:
+        sol = solve(PartitionSpec(graphs=(g, g2), cost=cm, q_grid=tuple(qs),
+                                  backend=backend))
+        for i, member in enumerate((g, g2)):
+            _assert_sweep_matches_oracle(sol.sweeps[i], member, cm,
+                                         ref.partitions(i),
+                                         ctx=(arch, backend, i))
+            alone = solve(PartitionSpec(graph=member, cost=cm,
+                                        q_grid=tuple(qs), backend=backend))
+            _assert_parts_equal(sol.partitions(i), alone.partitions(),
+                                ctx=(arch, backend, i))
 
 
-@pytest.mark.legacy
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_matches_sweep_jax_sharded(arch, arch_case):
-    from repro.core.partition_jax import sweep_jax_sharded
-
+    """Two Q shards give the unsharded solve's sweep table byte for byte,
+    and the numpy oracle's partitions."""
     g, cm, qs = arch_case(arch)
-    sol = solve(PartitionSpec(graphs=(g,), cost=cm, q_grid=tuple(qs),
-                              sharding=QGridSharding(n_shards=2)))
-    ref = sweep_jax_sharded([g], cm, qs, n_shards=2)
-    _assert_sweeps_equal(sol.sweeps[0], ref[0], ctx=arch)
+    ref = _numpy_oracle(graph=g, cost=cm, q_grid=tuple(qs)).partitions()
+    for backend in JIT_BACKENDS:
+        sol = solve(PartitionSpec(graphs=(g,), cost=cm, q_grid=tuple(qs),
+                                  sharding=QGridSharding(n_shards=2),
+                                  backend=backend))
+        whole = solve(PartitionSpec(graphs=(g,), cost=cm, q_grid=tuple(qs),
+                                    backend=backend))
+        _assert_sweeps_equal(sol.sweeps[0], whole.sweeps[0],
+                             ctx=(arch, backend))
+        _assert_sweep_matches_oracle(sol.sweeps[0], g, cm, ref,
+                                     ctx=(arch, backend))
 
 
-@pytest.mark.legacy
 @pytest.mark.parametrize("arch", ARCHS)
 def test_facade_matches_optimal_partition_jax(arch, arch_case):
-    from repro.core.partition_jax import optimal_partition_jax
-
+    """One Q_max under backend='auto' (scan for a smoke graph) == the numpy
+    oracle; below Q_min both raise the same Infeasible."""
     g, cm, qs = arch_case(arch)
     sol = solve(PartitionSpec(graph=g, cost=cm, q_max=qs[2]))
-    _assert_parts_equal([sol.partition()],
-                        [optimal_partition_jax(g, cm, qs[2])])
+    assert sol.backend == "scan"
+    _assert_parts_equal(
+        [sol.partition()],
+        [_numpy_oracle(graph=g, cost=cm, q_max=qs[2]).partition()],
+    )
+    msgs = []
+    for backend in ("auto", "numpy"):
+        with pytest.raises(Infeasible) as e:
+            solve(PartitionSpec(graph=g, cost=cm, q_max=qs[0],
+                                backend=backend)).partition()
+        msgs.append(str(e.value))
+    assert msgs[0] == msgs[1]
 
 
-@pytest.mark.legacy
 def test_facade_matches_pallas_sweep():
-    """The CSR/Pallas backend through the façade == legacy sweep_jax
-    (interpret mode; one config keeps the kernel tier fast)."""
-    from repro.core.partition_jax import sweep_jax
-
+    """The CSR/Pallas backend's sweep table == the scan backend's on the
+    same spec (interpret mode; one config keeps the kernel tier fast)."""
     cfg = SMOKE_CONFIGS["qwen3-4b"]
     cm = default_cost_model("time")
     g = lower_config(cfg, batch=2, seq=16, kind="time")
     qs = (q_min(g, cm), None)
     sol = solve(PartitionSpec(graph=g, cost=cm, q_grid=qs, backend="pallas"))
-    _assert_sweeps_equal(sol.sweep, sweep_jax(g, cm, list(qs),
-                                              backend="pallas"))
+    scan = solve(PartitionSpec(graph=g, cost=cm, q_grid=qs, backend="scan"))
     assert sol.backend == "pallas"
+    for field in ("dp", "e_total", "feasible", "starts"):
+        assert getattr(sol.sweep, field).tobytes() == \
+            getattr(scan.sweep, field).tobytes(), field
+    _assert_sweep_matches_oracle(
+        sol.sweep, g, cm,
+        _numpy_oracle(graph=g, cost=cm, q_grid=qs).partitions(),
+    )
 
 
-@pytest.mark.legacy
 def test_build_plan_table_sharding_matches_shard_plan_table(smoke_plan_table):
-    """build_plan_table(sharding=...) — the spec-shaped replacement — is
-    byte-identical to the deprecated shard_plan_table."""
-    from repro.core.plan_table import PlanTable, shard_plan_table
+    """build_plan_table(sharding=QGridSharding(4)) is byte-identical to the
+    unsharded build: every payload array and the content digest."""
+    from repro.core.plan_table import PlanTable
 
     cfg, cm, qs, single = smoke_plan_table("qwen3-4b")
-    via_param = smoke_plan_table(
+    sharded = smoke_plan_table(
         "qwen3-4b", sharding=QGridSharding(4)
     )[3]
-    legacy = shard_plan_table(cfg, PLAN_BUCKETS, qs, n_shards=4, cost=cm)
     for name in PlanTable._PAYLOAD:
-        assert getattr(via_param, name).tobytes() == \
-            getattr(legacy, name).tobytes(), name
-    assert via_param.content_digest() == legacy.content_digest()
-    assert via_param.content_digest() == single.content_digest()
+        assert getattr(sharded, name).tobytes() == \
+            getattr(single, name).tobytes(), name
+    assert sharded.content_digest() == single.content_digest()
+    assert sharded.fingerprint == single.fingerprint
 
 
-@pytest.mark.legacy
 def test_facade_mixed_auto_batch_matches_legacy(monkeypatch):
-    """A mixed dense/CSR/TaskGraph batch under backend='auto' resolves and
-    groups exactly like the legacy batched entry point."""
-    from repro.core import partition_jax
-    from repro.core.partition_jax import sweep_jax_batched
+    """A mixed dense/CSR/TaskGraph batch under backend='auto' resolves per
+    member and gives each member exactly its single-graph solve (a batch
+    pads its members to one task count, so the columns past a member's own
+    tasks are left out)."""
+    from repro.core import engine
 
     rng = random.Random(11)
     g1, g2, g3 = (random_task_graph(rng, max_tasks=6) for _ in range(3))
     cm = random_cost_model(rng)
-    monkeypatch.setattr(partition_jax, "_AUTO_DENSE_BYTES", 0)  # g3 → pallas
+    monkeypatch.setattr(engine, "_AUTO_DENSE_BYTES", 0)  # g3 → pallas
     qs = (None, 0.5)
     batch = (g1.to_arrays(), g2.to_csr_arrays(), g3)
     sol = solve(PartitionSpec(graphs=batch, cost=cm, q_grid=qs))
     assert sol.backend == "pallas+scan"
-    for a, b in zip(sol.sweeps, sweep_jax_batched(list(batch), cm, list(qs))):
-        _assert_sweeps_equal(a, b)
-
-
-# ---------------------------------------------------------------------------
-# Deprecation shims: every legacy entry point warns exactly once per call
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.legacy
-def test_every_legacy_entry_point_warns():
-    from repro.core import partition as p
-    from repro.core import partition_jax as pj
-    from repro.core import plan_table as pt
-
-    rng = random.Random(0)
-    g = random_task_graph(rng, max_tasks=5)
-    cm = random_cost_model(rng)
-    cfg = SMOKE_CONFIGS["qwen3-4b"]
-    cmt = default_cost_model("time")
-    calls = [
-        ("optimal_partition", lambda: p.optimal_partition(g, cm)),
-        ("optimal_partition_multi",
-         lambda: p.optimal_partition_multi(g, cm, [None])),
-        ("sweep", lambda: p.sweep(g, cm, [1e9])),
-        ("optimal_partition_k", lambda: p.optimal_partition_k(g, cm, 1)),
-        ("sweep_jax", lambda: pj.sweep_jax(g, cm, [None])),
-        ("sweep_jax_batched", lambda: pj.sweep_jax_batched([g], cm, [None])),
-        ("sweep_jax_sharded",
-         lambda: pj.sweep_jax_sharded([g], cm, [None, 1e9], n_shards=2)),
-        ("optimal_partition_jax", lambda: pj.optimal_partition_jax(g, cm)),
-        ("shard_plan_table",
-         lambda: pt.shard_plan_table(cfg, [(2, 16)], [None], n_shards=1,
-                                     cost=cmt)),
-    ]
-    for name, fn in calls:
-        with warnings.catch_warnings(record=True) as rec:
-            warnings.simplefilter("always")
-            fn()
-        dep = [w for w in rec if issubclass(w.category, DeprecationWarning)
-               and "legacy Julienning entry point" in str(w.message)]
-        assert len(dep) == 1, (name, [str(w.message) for w in rec])
-        assert name in str(dep[0].message), name
+    for member, got, backend in zip(batch, sol.sweeps,
+                                    ("scan", "pallas", "pallas")):
+        alone = solve(PartitionSpec(graph=member, cost=cm, q_grid=qs))
+        assert alone.backend == backend
+        n = alone.sweep.n_tasks + 1
+        assert got.n_tasks == alone.sweep.n_tasks
+        for field in ("dp", "parent", "starts"):
+            assert getattr(got, field)[:, :n].tobytes() == \
+                getattr(alone.sweep, field)[:, :n].tobytes(), (backend, field)
+        for field in ("e_total", "feasible"):
+            assert getattr(got, field).tobytes() == \
+                getattr(alone.sweep, field).tobytes(), (backend, field)
 
 
 # ---------------------------------------------------------------------------
@@ -607,76 +602,48 @@ def test_register_backend_rejects_unknown_objective_before_decorating():
         register_backend("x", objectives=("sum", "nope"), registry={})
 
 
-# ---------------------------------------------------------------------------
-# Static guard: no non-test module in src/ imports a legacy entry point
-# ---------------------------------------------------------------------------
+def test_backend_resolved_once_per_graph(monkeypatch, tiny_case):
+    """backend='auto' is resolved in the engine, one call per graph, for
+    every objective (that nothing below it takes 'auto' is the next
+    test's)."""
+    from repro.core import engine
 
-LEGACY_NAMES = {
-    "optimal_partition", "optimal_partition_multi", "optimal_partition_k",
-    "sweep", "sweep_jax", "sweep_jax_batched", "sweep_jax_sharded",
-    "optimal_partition_jax", "shard_plan_table",
-}
-# attribute accesses are checked too, for the names that are unambiguous
-# ("sweep" is excluded: Solution.sweep / Solution.sweeps are façade API)
-LEGACY_ATTRS = LEGACY_NAMES - {"sweep"}
-# the exact modules that define / re-export the shims (everything else in
-# src/, *including* other packages' __init__.py files, is checked)
-DEFINING = {
-    os.path.join("repro", "core", "partition.py"),
-    os.path.join("repro", "core", "partition_jax.py"),
-    os.path.join("repro", "core", "plan_table.py"),
-    os.path.join("repro", "core", "__init__.py"),
-}
+    g, cm = tiny_case
+    calls = []
+    real = engine.resolve_jit_backend
 
+    def counting(graph, backend="auto", *args, **kwargs):
+        calls.append(backend)
+        return real(graph, backend, *args, **kwargs)
 
-def test_no_src_module_imports_legacy_entry_points():
-    """No non-test module under src/ reaches a legacy entry point — neither
-    `from x import optimal_partition` nor `mod.optimal_partition(...)`. The
-    CI deprecation gate is the dynamic half of this check; the AST walk
-    also catches module-level and slow-path-only call sites no fast test
-    executes."""
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    offenders = []
-    for dirpath, _, files in os.walk(src):
-        for fname in files:
-            if not fname.endswith(".py"):
-                continue
-            path = os.path.join(dirpath, fname)
-            if os.path.relpath(path, src) in DEFINING:
-                continue
-            tree = ast.parse(open(path).read(), filename=path)
-            for node in ast.walk(tree):
-                bad = set()
-                if isinstance(node, ast.ImportFrom):
-                    bad = {a.name for a in node.names} & LEGACY_NAMES
-                elif isinstance(node, ast.Attribute):
-                    bad = {node.attr} & LEGACY_ATTRS
-                if bad:
-                    offenders.append(
-                        (os.path.relpath(path, src), node.lineno, sorted(bad))
-                    )
-    assert not offenders, offenders
-
-
-def test_legacy_guard_walk_covers_the_placement_subsystem():
-    """The AST walk above discovers files by os.walk — pin that the swarm
-    placement modules (and the data/ loader package) are actually under it,
-    so a future src-layout move can't silently exempt them."""
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    walked = set()
-    for dirpath, _, files in os.walk(src):
-        for fname in files:
-            if fname.endswith(".py"):
-                walked.add(os.path.relpath(os.path.join(dirpath, fname), src))
-    for mod in (
-        os.path.join("repro", "core", "placement.py"),
-        os.path.join("repro", "core", "placement_jax.py"),
-        os.path.join("repro", "data", "ns_optimizer.py"),
-        os.path.join("repro", "launch", "swarm.py"),
+    monkeypatch.setattr(engine, "resolve_jit_backend", counting)
+    csr = g.to_csr_arrays()
+    for objective, graphs, extra in (
+        ("sum", (g, csr), {"q_grid": (None, 1.0)}),
+        ("minimax", (g, csr), {}),
+        ("exact_k", (g, g), {"n_bursts": 2}),  # exact_k takes TaskGraphs
     ):
-        assert mod in walked, mod
+        calls.clear()
+        solve(PartitionSpec(graphs=graphs, cost=cm, objective=objective,
+                            **extra))
+        assert calls == ["auto", "auto"], objective
+
+
+@pytest.mark.parametrize(
+    "fn", ["_sweep_jax", "_sweep_jax_batched", "_sweep_jax_sharded"])
+def test_partition_jax_takes_only_resolved_backends(fn, tiny_case):
+    """The jit implementations accept the two concrete names the engine
+    resolves to; 'auto' or any other name is refused, not re-resolved."""
+    from repro.core import partition_jax as pj
+
+    g, cm = tiny_case
+    graphs = g if fn == "_sweep_jax" else [g]
+    extra = {"n_shards": 2} if fn == "_sweep_jax_sharded" else {}
+    for bad in ("auto", "numpy", "mosaic"):
+        with pytest.raises(ValueError, match="resolved backend"):
+            getattr(pj, fn)(graphs, cm, [None], backend=bad, **extra)
+    for good in ("scan", "pallas"):
+        getattr(pj, fn)(graphs, cm, [None], backend=good, **extra)
 
 
 def test_placement_api_exported_through_facade():

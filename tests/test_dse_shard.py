@@ -26,15 +26,11 @@ import random
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
-
 
 from conftest import PLAN_BUCKETS
 from helpers_random import random_cost_model, random_q_grid, random_task_graph
 
+from repro.api import PartitionSpec, QGridSharding, solve
 from repro.configs import SMOKE_CONFIGS
 from repro.core import (
     PlanTable,
@@ -45,11 +41,7 @@ from repro.core import (
     lower_config,
     probe_plan_table,
     q_min,
-    shard_plan_table,
     shard_q_grid,
-    sweep_jax,
-    sweep_jax_batched,
-    sweep_jax_sharded,
     whole_app_partition,
 )
 from repro.core import partition_jax
@@ -65,6 +57,14 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 SHARD_COUNTS = (1, 2, 4, 8)
+
+
+def _sweeps(graphs, cm, qs, *, backend, n_shards=None):
+    """The façade's batched sweep tables, Q-sharded when ``n_shards``."""
+    sharding = None if n_shards is None else QGridSharding(n_shards)
+    return solve(PartitionSpec(graphs=tuple(graphs), cost=cm,
+                               q_grid=tuple(qs), backend=backend,
+                               sharding=sharding)).sweeps
 
 
 def _clone(table: PlanTable) -> PlanTable:
@@ -114,10 +114,9 @@ def test_sweep_jax_sharded_matches_batched(seed):
     qmn = max(q_min(g, cm) for g in graphs)
     whole = max(whole_app_partition(g, cm).e_total for g in graphs)
     qs = random_q_grid(rng, qmn, whole)
-    ref = sweep_jax_batched(graphs, cm, qs, backend="scan")
+    ref = _sweeps(graphs, cm, qs, backend="scan")
     for n_shards in (1, 2, 3, len(qs)):
-        got = sweep_jax_sharded(graphs, cm, qs, n_shards=n_shards,
-                                backend="scan")
+        got = _sweeps(graphs, cm, qs, backend="scan", n_shards=n_shards)
         for g_idx, (r, s) in enumerate(zip(ref, got)):
             assert r.n_tasks == s.n_tasks
             for field in ("dp", "parent", "e_total", "feasible", "starts"):
@@ -132,8 +131,8 @@ def test_sweep_jax_sharded_pallas_chunks_match():
     g = random_task_graph(rng, max_tasks=8, min_tasks=4)
     cm = random_cost_model(rng)
     qs = random_q_grid(rng, q_min(g, cm), whole_app_partition(g, cm).e_total)
-    ref = sweep_jax_batched([g], cm, qs, backend="pallas")
-    got = sweep_jax_sharded([g], cm, qs, n_shards=3, backend="pallas")
+    ref = _sweeps([g], cm, qs, backend="pallas")
+    got = _sweeps([g], cm, qs, backend="pallas", n_shards=3)
     for field in ("dp", "parent", "e_total", "feasible", "starts"):
         assert getattr(ref[0], field).tobytes() == \
             getattr(got[0], field).tobytes(), field
@@ -143,8 +142,6 @@ def test_explicit_devices_too_few_for_shards_raise():
     """Named devices are a placement: a build asked to put 2 shards on one
     named device fails instead of running the shards back to back."""
     import jax
-
-    from repro.api import PartitionSpec, QGridSharding, solve
 
     rng = random.Random(3)
     g = random_task_graph(rng, max_tasks=6, min_tasks=3)
@@ -166,16 +163,17 @@ def test_sharded_build_bitidentical_to_single_host(arch, smoke_plan_table):
     bytes exactly (npz payload + header fingerprint + content digest)."""
     cfg, cm, qs, single = smoke_plan_table(arch)
     for n_shards in SHARD_COUNTS:
-        sharded = shard_plan_table(
-            cfg, PLAN_BUCKETS, qs, n_shards=n_shards, cost=cm
+        sharded = build_plan_table(
+            cfg, PLAN_BUCKETS, qs, cost=cm,
+            sharding=QGridSharding(n_shards),
         )
         _assert_tables_bitidentical(single, sharded)
 
 
 def test_sharded_save_load_roundtrip_preserves_digest(tmp_path,
                                                       smoke_plan_table):
-    _, _, _, table = smoke_plan_table("qwen3-4b", builder=shard_plan_table,
-                                      n_shards=4)
+    _, _, _, table = smoke_plan_table("qwen3-4b",
+                                      sharding=QGridSharding(4))
     path = str(tmp_path / "sharded.npz")
     table.save(path)
     loaded = PlanTable.load(path)
@@ -186,13 +184,13 @@ def test_sharded_table_lookups_match_direct_solves(smoke_plan_table):
     """ServePlanner against a sharded table answers every (bucket, Q) with
     bounds/energies bit-identical to direct engine solves."""
     cfg, cm, qs, table = smoke_plan_table("zamba2-7b",
-                                          builder=shard_plan_table,
-                                          n_shards=4)
+                                          sharding=QGridSharding(4))
     planner = ServePlanner(table)
     n_feasible = 0
     for (b, s) in PLAN_BUCKETS:
         g = lower_config(cfg, b, s, kind="time")
-        direct = sweep_jax(g, cm, qs)
+        direct = solve(PartitionSpec(graph=g, cost=cm,
+                                     q_grid=tuple(qs))).sweep
         for qi, q in enumerate(qs):
             if not direct.feasible[qi]:
                 continue

@@ -13,12 +13,8 @@ import pickle
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
 
-
+from repro.api import PartitionSpec, solve
 from repro.configs import SMOKE_CONFIGS
 from repro.core import (
     BurstRuntime,
@@ -27,10 +23,15 @@ from repro.core import (
     execute_atomic,
     external_inputs,
     lower_config,
-    optimal_partition,
     q_min,
 )
 from repro.core.apps.headcount import THERMAL, build_graph
+
+
+def _numpy_partition(graph, cost, q_max=None):
+    """The numpy reference DP's optimal partition under one Q_max."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_max=q_max,
+                               backend="numpy")).partition()
 
 
 class RecordingNVM(MemoryNVM):
@@ -80,7 +81,7 @@ def test_crash_at_every_burst_phase_matches_atomic(arch, graph):
 
     # a mid-granularity partition: several bursts, several tasks per burst
     qmn = q_min(graph, CM)
-    part = optimal_partition(graph, CM, qmn * 1.5)
+    part = _numpy_partition(graph, CM, qmn * 1.5)
     hook = CrashEverywhere()
     nvm = RecordingNVM()
     rt = BurstRuntime(graph, part, nvm, cost=CM, crash_hook=hook)

@@ -7,23 +7,30 @@ EXPERIMENTS.md §Paper-repro.
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
 
-
+from repro.api import PartitionSpec, solve
 from repro.core import (
     BurstRuntime,
     MemoryNVM,
     execute_atomic,
-    optimal_partition,
     q_min,
     single_task_partition,
-    sweep,
     whole_app_partition,
 )
 from repro.core.apps.headcount import THERMAL, VISUAL, build_graph, paper_cost_model
+
+
+def _numpy_partition(graph, cost, q_max=None):
+    """The numpy reference DP's optimal partition under one Q_max."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_max=q_max,
+                               backend="numpy")).partition()
+
+
+def _numpy_partitions(graph, cost, q_values):
+    """The numpy reference DP's per-Q partitions (None where infeasible)."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_grid=tuple(q_values),
+                               backend="numpy")).partitions()
+
 
 CM = paper_cost_model()
 
@@ -75,13 +82,13 @@ class TestPartitioningResults:
         assert q_min(thermal, CM) == pytest.approx(132e-3, abs=0.5e-3)
 
     def test_julienning_18_bursts(self, thermal):
-        p = optimal_partition(thermal, CM, 132e-3)
+        p = _numpy_partition(thermal, CM, 132e-3)
         assert p.n_bursts == 18  # Fig. 6
 
     def test_overhead_near_paper(self, thermal):
         # Fig. 6 / abstract: 2.79 mJ ≈ 0.12 % overhead. Our reconstruction
         # gives ~1.8 mJ ≈ 0.08 % — same order, see EXPERIMENTS.md.
-        p = optimal_partition(thermal, CM, 132e-3)
+        p = _numpy_partition(thermal, CM, 132e-3)
         pct = 100 * p.e_overhead / p.e_total
         assert pct < 0.2
         assert p.e_overhead < 3e-3
@@ -102,7 +109,7 @@ class TestPartitioningResults:
 
     def test_single_burst_when_qmax_exceeds_app(self, thermal):
         # §6.3: "Once Q_max > E_app + E_bootup, the optimal N_bursts is 1"
-        p = optimal_partition(thermal, CM, thermal.total_task_cost() * 1.01)
+        p = _numpy_partition(thermal, CM, thermal.total_task_cost() * 1.01)
         assert p.n_bursts == 1
 
 
@@ -110,13 +117,13 @@ class TestDesignSpace:
     def test_thermal_feasibility_range(self, thermal):
         # §6.4: thermal feasibility range is 1–18 bursts
         qs = np.geomspace(132e-3, 2.5, 24)
-        parts = [p for p in sweep(thermal, CM, qs) if p is not None]
+        parts = [p for p in _numpy_partitions(thermal, CM, qs) if p is not None]
         nb = [p.n_bursts for p in parts]
         assert max(nb) == 18 and min(nb) == 1
 
     def test_nbursts_monotone_nonincreasing(self, visual):
         qs = np.geomspace(4.5e-3, 2.4, 16)
-        parts = sweep(visual, CM, qs)
+        parts = _numpy_partitions(visual, CM, qs)
         nb = [p.n_bursts for p in parts if p is not None]
         assert all(a >= b for a, b in zip(nb, nb[1:]))
 
@@ -125,7 +132,7 @@ class TestDesignSpace:
         # ~500 in our reconstruction) because sensing is only 4.4 mJ
         qv, qt = q_min(visual, CM), q_min(thermal, CM)
         assert qv < qt / 25
-        pv = optimal_partition(visual, CM, qv)
+        pv = _numpy_partition(visual, CM, qv)
         assert pv.n_bursts > 400
 
     def test_overhead_below_3pct_at_4p3pct_storage(self, visual, thermal):
@@ -134,7 +141,7 @@ class TestDesignSpace:
         for g in (thermal, visual):
             e_app = g.total_task_cost()
             q = max(0.043 * e_app, q_min(g, CM))
-            p = optimal_partition(g, CM, q)
+            p = _numpy_partition(g, CM, q)
             assert p.e_overhead / p.e_total < 0.03
 
 
@@ -144,7 +151,7 @@ class TestExecutableCNN:
         g = build_graph(spec, with_fns=True, seed=3)
         ref = execute_atomic(g, {})
         assert int(ref["headcount"]) > 0
-        p = optimal_partition(g, CM, 132e-3)
+        p = _numpy_partition(g, CM, 132e-3)
         rt = BurstRuntime(g, p, MemoryNVM(), cost=CM)
         out = rt.run({})
         assert out["headcount"] == ref["headcount"]

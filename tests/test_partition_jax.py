@@ -1,9 +1,11 @@
 """Differential suite: the jitted engine vs the three numpy oracles.
 
 On ≥100 randomized graphs (1–20 tasks, random Q grids straddling the
-feasibility boundary) the jitted ``sweep_jax`` must agree with
+feasibility boundary) the jitted sweep (``solve(PartitionSpec(...)).sweep``
+under backend="auto") must agree with
 
-* :func:`optimal_partition_multi` — e_total AND reconstructed bounds
+* the numpy reference DP (``backend="numpy"``) — e_total AND reconstructed
+  bounds
   (bit-exact: the engine replays the numpy accumulation order, so even
   argmin tie-breaks match on unit-``c0_weight`` graphs);
 * :func:`dijkstra_partition` — e_total on every feasible Q;
@@ -21,11 +23,6 @@ import random
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
-
 
 from helpers_random import (
     adversarial_tie_graph,
@@ -36,6 +33,7 @@ from helpers_random import (
     tie_q_grid,
 )
 
+from repro.api import PartitionSpec, solve
 from repro.configs import REGISTRY
 from repro.core import (
     PAPER_FRAM_MODEL,
@@ -44,18 +42,12 @@ from repro.core import (
     brute_force_partition,
     dijkstra_partition,
     lower_zoo,
-    optimal_partition_multi,
     q_min,
     stack_graph_arrays,
     tpu_host_offload_model,
     whole_app_partition,
 )
 from repro.core.apps.headcount import THERMAL, build_graph
-from repro.core.partition_jax import (
-    optimal_partition_jax,
-    sweep_jax,
-    sweep_jax_batched,
-)
 
 CM = PAPER_FRAM_MODEL
 
@@ -66,9 +58,26 @@ PAD = dict(n_pad=20, r_pad=3, w_pad=2)
 REL = 1e-6  # spec'd tolerance; the engine is asserted exact/1e-9 below
 
 
+def _numpy_partitions(graph, cost, q_values):
+    """The numpy reference DP's per-Q partitions (None where infeasible)."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_grid=q_values,
+                               backend="numpy")).partitions()
+
+
+def _sweep(graph, cost, q_values):
+    """The jitted single-graph sweep table (backend='auto')."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_grid=q_values)).sweep
+
+
+def _sweeps(graphs, cost, q_values):
+    """The jitted batched sweep tables, one per graph (backend='auto')."""
+    return solve(PartitionSpec(graphs=graphs, cost=cost,
+                               q_grid=q_values)).sweeps
+
+
 def _assert_matches_oracles(g, cm, qs):
-    ref = optimal_partition_multi(g, cm, qs)
-    res = sweep_jax(g.to_arrays(**PAD), cm, qs)
+    ref = _numpy_partitions(g, cm, qs)
+    res = _sweep(g.to_arrays(**PAD), cm, qs)
     parts = res.to_partitions(g, cm)
     for q, r, p in zip(qs, ref, parts):
         if r is None:
@@ -116,7 +125,7 @@ def test_differential_tie_graphs(seed):
 
 def test_empty_graph_feasible_everywhere():
     g = GraphBuilder().build()
-    res = sweep_jax(g, CM, [None, 0.0, 1.0])
+    res = _sweep(g, CM, [None, 0.0, 1.0])
     assert res.feasible.all() and (res.e_total == 0.0).all()
     parts = res.to_partitions(g, CM)
     assert all(p is not None and p.n_bursts == 0 for p in parts)
@@ -127,10 +136,10 @@ def test_single_q_convenience_raises_infeasible():
     b.packet("x", 100, keep=True)
     b.task("t", writes=("x",), cost=1.0)
     g = b.build()
-    p = optimal_partition_jax(g, CM, None)
+    p = solve(PartitionSpec(graph=g, cost=CM, q_max=None)).partition()
     assert p.n_bursts == 1
     with pytest.raises(Infeasible):
-        optimal_partition_jax(g, CM, 1e-6)
+        solve(PartitionSpec(graph=g, cost=CM, q_max=1e-6)).partition()
 
 
 def test_dp_and_parent_tables_match_recurrence():
@@ -140,7 +149,7 @@ def test_dp_and_parent_tables_match_recurrence():
     cm = random_cost_model(rng)
     qmn = q_min(g, cm)
     qs = [qmn, qmn * 2.0, None]
-    res = sweep_jax(g, cm, qs)
+    res = _sweep(g, cm, qs)
     n = g.n_tasks
     assert res.dp.shape == (3, res.dp.shape[1]) and res.dp[:, 0].min() == 0.0
     # larger budget → every dp entry no worse
@@ -167,10 +176,10 @@ def test_zoo_configs_match_numpy_multi():
     qmns = {name: q_min(zoo[name], cm) for name in names}
     q_hi = max(qmns.values()) * 4
     qs = [None, 0.0, min(qmns.values()), q_hi]
-    results = sweep_jax_batched([zoo[n] for n in names], cm, qs)
+    results = _sweeps([zoo[n] for n in names], cm, qs)
     for name, res in zip(names, results):
         g = zoo[name]
-        ref = optimal_partition_multi(g, cm, qs)
+        ref = _numpy_partitions(g, cm, qs)
         parts = res.to_partitions(g, cm)
         for q, r, p in zip(qs, ref, parts):
             if r is None:
@@ -190,7 +199,7 @@ def test_zoo_memory_kind_q_min_sweep():
     zoo = lower_zoo(batch=1, seq=128, kind="memory")
     for name, g in sorted(zoo.items()):
         qmn = q_min(g, cm)
-        res = sweep_jax(g, cm, [qmn * 0.5, qmn, qmn * 4])
+        res = _sweep(g, cm, [qmn * 0.5, qmn, qmn * 4])
         assert not res.feasible[0] or qmn == 0.0
         assert res.feasible[1] and res.feasible[2]
         assert res.e_total[2] <= res.e_total[1] + 1e-9
@@ -204,8 +213,8 @@ def test_stacked_arrays_roundtrip():
     stacked = stack_graph_arrays([g.to_arrays() for g in graphs])
     assert stacked.e_task.shape[0] == len(graphs)
     qs = [None, 0.5]
-    for g, res in zip(graphs, sweep_jax_batched(graphs, CM, qs)):
-        ref = optimal_partition_multi(g, CM, qs)
+    for g, res in zip(graphs, _sweeps(graphs, CM, qs)):
+        ref = _numpy_partitions(g, CM, qs)
         for r, p in zip(ref, res.to_partitions(g, CM)):
             if r is None:
                 assert p is None
@@ -222,8 +231,8 @@ def test_headcount_reduced_matches_numpy():
     g = build_graph(THERMAL.reduced(256))
     qmn = q_min(g, CM)
     qs = list(np.geomspace(qmn, g.total_task_cost() * 1.05, 64)) + [None, 0.0]
-    ref = optimal_partition_multi(g, CM, qs)
-    res = sweep_jax(g, CM, qs)
+    ref = _numpy_partitions(g, CM, qs)
+    res = _sweep(g, CM, qs)
     parts = res.to_partitions(g, CM)
     for q, r, p in zip(qs, ref, parts):
         if r is None:

@@ -9,8 +9,9 @@ Layers under test, bottom-up:
 * ``kernels.partition_sweep.kernel`` (interpret mode) — bit-identical column
   tables (mns *and* argmin bests) against the ref, on random graphs, the
   adversarial equal-cost tie family, and lowered model-zoo graphs.
-* ``partition_jax`` backend plumbing — backend="pallas" returns the same
-  JaxSweep as backend="scan"/numpy; backend="auto" routes by export size;
+* backend plumbing through the façade — backend="pallas" returns the same
+  JaxSweep as backend="scan"/numpy; backend="auto" routes by export size
+  (``engine.resolve_jit_backend``);
   serving loops neither re-trace nor re-upload.
 * slow: the full (unreduced) 5458-task head-count graphs solve end-to-end
   through the CSR backend — the dense export would be ~1 GB and is never
@@ -21,11 +22,6 @@ import random
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
-
 
 from helpers_random import (
     adversarial_tie_graph,
@@ -44,20 +40,15 @@ from repro.core import (
     LinearTransfer,
     dense_export_nbytes,
     lower_config,
-    optimal_partition_multi,
     q_min,
     stack_csr_arrays,
     tpu_host_offload_model,
     whole_app_partition,
 )
-from repro.core import partition_jax
+from repro.core import engine, partition_jax
 from repro.core.apps.headcount import THERMAL, VISUAL, build_graph
-from repro.core.partition_jax import (
-    optimal_partition_jax,
-    sweep_from_columns,
-    sweep_jax,
-    sweep_jax_batched,
-)
+from repro.core.engine import resolve_jit_backend
+from repro.core.partition_jax import sweep_from_columns
 from repro.configs import REGISTRY
 from repro.api import PartitionSpec, solve
 from repro.core.partition import _optimal_k
@@ -70,6 +61,24 @@ from repro.kernels.partition_sweep.ref import (
 )
 
 CM = PAPER_FRAM_MODEL
+
+
+def _numpy_partitions(graph, cost, q_values):
+    """The numpy reference DP's per-Q partitions (None where infeasible)."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_grid=q_values,
+                               backend="numpy")).partitions()
+
+
+def _sweep(graph, cost, q_values, backend="auto"):
+    """The jitted single-graph sweep table."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_grid=q_values,
+                               backend=backend)).sweep
+
+
+def _sweeps(graphs, cost, q_values, backend="auto"):
+    """The jitted batched sweep tables, one per graph."""
+    return solve(PartitionSpec(graphs=graphs, cost=cost, q_grid=q_values,
+                               backend=backend)).sweeps
 
 
 def _case(seed):
@@ -157,8 +166,8 @@ def test_stack_csr_arrays_batches_heterogeneous_graphs():
     assert stacked.e_task.shape[0] == len(graphs)
     assert (np.asarray(stacked.n_tasks) == [g.n_tasks for g in graphs]).all()
     qs = [None, 0.5]
-    for g, res in zip(graphs, sweep_jax_batched(graphs, CM, qs, backend="pallas")):
-        ref = optimal_partition_multi(g, CM, qs)
+    for g, res in zip(graphs, _sweeps(graphs, CM, qs, backend="pallas")):
+        ref = _numpy_partitions(g, CM, qs)
         for r, p in zip(ref, res.to_partitions(g, CM)):
             if r is None:
                 assert p is None
@@ -172,10 +181,10 @@ def test_stack_csr_arrays_batches_heterogeneous_graphs():
 
 @pytest.mark.parametrize("seed", range(20))
 def test_ref_matches_numpy_dp(seed):
-    """The numpy CSR sweep is bit-identical to optimal_partition_multi —
+    """The numpy CSR sweep is bit-identical to the numpy DP —
     e_total AND reconstructed bounds, including Infeasible cases."""
     g, cm, qs = _case(seed)
-    ref = optimal_partition_multi(g, cm, qs)
+    ref = _numpy_partitions(g, cm, qs)
     res = sweep_from_columns(
         g.n_tasks, qs, *sweep_columns_ref(g.to_csr_arrays(), cm, qs)
     )
@@ -426,7 +435,7 @@ def test_csr_export_minimax_routes_to_pallas():
                             objective="minimax"))
     assert s.backend == "pallas"
     assert s.q_min() == q_min(g, cm)
-    assert partition_jax._select_backend(
+    assert resolve_jit_backend(
         g.to_csr_arrays(), "auto", objective="minimax") == "pallas"
 
 
@@ -454,9 +463,9 @@ def test_tie_audit_numpy_scan_pallas(seed):
     CSR/Pallas backend must agree on e_total bits AND reconstructed bounds
     (argmin tie-break: smallest burst start wins)."""
     g, cm, qs = _tie_case(seed)
-    ref = optimal_partition_multi(g, cm, qs)
-    scan = sweep_jax(g, cm, qs, backend="scan")
-    pall = sweep_jax(g, cm, qs, backend="pallas")
+    ref = _numpy_partitions(g, cm, qs)
+    scan = _sweep(g, cm, qs, backend="scan")
+    pall = _sweep(g, cm, qs, backend="pallas")
     _assert_bitequal(scan.dp, pall.dp, seed)
     for qi, (q, r) in enumerate(zip(qs, ref)):
         if r is None:
@@ -505,8 +514,8 @@ def test_tie_audit_chunked_all_objectives(seed, slot_chunk):
 @pytest.mark.parametrize("seed", range(10))
 def test_engine_pallas_vs_scan(seed):
     g, cm, qs = _case(200 + seed)
-    a = sweep_jax(g, cm, qs, backend="scan")
-    b = sweep_jax(g, cm, qs, backend="pallas")
+    a = _sweep(g, cm, qs, backend="scan")
+    b = _sweep(g, cm, qs, backend="pallas")
     _assert_bitequal(a.dp, b.dp, seed)
     assert (a.feasible == b.feasible).all()
     for qi in range(len(qs)):
@@ -515,31 +524,31 @@ def test_engine_pallas_vs_scan(seed):
 
 def test_backend_selection():
     g, _, _ = _case(1)
-    assert partition_jax._select_backend(g, "scan") == "scan"
-    assert partition_jax._select_backend(g, "pallas") == "pallas"
-    assert partition_jax._select_backend(g, "auto") == "scan"  # tiny graph
-    assert partition_jax._select_backend(g.to_arrays(), "auto") == "scan"
-    assert partition_jax._select_backend(g.to_csr_arrays(), "auto") == "pallas"
+    assert resolve_jit_backend(g, "scan") == "scan"
+    assert resolve_jit_backend(g, "pallas") == "pallas"
+    assert resolve_jit_backend(g, "auto") == "scan"  # tiny graph
+    assert resolve_jit_backend(g.to_arrays(), "auto") == "scan"
+    assert resolve_jit_backend(g.to_csr_arrays(), "auto") == "pallas"
     with pytest.raises(ValueError):
-        partition_jax._select_backend(g, "mosaic")
+        resolve_jit_backend(g, "mosaic")
     # explicit exports refuse the wrong backend instead of silently converting
     with pytest.raises(TypeError):
-        sweep_jax(g.to_csr_arrays(), CM, [None], backend="scan")
+        _sweep(g.to_csr_arrays(), CM, [None], backend="scan")
     with pytest.raises(TypeError):
-        sweep_jax(g.to_arrays(), CM, [None], backend="pallas")
+        _sweep(g.to_arrays(), CM, [None], backend="pallas")
     # the full head-count shape routes to pallas purely by export size
     full = THERMAL
     n = full.n_tasks
     r = sum(full.n_cnn)  # the sort task's read degree
-    assert dense_export_nbytes(n, r, 1) > partition_jax._AUTO_DENSE_BYTES
+    assert dense_export_nbytes(n, r, 1) > engine._AUTO_DENSE_BYTES
 
 
 def test_auto_threshold_routes_small_graph(monkeypatch):
     g, cm, qs = _case(2)
-    monkeypatch.setattr(partition_jax, "_AUTO_DENSE_BYTES", 0)
-    assert partition_jax._select_backend(g, "auto") == "pallas"
-    res = sweep_jax(g, cm, qs)  # default backend="auto" → pallas
-    ref = sweep_jax(g, cm, qs, backend="scan")
+    monkeypatch.setattr(engine, "_AUTO_DENSE_BYTES", 0)
+    assert resolve_jit_backend(g, "auto") == "pallas"
+    res = _sweep(g, cm, qs)  # default backend="auto" → pallas
+    ref = _sweep(g, cm, qs, backend="scan")
     _assert_bitequal(res.dp, ref.dp)
 
 
@@ -548,12 +557,12 @@ def test_batched_auto_mixed_exports(monkeypatch):
     to different backends — solves per-group under backend='auto' with
     order preserved."""
     g1, g2, g3 = (_case(40 + s)[0] for s in range(3))
-    monkeypatch.setattr(partition_jax, "_AUTO_DENSE_BYTES", 0)  # g3 → pallas
+    monkeypatch.setattr(engine, "_AUTO_DENSE_BYTES", 0)  # g3 → pallas
     qs = [None, 0.5]
     batch = [g1.to_arrays(), g2.to_csr_arrays(), g3]
-    results = sweep_jax_batched(batch, CM, qs)
+    results = _sweeps(batch, CM, qs)
     for g, res in zip((g1, g2, g3), results):
-        ref = optimal_partition_multi(g, CM, qs)
+        ref = _numpy_partitions(g, CM, qs)
         for r, p in zip(ref, res.to_partitions(g, CM)):
             if r is None:
                 assert p is None
@@ -577,33 +586,35 @@ def test_batched_pallas_reuses_padded_rows():
     # already-matching shapes short-circuit to the export itself
     assert partition_jax._padded_csr(rows[0], n, r, w) is rows[0]
     qs = [None, 1.0]
-    first = sweep_jax_batched(graphs, CM, qs, backend="pallas")
+    first = _sweeps(graphs, CM, qs, backend="pallas")
     traces = sweep_kernel.TRACE_COUNT["sweep_columns"]
-    second = sweep_jax_batched(graphs, CM, qs, backend="pallas")
+    second = _sweeps(graphs, CM, qs, backend="pallas")
     assert sweep_kernel.TRACE_COUNT["sweep_columns"] == traces
     for a, b in zip(first, second):
         _assert_bitequal(a.dp, b.dp)
 
 
 def test_empty_and_single_task_pallas():
-    assert sweep_jax(GraphBuilder().build(), CM, [None, 0.0],
+    assert _sweep(GraphBuilder().build(), CM, [None, 0.0],
                      backend="pallas").feasible.all()
     b = GraphBuilder()
     b.packet("x", 128, keep=True)
     b.task("t", writes=("x",), cost=1.0)
     g = b.build()
-    p = optimal_partition_jax(g, CM, None, backend="pallas")
+    p = solve(PartitionSpec(graph=g, cost=CM, q_max=None,
+                            backend="pallas")).partition()
     assert p.n_bursts == 1
     with pytest.raises(Infeasible):
-        optimal_partition_jax(g, CM, 1e-9, backend="pallas")
+        solve(PartitionSpec(graph=g, cost=CM, q_max=1e-9,
+                            backend="pallas")).partition()
 
 
 def test_zoo_config_pallas_matches_numpy():
     cm = tpu_host_offload_model()
     g = lower_config(REGISTRY["qwen1.5-0.5b"], batch=2, seq=256)
     qs = [None, q_min(g, cm), q_min(g, cm) * 4]
-    ref = optimal_partition_multi(g, cm, qs)
-    res = sweep_jax(g, cm, qs, backend="pallas")
+    ref = _numpy_partitions(g, cm, qs)
+    res = _sweep(g, cm, qs, backend="pallas")
     for q, r, p in zip(qs, ref, res.to_partitions(g, cm)):
         assert p is not None and r is not None
         assert p.e_total == r.e_total and p.bounds == r.bounds, q
@@ -615,8 +626,8 @@ def test_headcount_reduced_pallas_matches_numpy():
     g = build_graph(THERMAL.reduced(256))
     qmn = q_min(g, CM)
     qs = list(np.geomspace(qmn, g.total_task_cost() * 1.05, 16)) + [None, 0.0]
-    ref = optimal_partition_multi(g, CM, qs)
-    res = sweep_jax(g, CM, qs, backend="pallas")
+    ref = _numpy_partitions(g, CM, qs)
+    res = _sweep(g, CM, qs, backend="pallas")
     for q, r, p in zip(qs, ref, res.to_partitions(g, CM)):
         if r is None:
             assert p is None
@@ -631,14 +642,14 @@ def test_serving_loop_no_retrace_no_reupload():
     must not re-trace either backend nor re-upload the graph per request."""
     g, cm, _ = _case(4)
     qs1, qs2 = [None, 1.0], [None, 2.0]
-    sweep_jax(g, cm, qs1, backend="scan")
-    sweep_jax(g, cm, qs1, backend="pallas")
+    _sweep(g, cm, qs1, backend="scan")
+    _sweep(g, cm, qs1, backend="pallas")
     t_scan = partition_jax.TRACE_COUNT["dp_sweep"]
     t_pall = sweep_kernel.TRACE_COUNT["sweep_columns"]
     ga_id = id(partition_jax._ga_dict(g.to_arrays()))
     for qs in (qs1, qs2, qs1):
-        a = sweep_jax(g, cm, qs, backend="scan")
-        b = sweep_jax(g, cm, qs, backend="pallas")
+        a = _sweep(g, cm, qs, backend="scan")
+        b = _sweep(g, cm, qs, backend="pallas")
         _assert_bitequal(a.dp, b.dp)
     assert partition_jax.TRACE_COUNT["dp_sweep"] == t_scan
     assert sweep_kernel.TRACE_COUNT["sweep_columns"] == t_pall
@@ -658,7 +669,7 @@ def test_full_headcount_solves_through_csr_backend():
     """
     for spec in (THERMAL, VISUAL):
         g = build_graph(spec)
-        assert partition_jax._select_backend(g, "auto") == "pallas"
+        assert resolve_jit_backend(g, "auto") == "pallas"
         csr = g.to_csr_arrays()
         r = max(len(t.reads) for t in g.tasks)
         w = max(len(t.writes) for t in g.tasks)
@@ -666,7 +677,7 @@ def test_full_headcount_solves_through_csr_backend():
         assert dense_bytes >= 50 * csr.nbytes, (dense_bytes, csr.nbytes)
 
         qs = [132e-3, None]
-        res = sweep_jax(g, CM, qs)  # auto → pallas
+        res = _sweep(g, CM, qs)  # auto → pallas
         assert res.feasible.all()
         e_app = g.total_task_cost()
         assert res.e_total[1] >= e_app  # total can't beat pure execution
@@ -681,8 +692,8 @@ def test_full_headcount_solves_through_csr_backend():
     # reduced cross-check: same pipeline, bounds bit-equal to the numpy DP
     g = build_graph(THERMAL.reduced(64))
     qs = [132e-3, q_min(g, CM), None]
-    ref = optimal_partition_multi(g, CM, qs)
-    res = sweep_jax(g, CM, qs, backend="pallas")
+    ref = _numpy_partitions(g, CM, qs)
+    res = _sweep(g, CM, qs, backend="pallas")
     for q, r_, p in zip(qs, ref, res.to_partitions(g, CM)):
         assert r_ is not None and p is not None
         assert p.e_total == r_.e_total and p.bounds == r_.bounds, q

@@ -1,7 +1,8 @@
 """Differential suite for the plan-table subsystem (serving-path integration).
 
 * every smoke config × shape bucket: table lookups return segment bounds
-  bit-identical to direct ``optimal_partition_jax`` / ``sweep_jax`` solves;
+  bit-identical to direct single-graph façade solves (``.sweep`` over the
+  Q grid, ``.partition()`` at one Q);
 * save → load → lookup round-trips exactly (bounds, e_total, cycle energies);
 * stale-version and unknown-bucket lookups raise cleanly;
 * the fingerprint-keyed build cache short-circuits the solve;
@@ -17,14 +18,10 @@ tests/test_serve_plan.py and the sharded-DSE tier in tests/test_dse_shard.py.
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
-
 
 from conftest import PLAN_BUCKETS
 
+from repro.api import PartitionSpec, solve
 from repro.configs import SMOKE_CONFIGS
 from repro.core import (
     Infeasible,
@@ -35,8 +32,6 @@ from repro.core import (
     build_plan_table,
     config_fingerprint,
     lower_config,
-    optimal_partition_jax,
-    sweep_jax,
 )
 from repro.core import plan_table as pt_mod
 from repro.core import partition_jax
@@ -52,7 +47,7 @@ def test_lookup_bitidentical_to_direct_solve(arch, smoke_plan_table):
     cfg, cm, qs, table = smoke_plan_table(arch)
     for (b, s) in BUCKETS:
         g = lower_config(cfg, b, s, kind="time")
-        direct = sweep_jax(g, cm, qs)
+        direct = solve(PartitionSpec(graph=g, cost=cm, q_grid=qs)).sweep
         for qi, q in enumerate(qs):
             if not direct.feasible[qi]:
                 with pytest.raises(Infeasible):
@@ -63,7 +58,8 @@ def test_lookup_bitidentical_to_direct_solve(arch, smoke_plan_table):
             assert plan.e_total == direct.e_total[qi], (arch, b, s, q)
             assert plan.n_tasks == g.n_tasks
         # the single-Q convenience API agrees too (bounds bit-identical)
-        part = optimal_partition_jax(g, cm, qs[-2])
+        part = solve(PartitionSpec(graph=g, cost=cm,
+                                   q_max=qs[-2])).partition()
         assert list(table.lookup(b, s, qs[-2]).bounds) == part.bounds
 
 

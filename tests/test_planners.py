@@ -1,5 +1,5 @@
 """Julienne-planner tests: pipeline / offload / remat over the model zoo,
-plus optimal_partition_k invariants.
+plus invariants of the exact-K partition (``objective="exact_k"``).
 
 The partition-k properties are plain ``check_*`` functions driven by a
 stdlib-``random`` seed parametrization (always runs) and additionally by
@@ -11,15 +11,11 @@ import random
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
 
-
+from repro.api import PartitionSpec, solve
 from repro.configs import REGISTRY
 from repro.core import (GraphBuilder, Infeasible, PAPER_FRAM_MODEL,
-                        brute_force_partition, optimal_partition_k, q_min)
+                        brute_force_partition, q_min)
 from repro.core.layer_profile import build_activation_graph, profile_model
 from repro.core.offload import min_activation_budget, plan_offload
 from repro.core.pipeline import plan_pipeline
@@ -31,6 +27,13 @@ try:
     HAVE_HYPOTHESIS = True
 except ImportError:
     HAVE_HYPOTHESIS = False
+
+
+def _numpy_partition_k(graph, cost, n_bursts, objective="sum"):
+    """The numpy reference DP's optimal partition into exactly n_bursts."""
+    return solve(PartitionSpec(graph=graph, cost=cost, objective="exact_k",
+                               n_bursts=n_bursts, k_objective=objective,
+                               backend="numpy")).partition()
 
 
 def chain_graph(costs, nbytes=1000):
@@ -47,7 +50,7 @@ def check_k_bursts_exact_count(costs, k):
     if k > len(costs):
         k = len(costs)
     g = chain_graph(costs)
-    p = optimal_partition_k(g, PAPER_FRAM_MODEL, k)
+    p = _numpy_partition_k(g, PAPER_FRAM_MODEL, k)
     assert p.n_bursts == k
     p.validate(g)
 
@@ -55,7 +58,7 @@ def check_k_bursts_exact_count(costs, k):
 def check_minimax_beats_uniform_split(costs):
     g = chain_graph(costs)
     k = 3 if len(costs) >= 3 else len(costs)
-    p = optimal_partition_k(g, PAPER_FRAM_MODEL, k, objective="max")
+    p = _numpy_partition_k(g, PAPER_FRAM_MODEL, k, objective="max")
     # uniform split is a candidate → optimum bottleneck ≤ its bottleneck
     n = len(costs)
     bounds, start = [], 1
@@ -83,7 +86,7 @@ class TestPartitionK:
 
     def test_k_equals_brute_force(self):
         g = chain_graph([1.0, 3.0, 0.5, 2.0, 1.5])
-        p = optimal_partition_k(g, PAPER_FRAM_MODEL, 2)
+        p = _numpy_partition_k(g, PAPER_FRAM_MODEL, 2)
         # brute force over all 2-burst splits
         from repro.core.burst import burst_cost
         best = min(

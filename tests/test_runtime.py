@@ -5,12 +5,8 @@ import tempfile
 
 import numpy as np
 import pytest
-# These suites pin the *legacy* entry points (deprecation shims) bit-for-bit
-# against the facade-era implementations; the CI deprecation gate excludes
-# them via -m "not legacy" (see conftest).
-pytestmark = pytest.mark.legacy
 
-
+from repro.api import PartitionSpec, solve
 from repro.core import (
     PAPER_FRAM_MODEL,
     BurstRuntime,
@@ -19,9 +15,15 @@ from repro.core import (
     MemoryNVM,
     PowerFailure,
     execute_atomic,
-    optimal_partition,
     single_task_partition,
 )
+
+
+def _numpy_partition(graph, cost, q_max=None):
+    """The numpy reference DP's optimal partition under one Q_max."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_max=q_max,
+                               backend="numpy")).partition()
+
 
 CM = PAPER_FRAM_MODEL
 
@@ -66,7 +68,7 @@ def test_partitioned_equals_atomic(graph, inputs):
     from repro.core import q_min
     qmn = q_min(graph, CM)
     for qmax in [None, 3 * qmn, qmn]:
-        part = optimal_partition(graph, CM, qmax)
+        part = _numpy_partition(graph, CM, qmax)
         rt = BurstRuntime(graph, part, MemoryNVM(), cost=CM)
         out = rt.run(inputs)
         np.testing.assert_array_equal(out["x12"], ref["x12"])
@@ -74,7 +76,7 @@ def test_partitioned_equals_atomic(graph, inputs):
 
 def test_energy_and_bytes_match_model(graph, inputs):
     from repro.core import q_min
-    part = optimal_partition(graph, CM, 1.5 * q_min(graph, CM))
+    part = _numpy_partition(graph, CM, 1.5 * q_min(graph, CM))
     rt = BurstRuntime(graph, part, MemoryNVM(), cost=CM)
     rt.run(inputs)
     assert rt.stats.energy == pytest.approx(part.e_total, rel=1e-12)
@@ -88,7 +90,7 @@ def test_energy_and_bytes_match_model(graph, inputs):
 def test_crash_recovery_bit_exact(graph, inputs, crash_p):
     from repro.core import q_min
     ref = execute_atomic(graph, inputs)
-    part = optimal_partition(graph, CM, 1.5 * q_min(graph, CM))
+    part = _numpy_partition(graph, CM, 1.5 * q_min(graph, CM))
     rng = random.Random(int(crash_p * 100))
 
     def chaos(b, phase):
@@ -104,7 +106,7 @@ def test_crash_recovery_bit_exact(graph, inputs, crash_p):
 
 def test_crash_before_commit_replays_burst(graph, inputs):
     from repro.core import q_min
-    part = optimal_partition(graph, CM, 1.5 * q_min(graph, CM))
+    part = _numpy_partition(graph, CM, 1.5 * q_min(graph, CM))
     crashed = []
 
     def crash_once(b, phase):
@@ -124,7 +126,7 @@ def test_disk_nvm_resume_across_instances(graph, inputs):
     """Simulates full process death: a NEW runtime resumes from disk."""
     from repro.core import q_min
     ref = execute_atomic(graph, inputs)
-    part = optimal_partition(graph, CM, 1.5 * q_min(graph, CM))
+    part = _numpy_partition(graph, CM, 1.5 * q_min(graph, CM))
     with tempfile.TemporaryDirectory() as d:
         nvm = DirNVM(d)
         hits = [0]
@@ -154,7 +156,7 @@ def test_single_task_partition_runs(graph, inputs):
 
 
 def test_missing_external_input_raises(graph):
-    part = optimal_partition(graph, CM, None)
+    part = _numpy_partition(graph, CM, None)
     rt = BurstRuntime(graph, part, MemoryNVM())
     with pytest.raises(ValueError, match="missing external packet"):
         rt.run({})

@@ -17,19 +17,25 @@ import random
 import numpy as np
 import pytest
 
+from repro.api import PartitionSpec, solve
 from repro.core import (
     BurstRuntime,
     DirNVM,
     PAPER_FRAM_MODEL,
     PowerFailure,
     execute_atomic,
-    optimal_partition,
     q_min,
 )
 from repro.core.apps.headcount import VISUAL, build_graph
 
-pytestmark = [pytest.mark.slow,  # ~30 s of repeated 550-task executions
-              pytest.mark.legacy]  # drives the legacy optimal_partition shim
+
+def _numpy_partition(graph, cost, q_max=None):
+    """The numpy reference DP's optimal partition under one Q_max."""
+    return solve(PartitionSpec(graph=graph, cost=cost, q_max=q_max,
+                               backend="numpy")).partition()
+
+
+pytestmark = pytest.mark.slow  # ~30 s of repeated 550-task executions
 
 CM = PAPER_FRAM_MODEL
 N_SCHEDULES = 20
@@ -55,7 +61,7 @@ def soak_case(tmp_path_factory):
     """(graph, partition, atomic reference, crash-free DirNVM packet bytes)."""
     graph = build_graph(VISUAL.reduced(10), with_fns=True)
     assert graph.n_tasks >= 500, "soak graph must be large-scale"
-    part = optimal_partition(graph, CM, q_min(graph, CM) * 1.5)
+    part = _numpy_partition(graph, CM, q_min(graph, CM) * 1.5)
     assert part.n_bursts >= 20, "soak partition should have many crash sites"
     ref = execute_atomic(graph, {})
 
