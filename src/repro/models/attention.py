@@ -16,22 +16,36 @@ Sharding (see models/sharding.py):
 * decode: KV caches are [B, S, kv, hd] sharded along S over "model"; scores
   and the weighted sum reduce over the sharded axis, which GSPMD lowers to
   all-reduces — this works for any (n_heads, n_kv_heads), unlike head-sharded
-  TP (DESIGN.md §4). Cache updates use one-hot scatter (shard-local).
+  TP (DESIGN.md §4). A decode step writes no cache inside its layer loop:
+  each layer attends over its cache with the new row selected in at ``pos``
+  and returns the row, and :func:`write_kv_rows` writes every layer's row
+  after the loop. It writes with a dynamic-update-slice of the rows, or
+  with a one-hot blend (shard-local) where the ambient mesh splits the
+  sequence axis.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..obs.metrics import METRICS
 from .common import COMPUTE_DTYPE, apply_rope, dense_init, ones_init, rmsnorm, zeros_init
+from .sharding import _current_mesh, rules_for
 
-__all__ = ["init_attention", "attention", "decode_attention", "blockwise_attention"]
+__all__ = ["init_attention", "attention", "decode_attention", "write_kv_rows",
+           "blockwise_attention"]
 
 NEG_INF = -1e30
+
+# Trace-time count of decode-cache writes by the form taken: ``row`` (a
+# dynamic-update-slice of the step's rows) or ``onehot`` (a blend over every
+# position, kept where the mesh shards the cache's sequence axis).
+CACHE_WRITE = METRICS.counter_dict("attention.cache_write", ("row", "onehot"))
 
 
 def init_attention(cfg, kg, cross: bool = False):
@@ -188,22 +202,50 @@ def attention(cfg, p, x, *, positions, causal: bool = True, kv_x=None,
     return o @ p["wo"].astype(COMPUTE_DTYPE), (k, v)
 
 
-def _onehot_update(cache, new, pos):
-    """cache [B, S, KV, hd] ← new [B, 1, KV, hd] at sequence index ``pos``.
+def _kv_seq_shards(cfg) -> int:
+    """Devices the ambient mesh splits a decode cache's sequence axis over:
+    the product of the mesh's sizes along the axes ``kv_seq`` maps to."""
+    mesh = _current_mesh()
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    return math.prod(sizes.get(a, 1) for a in rules_for(cfg.family)["kv_seq"])
 
-    One-hot scatter: every shard updates only its local slice, no cross-shard
-    gather under SPMD (a dynamic-update-slice on a sharded dim would gather).
+
+def write_kv_rows(cfg, cache, rows, pos):
+    """cache [..., S, KV, hd] ← rows [..., 1, KV, hd] (the cache's dtype) at
+    sequence index ``pos``.
+
+    On an unsharded sequence axis, one dynamic-update-slice writes the rows.
+    Where the mesh shards that axis, a dynamic-update-slice would gather it,
+    so a one-hot blend updates every shard's local slice instead. Both leave
+    the same bits: the rows at ``pos``, every other position as it was.
     """
-    S = cache.shape[1]
-    oh = (jnp.arange(S) == pos).astype(cache.dtype)[None, :, None, None]
-    return cache * (1 - oh) + oh * new.astype(cache.dtype)
+    if _kv_seq_shards(cfg) == 1:
+        CACHE_WRITE["row"] += 1
+        start = [0] * cache.ndim
+        start[-3] = pos
+        return jax.lax.dynamic_update_slice(cache, rows, start)
+    CACHE_WRITE["onehot"] += 1
+    oh = (jnp.arange(cache.shape[-3]) == pos).astype(cache.dtype)[:, None, None]
+    return cache * (1 - oh) + oh * rows
 
 
-def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, cross: bool = False):
+def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, cross: bool = False,
+                     write: bool = False):
     """Single-token attention against a (sequence-sharded) cache.
 
     x: [B, 1, d]; cache_k/v: [B, S, KV, hd]; pos: scalar current position.
-    Returns (out [B, 1, d], cache_k, cache_v).
+    Returns (out [B, 1, d], k, v), where k and v depend on who calls:
+
+    * a layer scan (``write=False``): the caches are only read, with
+      position ``pos`` replaced by the new row as the dots read it, and k, v
+      are the step's rows [B, 1, KV, hd], which the caller writes after the
+      loop with :func:`write_kv_rows`. Nothing cache-sized is written
+      inside the loop.
+    * a caller outside a loop (``write=True``): k, v are the caches with the
+      rows written at ``pos``, and the dots read those, so that one pass can
+      read each cache, feed the dots and write its copy.
+
+    Cross-attention caches are fixed at prefill: k and v are ``None``.
     """
     positions = jnp.full((1, 1), pos, jnp.int32)
     if cross:
@@ -211,13 +253,19 @@ def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, cross: bool = False):
         q, _, _ = _project_qkv(cfg, p, x, kv_x=jnp.zeros_like(x), rope=False,
                                positions=positions)
         k, v = cache_k, cache_v
-        mask = None
+        k_out = v_out = mask = None
     else:
-        q, k_new, v_new = _project_qkv(cfg, p, x, positions=positions,
+        q, k_out, v_out = _project_qkv(cfg, p, x, positions=positions,
                                        kv_positions=positions)
-        cache_k = _onehot_update(cache_k, k_new, pos)
-        cache_v = _onehot_update(cache_v, v_new, pos)
-        k, v = cache_k, cache_v
+        k_out = k_out.astype(cache_k.dtype)
+        v_out = v_out.astype(cache_v.dtype)
+        if write:
+            k = k_out = write_kv_rows(cfg, cache_k, k_out, pos)
+            v = v_out = write_kv_rows(cfg, cache_v, v_out, pos)
+        else:
+            at_pos = (jnp.arange(cache_k.shape[1]) == pos)[None, :, None, None]
+            k = jnp.where(at_pos, k_out, cache_k)
+            v = jnp.where(at_pos, v_out, cache_v)
         mask = (jnp.arange(k.shape[1]) <= pos)[None, None, None, :]  # [1,1,1,S]
 
     B, S, KV, hd = k.shape
@@ -233,4 +281,4 @@ def decode_attention(cfg, p, x, cache_k, cache_v, pos, *, cross: bool = False):
     o = jnp.einsum("bkgqs,bskh->bqkgh", w.astype(COMPUTE_DTYPE),
                    v.astype(COMPUTE_DTYPE), preferred_element_type=jnp.float32)
     o = o.reshape(B, 1, H * hd).astype(COMPUTE_DTYPE)
-    return o @ p["wo"].astype(COMPUTE_DTYPE), cache_k, cache_v
+    return o @ p["wo"].astype(COMPUTE_DTYPE), k_out, v_out

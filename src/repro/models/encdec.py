@@ -17,7 +17,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import attention, decode_attention, init_attention
+from .attention import attention, decode_attention, init_attention, write_kv_rows
 from .common import COMPUTE_DTYPE, KeyGen, dense_init, layernorm, ones_init, zeros_init
 from .mlp import gelu_mlp, init_gelu_mlp
 from .transformer import _probe, stack_init
@@ -182,17 +182,19 @@ def encdec_decode_step(cfg, params, cache, token, pos, constrain=lambda x: x):
     def body(x, lin):
         lp, k_, v_, ck_, cv_ = lin
         h = _ln(x, lp["ln1"], cfg.norm_eps)
-        a, k_, v_ = decode_attention(cfg, lp["self"], h, k_, v_, pos)
+        a, k_row, v_row = decode_attention(cfg, lp["self"], h, k_, v_, pos)
         x = constrain(x + a)
         h = _ln(x, lp["lnc"], cfg.norm_eps)
         a, _, _ = decode_attention(cfg, lp["cross"], h, ck_, cv_, pos, cross=True)
         x = constrain(x + a)
         h = _ln(x, lp["ln2"], cfg.norm_eps)
         x = constrain(x + gelu_mlp(lp["mlp"], h))
-        return x, (k_, v_)
+        return x, (k_row, v_row)
 
-    x, (k2, v2) = jax.lax.scan(body, x,
-                               (params["dec"], cache["k"], cache["v"],
-                                cache["cross_k"], cache["cross_v"]))
+    x, (k_rows, v_rows) = jax.lax.scan(body, x,
+                                       (params["dec"], cache["k"], cache["v"],
+                                        cache["cross_k"], cache["cross_v"]))
     x = _ln(x, params["dec_ln"], cfg.norm_eps)
-    return x @ params["head"].astype(COMPUTE_DTYPE), dict(cache, k=k2, v=v2)
+    cache = dict(cache, k=write_kv_rows(cfg, cache["k"], k_rows, pos),
+                 v=write_kv_rows(cfg, cache["v"], v_rows, pos))
+    return x @ params["head"].astype(COMPUTE_DTYPE), cache

@@ -254,7 +254,7 @@ def _shared_block(cfg, sp, adapter, x, e0, kv=None, pos=None):
         a, kv = attention(cfg, sp["attn"], u, positions=jnp.arange(x.shape[1])[None],
                           constrain=_seq_sharded)
     else:
-        a, ck, cv = decode_attention(cfg, sp["attn"], u, *kv, pos)
+        a, ck, cv = decode_attention(cfg, sp["attn"], u, *kv, pos, write=True)
         kv = (ck, cv)
     h = rmsnorm(a, sp["mlp_ln"], cfg.norm_eps)
     return gated_mlp(sp["mlp"], h, cfg.mlp_act, adapter), kv

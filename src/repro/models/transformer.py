@@ -22,7 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import attention, decode_attention, init_attention
+from .attention import attention, decode_attention, init_attention, write_kv_rows
 from .common import COMPUTE_DTYPE, KeyGen, dense_init, ones_init, rmsnorm, softmax_cross_entropy
 from .mlp import init_swiglu, swiglu
 from .moe import init_moe, moe_block
@@ -261,8 +261,24 @@ def _to_cache_layout(kv):
 
 
 def lm_decode_step(cfg, params, cache, token, pos, constrain=lambda x: x):
-    """token [B,1] int32, pos scalar int32 → (logits [B,1,V], cache)."""
+    """token [B,1] int32, pos scalar int32 → (logits [B,1,V], cache).
+
+    The layer scan reads each layer's cache slice and emits only its new
+    K/V rows; one write per stacked cache puts all of them in at ``pos``.
+    """
     x = constrain(_embed(cfg, params, token))
+
+    def body(x, lin):  # one self-attention layer
+        lp, ck_, cv_ = lin
+        h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        a, k_row, v_row = decode_attention(cfg, lp["attn"], h, ck_, cv_, pos)
+        x = constrain(x + a)
+        h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
+        if cfg.family == "moe":
+            m, _ = moe_block(cfg, lp["moe"], h)
+        else:
+            m = swiglu(lp["mlp"], h)
+        return constrain(x + m), (k_row, v_row)
 
     if cfg.family == "vlm":
         per = cfg.cross_attn_every
@@ -272,45 +288,24 @@ def lm_decode_step(cfg, params, cache, token, pos, constrain=lambda x: x):
 
         def group_body(x, gin):
             gp, gk, gv, gck, gcv = gin
-
-            def body(x, lin):
-                lp, ck_, cv_ = lin
-                h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-                a, ck_, cv_ = decode_attention(cfg, lp["attn"], h, ck_, cv_, pos)
-                x = constrain(x + a)
-                h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
-                x = constrain(x + swiglu(lp["mlp"], h))
-                return x, (ck_, cv_)
-
-            x, (gk, gv) = jax.lax.scan(body, x, (gp["self"], gk, gv))
+            x, rows = jax.lax.scan(body, x, (gp["self"], gk, gv))
             h = rmsnorm(x, gp["cross"]["ln"], cfg.norm_eps)
             a, _, _ = decode_attention(cfg, gp["cross"]["attn"], h, gck, gcv,
                                        pos, cross=True)
             gate = jnp.tanh(gp["cross"]["gate"].astype(jnp.float32)).astype(COMPUTE_DTYPE)
             x = constrain(x + gate * a)
-            return x, (gk, gv)
+            return x, rows
 
-        x, (k2, v2) = jax.lax.scan(group_body, x,
-                                   (params["groups"], k, v,
-                                    cache["cross_k"], cache["cross_v"]))
-        cache = dict(cache, k=k2.reshape(-1, *k2.shape[2:]),
-                     v=v2.reshape(-1, *v2.shape[2:]))
+        x, (k_rows, v_rows) = jax.lax.scan(group_body, x,
+                                           (params["groups"], k, v,
+                                            cache["cross_k"], cache["cross_v"]))
+        k_rows = k_rows.reshape(-1, *k_rows.shape[2:])
+        v_rows = v_rows.reshape(-1, *v_rows.shape[2:])
     else:
-        def body(x, lin):
-            lp, ck_, cv_ = lin
-            h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            a, ck_, cv_ = decode_attention(cfg, lp["attn"], h, ck_, cv_, pos)
-            x = constrain(x + a)
-            h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                m, _ = moe_block(cfg, lp["moe"], h)
-            else:
-                m = swiglu(lp["mlp"], h)
-            x = constrain(x + m)
-            return x, (ck_, cv_)
-
-        x, (k2, v2) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-        cache = dict(cache, k=k2, v=v2)
+        x, (k_rows, v_rows) = jax.lax.scan(body, x, (params["layers"], cache["k"],
+                                                     cache["v"]))
+    cache = dict(cache, k=write_kv_rows(cfg, cache["k"], k_rows, pos),
+                 v=write_kv_rows(cfg, cache["v"], v_rows, pos))
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return _head(cfg, params, x), cache

@@ -197,6 +197,42 @@ def test_full_width_decode_step_compiles(one_chip, full_width, donate):
     assert _weight_converts(compiled.as_text(), params) == []
 
 
+def _relayouts_of(text: str, dims) -> list:
+    """Transpose and copy ops of a compiled module whose operand has, size-1
+    axes aside, the ``dims`` in some order: relayouts of such a value."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    want = sorted(dims)
+    found = []
+    for name, op, arg in re.findall(
+            r"%([\w.\-]+) = \S+ (transpose|copy)\(%([\w.\-]+)\)", text):
+        shape = [int(d) for d in shapes.get(arg, "").split(",") if d]
+        if sorted(d for d in shape if d != 1) == want:
+            found.append((name, op, arg))
+    return found
+
+
+def test_full_width_decode_reads_each_layer_cache_in_place(one_chip, full_width):
+    """At the longest serving bucket the decode step fits the chip, and no
+    transpose or copy takes one layer's K or V slice as its operand: the
+    attention reads each layer's cache where it lies."""
+    from repro.launch.serve import _step_fns
+    from repro.models import api
+
+    cfg, params = full_width
+    S = 4128
+    _, decode = _step_fns(ARCH, False, S, donate=False)
+    cache, _ = api.cache_shape(cfg, 1, S)
+    compiled = decode.lower(
+        params, _on(one_chip, cache),
+        jax.ShapeDtypeStruct((1, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
+    assert _relayouts_of(compiled.as_text(), (S, cfg.n_kv_heads, cfg.hd)) == []
+
+
 def test_float32_weights_are_cast_inside_the_step(one_chip):
     """The check above can fail: on the float32 tree the decode step casts
     each matmul weight (the stacked MLP weights, the embedding) itself."""
